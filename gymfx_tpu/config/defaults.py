@@ -175,14 +175,17 @@ DEFAULT_VALUES = {
     "ppo_minibatch_scheme": "env_permute",  # env_permute | sample_permute
     # per-step fused feature scaling in the rollout (pallas kernel,
     # ops/window_zscore.fused_step_obs): off = plain XLA (the bitwise
-    # oracle), on = pallas on TPU / XLA fallback elsewhere, interpret =
-    # pallas interpret mode anywhere (CPU parity tests)
+    # oracle), on = the compiled kernel (or its error) on a TPU and the
+    # XLA twin on a CPU, interpret = pallas interpret mode anywhere (CPU
+    # parity tests) — resolved in ops/dispatch.py.  Needs feature
+    # columns: refused when n_features == 0
     "rollout_obs_kernel": "off",
     # fused env-dynamics kernel family (ops/env_dynamics.py): the bar
     # venue's fill/bracket/financing chain and the mark/reward chain as
     # two env-blocked pallas VMEM passes bracketing the strategy kernel.
-    # off = plain XLA (the bitwise oracle), on = pallas on TPU / XLA
-    # fallback elsewhere, interpret = pallas interpret mode anywhere
+    # off = plain XLA (the bitwise oracle), on = the compiled kernel (or
+    # its error) on a TPU and the XLA twin on a CPU, interpret = pallas
+    # interpret mode anywhere
     "rollout_env_kernel": "off",
     # pallas LOB stream matching (ops/lob_match.py): sort-free ranked
     # matcher with exact int32 parity vs lob/book.py; same mode contract

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from gymfx_tpu.core.types import (
@@ -37,6 +38,26 @@ from gymfx_tpu.core.types import (
     EnvParams,
     EnvState,
 )
+
+
+def bump_exec_diag(diag, key: str, amount):
+    """``diag[EXEC_DIAG_INDEX[key]] += amount`` as a dense one-hot add
+    over the counter axis (axis 0) instead of ``.at[].add``: integer
+    adds are exact, so the result is the same, and a one-hot add is a
+    form Mosaic lowers — ``fill_pending`` runs inside the fused
+    env-dynamics kernel (ops/env_dynamics.py), where a scatter-add does
+    not.  ``amount`` broadcasts against ``diag.shape[1:]``."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, diag.shape, 0)
+    hot = rows == EXEC_DIAG_INDEX[key]
+    return diag + jnp.where(hot, amount.astype(jnp.int32), 0)
+
+
+def pick_mask(pred, a, b):
+    """``jnp.where(pred, a, b)`` for boolean ``a``/``b`` in logic form:
+    the same truth table, and a form Mosaic lowers (it has no select on
+    mask vectors) — the bracket chain runs inside the fused env-dynamics
+    kernel too."""
+    return (pred & a) | (~pred & b)
 
 
 def quantize(x, tick):
@@ -51,8 +72,6 @@ def quantize(x, tick):
     value within ~0.01 tick of a midpoint can round to the adjacent
     tick vs the f64 path — the crosscheck bound carries a documented
     midpoint-flip slack for exactly this (simulation/crosscheck.py)."""
-    import jax
-
     x = jnp.asarray(x)
     if jax.config.jax_enable_x64:
         xi, ti = x.astype(jnp.float64), jnp.asarray(tick, jnp.float64)
@@ -246,9 +265,9 @@ def fill_pending(
     )
     target = jnp.where(denied, state.pos, state.pos + jnp.sign(delta) * qty)
     state = state._replace(
-        exec_diag=state.exec_diag.at[
-            EXEC_DIAG_INDEX["order_denied_min_quantity"]
-        ].add(denied.astype(jnp.int32))
+        exec_diag=bump_exec_diag(
+            state.exec_diag, "order_denied_min_quantity", denied
+        )
     )
     fill_price = open_price
     slip_open = cfg.slip_open if cfg is not None else True
@@ -317,12 +336,12 @@ def check_brackets(
     #   cross         an exact touch fills, and a bar that gaps open
     #                 beyond the limit fills at the open (price
     #                 improvement) — the scan engine's no-profile default.
-    sl_trig = has_pos & has_sl & jnp.where(long, low <= sl, high >= sl)
+    sl_trig = has_pos & has_sl & pick_mask(long, low <= sl, high >= sl)
     strict = cfg.limit_fill_policy == "conservative"
     if strict:
-        tp_trig = has_pos & has_tp & jnp.where(long, high > tp, low < tp)
+        tp_trig = has_pos & has_tp & pick_mask(long, high > tp, low < tp)
     else:
-        tp_trig = has_pos & has_tp & jnp.where(long, high >= tp, low <= tp)
+        tp_trig = has_pos & has_tp & pick_mask(long, high >= tp, low <= tp)
     sl_fill = jnp.where(
         long,
         jnp.where(open_price <= sl, open_price, sl),
@@ -343,17 +362,17 @@ def check_brackets(
         # exclusive: SL and TP sit on opposite sides of the entry).
         # With no gap, longs reach TP on the O->H leg before SL on H->L;
         # shorts reach SL (above) on the O->H leg before TP on H->L.
-        gap_sl = has_pos & has_sl & jnp.where(long, open_price <= sl, open_price >= sl)
+        gap_sl = has_pos & has_sl & pick_mask(long, open_price <= sl, open_price >= sl)
         if strict:
-            gap_tp = has_pos & has_tp & jnp.where(
+            gap_tp = has_pos & has_tp & pick_mask(
                 long, open_price > tp, open_price < tp
             )
         else:
-            gap_tp = has_pos & has_tp & jnp.where(
+            gap_tp = has_pos & has_tp & pick_mask(
                 long, open_price >= tp, open_price <= tp
             )
         exit_sl = gap_sl | (
-            sl_trig & ~gap_tp & jnp.where(long, ~tp_trig, jnp.ones_like(gap_sl))
+            sl_trig & ~gap_tp & pick_mask(long, ~tp_trig, jnp.ones_like(gap_sl))
         )
         exit_tp = (gap_tp | tp_trig) & ~exit_sl
     else:  # worst_case / adaptive
@@ -373,7 +392,7 @@ def check_brackets(
     if cfg.slip_open and not cfg.slip_match:
         sl_adj = sl_fill  # apply_fill slips it (historical path)
     else:
-        sl_gap = has_pos & has_sl & jnp.where(
+        sl_gap = has_pos & has_sl & pick_mask(
             long, open_price <= sl, open_price >= sl
         )
         # gap SLs execute at the open (slip_open gates them); intrabar

@@ -46,6 +46,7 @@ from gymfx_tpu.core.types import (
     initial_state,
 )
 from gymfx_tpu.data.feed import MarketData
+from gymfx_tpu.ops.dispatch import kernel_interpret
 
 
 def jit_reset(cfg, params, data):
@@ -147,16 +148,15 @@ def step(
     st = state._replace(t=t_new, last_trade_cost=jnp.zeros_like(state.last_trade_cost))
 
     # fused env-dynamics kernel dispatch (`rollout_env_kernel` knob,
-    # docs/performance.md "MFU push"): "on" routes the bar venue's
-    # fill/bracket/financing and mark/reward chains through the pallas
-    # env-blocked kernels on TPU (plain XLA elsewhere); "interpret"
-    # forces pallas interpret mode anywhere (CPU parity tests); "off"
-    # is plain XLA everywhere.  All three bitwise-identical by
-    # construction (ops/env_dynamics.py; tests/test_env_dynamics_kernel.py).
-    kernel_env = cfg.venue == "bar" and cfg.rollout_env_kernel != "off" and (
-        cfg.rollout_env_kernel == "interpret"
-        or jax.default_backend() == "tpu"
-    )
+    # docs/performance.md "MFU push"): the bar venue's
+    # fill/bracket/financing and mark/reward chains as env-blocked
+    # pallas kernels.  The off|on|interpret decision is
+    # ops/dispatch.kernel_interpret's (None = the plain-XLA oracle);
+    # EnvConfig validation already refused a non-bar venue.  All three
+    # bitwise-identical by construction (ops/env_dynamics.py;
+    # tests/test_env_dynamics_kernel.py).
+    env_interpret = kernel_interpret(cfg.rollout_env_kernel)
+    kernel_env = env_interpret is not None
 
     if cfg.venue == "lob":
         # 1+2 (LOB venue): the pending order walks the seeded book at
@@ -189,7 +189,7 @@ def step(
             data.rollover_accrual[t_new - r0]
             if cfg.financing_enabled else None,
             advance, cfg, params,
-            interpret=cfg.rollout_env_kernel == "interpret",
+            interpret=env_interpret,
         )
     else:
         # 1. pending order fills at the new bar's open (only when advancing)
@@ -249,7 +249,7 @@ def step(
 
         st, _kernel_base_reward = env_dynamics.fused_mark_reward(
             st, c, advance | (live & ~state.started), live, cfg, params,
-            interpret=cfg.rollout_env_kernel == "interpret",
+            interpret=env_interpret,
         )
     else:
         st_m = broker.mark_to_market(st, c, params)

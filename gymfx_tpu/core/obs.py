@@ -91,28 +91,23 @@ def scale_feature_window_host(win, mean, std, neutral, cfg: "EnvConfig"):
 
 def _scaled_features(win, mean, std, neutral, cfg: "EnvConfig"):
     """Rollout feature-scaling dispatch (`rollout_obs_kernel` knob,
-    docs/performance.md): "on" routes through the fused pallas per-step
-    kernel on TPU and falls back to the plain-XLA oracle elsewhere;
-    "interpret" forces pallas interpret mode on any backend (the CPU
-    parity tests); "off" is the plain-XLA path everywhere.  All three
-    are bitwise-identical by construction (the kernel body reproduces
-    :func:`scale_feature_window` op for op; tests/test_ops.py +
-    tests/test_rollout_obs_kernel.py pin it)."""
-    mode = getattr(cfg, "rollout_obs_kernel", "off")
-    if mode != "off":
-        import jax
+    docs/performance.md): the fused pallas per-step kernel or the
+    plain-XLA oracle, as ops/dispatch.kernel_interpret decides
+    (off|on|interpret).  All three are bitwise-identical by construction
+    (the kernel body reproduces :func:`scale_feature_window` op for op;
+    tests/test_ops.py + tests/test_rollout_obs_kernel.py pin it)."""
+    from gymfx_tpu.ops.dispatch import kernel_interpret
 
-        on_tpu = jax.default_backend() == "tpu"
-        if mode == "interpret" or on_tpu:
-            from gymfx_tpu.ops.window_zscore import fused_step_obs
+    interpret = kernel_interpret(cfg.rollout_obs_kernel)
+    if interpret is None:
+        return scale_feature_window(win, mean, std, neutral, cfg)
+    from gymfx_tpu.ops.window_zscore import fused_step_obs
 
-            return fused_step_obs(
-                win, mean, std, neutral,
-                binary_mask=cfg.binary_mask, clip=cfg.feature_clip,
-                interpret=(mode == "interpret") or not on_tpu,
-            )
-        # "on" off-TPU: the plain-XLA fallback below
-    return scale_feature_window(win, mean, std, neutral, cfg)
+    return fused_step_obs(
+        win, mean, std, neutral,
+        binary_mask=cfg.binary_mask, clip=cfg.feature_clip,
+        interpret=interpret,
+    )
 
 
 def build_obs(

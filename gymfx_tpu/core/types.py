@@ -100,9 +100,10 @@ class EnvConfig:
     reward: str = "pnl_reward"               # pnl_reward | sharpe_reward | dd_penalized_reward | registered kernel
     obs_kernels: Tuple[str, ...] = ()        # registered extra obs blocks
     # per-step fused feature scaling (ops/window_zscore.fused_step_obs):
-    # "on" = pallas on TPU, plain XLA elsewhere; "interpret" = pallas
-    # interpret mode on any backend (CPU parity tests); "off" = plain
-    # XLA everywhere (the bitwise oracle)
+    # off|on|interpret resolve in ops/dispatch.py: "on" = the compiled
+    # kernel (or its error) on a TPU, plain XLA on a CPU; "interpret" =
+    # pallas interpret mode on any backend (CPU parity tests); "off" =
+    # plain XLA everywhere (the bitwise oracle)
     rollout_obs_kernel: str = "off"          # off | on | interpret
     # fused env-dynamics kernels (ops/env_dynamics.py): the bar venue's
     # fill/bracket/financing pass and the mark/reward pass each become
@@ -164,6 +165,7 @@ class EnvConfig:
     dtype: Any = jnp.float32
 
     def __post_init__(self):
+        from gymfx_tpu.ops.dispatch import KERNEL_MODES
         from gymfx_tpu.plugins import kernels as _k
 
         if self.action_space_mode not in ("discrete", "continuous"):
@@ -179,12 +181,21 @@ class EnvConfig:
         for name in self.obs_kernels:
             if not _k.has_obs_kernel(name):
                 raise ValueError(f"unknown obs kernel {name!r}")
-        if self.rollout_obs_kernel not in ("off", "on", "interpret"):
+        if self.rollout_obs_kernel not in KERNEL_MODES:
             raise ValueError(
                 f"rollout_obs_kernel must be off|on|interpret, got "
                 f"{self.rollout_obs_kernel!r}"
             )
-        if self.rollout_env_kernel not in ("off", "on", "interpret"):
+        if self.rollout_obs_kernel != "off" and self.n_features == 0:
+            # honor-or-reject: core/obs.build_obs only scales feature
+            # windows when the dataset has feature columns; with none
+            # the kernel would never enter the program
+            raise ValueError(
+                "rollout_obs_kernel requires feature columns "
+                "(n_features > 0): with none configured there is no "
+                "feature window for the kernel to scale"
+            )
+        if self.rollout_env_kernel not in KERNEL_MODES:
             raise ValueError(
                 f"rollout_env_kernel must be off|on|interpret, got "
                 f"{self.rollout_env_kernel!r}"
@@ -214,7 +225,7 @@ class EnvConfig:
                     f"(got {self.dtype!r}); the f64 oracle mode stays on "
                     "the plain-XLA path"
                 )
-        if self.lob_match_kernel not in ("off", "on", "interpret"):
+        if self.lob_match_kernel not in KERNEL_MODES:
             raise ValueError(
                 f"lob_match_kernel must be off|on|interpret, got "
                 f"{self.lob_match_kernel!r}"

@@ -618,6 +618,7 @@ def _decode_shard_impl(columns: Tuple[ColumnSpec, ...], shard_bars: int,
     import jax.numpy as jnp
 
     from gymfx_tpu.data.feed import MarketData
+    from gymfx_tpu.ops.dispatch import kernel_interpret
 
     slabs, bases, raws = slab["slabs"], slab["bases"], slab["raws"]
     R = int(shard_bars) + 1
@@ -631,15 +632,13 @@ def _decode_shard_impl(columns: Tuple[ColumnSpec, ...], shard_bars: int,
         delta = jnp.stack([slabs[s] for s, _ in items])
         base = jnp.stack([bases[s] for s, _ in items])
         inv = slab["invs"][gi]
-        if mode == "off":
+        interpret = kernel_interpret(mode)
+        if interpret is None:
             out = decode_q16_ref(delta, base, inv)
         else:
             from gymfx_tpu.ops.tape_decode import decode_q16_block
 
-            out = decode_q16_block(
-                delta, base, inv,
-                interpret=True if mode == "interpret" else None,
-            )
+            out = decode_q16_block(delta, base, inv, interpret=interpret)
         for i, key in enumerate(items):
             decoded_q16[key] = out[i]
 

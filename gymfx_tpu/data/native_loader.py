@@ -21,9 +21,16 @@ from typing import Optional
 import numpy as np
 import pandas as pd
 
-_LIB_PATH = pathlib.Path(__file__).resolve().parent.parent / "native" / "libgymfx_csv.so"
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
+# path -> which loader served it last ("native", or "pandas: <why>");
+# chip_smoke.py prints it so a silent fallback is at least visible
+_served: dict = {}
+
+
+def served_by(path) -> Optional[str]:
+    """Which loader served ``path`` the last time it was loaded."""
+    return _served.get(str(path))
 
 
 def _load_lib() -> Optional[ctypes.CDLL]:
@@ -35,11 +42,13 @@ def _load_lib() -> Optional[ctypes.CDLL]:
         import sys
 
         build = pathlib.Path(__file__).resolve().parents[2] / "tools" / "build_native.py"
-        # build_native handles staleness (mtime) and concurrency (lock +
-        # atomic rename), so it is safe and cheap to invoke every time
-        subprocess.run([sys.executable, str(build)], check=True,
-                       capture_output=True)
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        # build_native names the library after the hash of the committed
+        # csv_loader.cpp and builds it when absent (lock + atomic
+        # rename), so it is safe and cheap to invoke every time; its
+        # last line is "built <path>"
+        done = subprocess.run([sys.executable, str(build)], check=True,
+                              capture_output=True, text=True)
+        lib = ctypes.CDLL(done.stdout.split()[-1])
         lib.gymfx_csv_parse.restype = ctypes.c_void_p
         lib.gymfx_csv_parse.argtypes = [ctypes.c_char_p,
                                         ctypes.POINTER(ctypes.c_int64)]
@@ -81,22 +90,27 @@ def load_ohlcv_csv(path: str) -> Optional[pd.DataFrame]:
     """Native parse -> dataframe with DatetimeIndex, or None when the
     file is not canonical / the library is unavailable."""
     if not native_enabled():
+        _served[str(path)] = "pandas: GYMFX_NATIVE_LOADER=0"
         return None
     if not _header_is_canonical(path):
         if os.environ.get("GYMFX_NATIVE_LOADER") == "require":
             raise RuntimeError(f"native loader: non-canonical header in {path}")
+        _served[str(path)] = "pandas: non-canonical header"
         return None
     lib = _load_lib()
     if lib is None:
         if os.environ.get("GYMFX_NATIVE_LOADER") == "require":
             raise RuntimeError("native loader required but unavailable")
+        _served[str(path)] = "pandas: native library unavailable"
         return None
     n = ctypes.c_int64(0)
     handle = lib.gymfx_csv_parse(str(path).encode(), ctypes.byref(n))
     if not handle:
         if os.environ.get("GYMFX_NATIVE_LOADER") == "require":
             raise RuntimeError(f"native loader could not parse {path}")
+        _served[str(path)] = "pandas: native parser refused the file"
         return None
+    _served[str(path)] = "native"
     try:
         rows = int(n.value)
         epoch = np.empty(rows, np.int64)

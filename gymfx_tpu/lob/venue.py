@@ -51,6 +51,7 @@ import jax.numpy as jnp
 
 from gymfx_tpu.core import broker
 from gymfx_tpu.core.types import EXEC_DIAG_INDEX, EnvConfig, EnvParams, EnvState
+from gymfx_tpu.ops.dispatch import kernel_interpret
 
 from .book import (
     AGENT_OID,
@@ -136,21 +137,18 @@ def execute_bar(
     # fresh per-bar book, seeded with deterministic baseline depth;
     # lob_match_kernel routes the seed stream through the sort-free
     # pallas matcher (ops/lob_match.py) — exact int32 parity with the
-    # argsort engine, so "on" falling back off-TPU is bitwise safe
+    # argsort engine; off|on|interpret resolves in ops/dispatch
     book = empty_book(cfg.lob_depth_levels, cfg.lob_queue_slots)
     seed = seed_messages(o_t, cfg.lob_seed_levels, fp)
-    kernel_match = cfg.lob_match_kernel != "off" and (
-        cfg.lob_match_kernel == "interpret"
-        or jax.default_backend() == "tpu"
-    )
-    if kernel_match:
+    match_interpret = kernel_interpret(cfg.lob_match_kernel)
+    if match_interpret is None:
+        book, _ = process_stream(book, seed)
+    else:
         from gymfx_tpu.ops import lob_match
 
         book, _ = lob_match.fused_process_stream(
-            book, seed, interpret=cfg.lob_match_kernel == "interpret"
+            book, seed, interpret=match_interpret
         )
-    else:
-        book, _ = process_stream(book, seed)
 
     lot_units = lot_size(cfg, params)
 

@@ -13,7 +13,10 @@ The strategy kernel sits between the two in ``core/env.step``, so the
 family is TWO env-blocked pallas VMEM passes bracketing it (not one) —
 no reordering of the XLA program, which is what keeps the parity
 argument trivial.  Each kernel packs the touched ``EnvState`` scalars
-into (env_block, n_fields) faces, runs THE SAME ``core/broker`` /
+into (n_fields, rows, 128) faces — one field per leading index, the env
+batch folded over (rows, lanes) so every field is a whole-vreg tile;
+the (env_block, n_fields) layout with column slices and a scatter-add
+diag bump was refused by Mosaic (PR 22) — runs THE SAME ``core/broker`` /
 ``core/rewards`` functions elementwise on the block (op-for-op the XLA
 path, including the ``advance``/``mark`` select gating), and repacks.
 The plain-XLA path stays the bitwise oracle
@@ -21,10 +24,10 @@ The plain-XLA path stays the bitwise oracle
 ``ops/window_zscore.fused_step_obs``.
 
 The trainers' per-env ``vmap`` folds into the grid via
-``jax.custom_batching.custom_vmap`` (the fused-obs pattern); off-TPU
-the "on" mode falls back to XLA and "interpret" runs the pallas
-interpreter for CPU parity tests.  Dispatch lives in ``core/env.step``
-behind the ``rollout_env_kernel`` knob; EnvConfig validation rejects
+``jax.custom_batching.custom_vmap`` (the fused-obs pattern).  Dispatch
+lives in ``core/env.step`` behind the ``rollout_env_kernel`` knob, whose
+off|on|interpret modes resolve in ``ops/dispatch.py`` ("on" = this
+kernel compiled on a TPU, or its error; the XLA twin on a CPU); EnvConfig validation rejects
 configurations the packed-scalar form cannot reproduce (LOB venue,
 sharpe's ring buffer, f64 oracle mode).
 """
@@ -35,8 +38,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from gymfx_tpu.core import broker, rewards
+from gymfx_tpu.ops.dispatch import resolve_interpret
 from gymfx_tpu.core.types import (
     EXEC_DIAG_INDEX,
     EXEC_DIAG_KEYS,
@@ -77,18 +82,25 @@ MARK_PARAM_FIELDS = ("initial_cash", "reward_scale", "penalty_lambda")
 
 
 def _select(pred, a: EnvState, b: EnvState) -> EnvState:
-    # core/env._select, re-derived here to avoid a circular import
-    return EnvState(*(jnp.where(pred, x, y) for x, y in zip(a, b)))
+    """core/env._select for the block state.  Mosaic has no select on
+    mask (i1) vectors, so bool fields take the equivalent logic form."""
+
+    def pick(x, y):
+        if x.dtype == jnp.bool_:
+            return broker.pick_mask(pred, x, y)
+        return jnp.where(pred, x, y)
+
+    return EnvState(*(pick(x, y) for x, y in zip(a, b)))
 
 
-def _block_state(float_cols, bool_cols, int_cols, eb: int) -> EnvState:
-    """An EnvState whose listed fields are (eb,) columns and whose
-    untouched fields are typed dummies — the broker/reward functions
-    never read the dummies, and ``_select`` zips over all of them
-    harmlessly (where(pred, 0, 0))."""
-    zf = jnp.zeros((eb,), jnp.float32)
-    zi = jnp.zeros((eb,), jnp.int32)
-    zb = jnp.zeros((eb,), bool)
+def _block_state(float_cols, bool_cols, int_cols, face) -> EnvState:
+    """An EnvState whose listed fields are ``face``-shaped (rows, lanes)
+    env tiles and whose untouched fields are typed dummies — the
+    broker/reward functions never read the dummies, and ``_select`` zips
+    over all of them harmlessly (where(pred, 0, 0))."""
+    zf = jnp.zeros(face, jnp.float32)
+    zi = jnp.zeros(face, jnp.int32)
+    zb = zi != 0
     fields = {}
     for name in EnvState._fields:
         if name in ("started", "terminated", "pending_active",
@@ -100,11 +112,11 @@ def _block_state(float_cols, bool_cols, int_cols, eb: int) -> EnvState:
                       "last_coerced_action"):
             fields[name] = zi
         elif name == "exec_diag":
-            # (n_counters, eb): row-indexed .at[idx].add works
-            # elementwise across the env block
-            fields[name] = jnp.zeros((len(EXEC_DIAG_KEYS), eb), jnp.int32)
+            # (n_counters, *face): broker.bump_exec_diag's one-hot add
+            # over axis 0 works elementwise across the env tile
+            fields[name] = jnp.zeros((len(EXEC_DIAG_KEYS), *face), jnp.int32)
         elif name == "action_diag":
-            fields[name] = jnp.zeros((1, eb), jnp.int32)
+            fields[name] = jnp.zeros((1, *face), jnp.int32)
         else:
             fields[name] = zf
     fields.update(float_cols)
@@ -124,27 +136,27 @@ def _dummy_params(cols) -> EnvParams:
 
 # ---------------------------------------------------------------------------
 # Kernel A: fill_pending + check_brackets (+ financing accrual)
+#
+# Layout: every packed array is (fields, rows, 128) — one field per
+# leading index, the env batch folded into (rows, lanes) faces, so each
+# field of a block is a whole-vreg (rb, 128) tile and the broker chain
+# runs as plain elementwise VPU ops.  Params are SMEM scalars.
 # ---------------------------------------------------------------------------
-def _fill_bracket_kernel(fl_ref, it_ref, bars_ref, pp_ref, out_f_ref,
+def _fill_bracket_kernel(pp_ref, fl_ref, it_ref, bars_ref, out_f_ref,
                          out_i_ref, *, cfg: EnvConfig):
-    fl = fl_ref[...]                        # (eb, NF) f32
-    it = it_ref[...]                        # (eb, NB + NI + 1) i32
-    bars = bars_ref[...]                    # (eb, 5) f32: o h l c accrual
-    pp = pp_ref[...]                        # (1, NP) f32
-    eb = fl.shape[0]
-
-    float_cols = {n: fl[:, i] for i, n in enumerate(FILL_FLOAT_FIELDS)}
+    face = fl_ref.shape[1:]
+    float_cols = {n: fl_ref[i] for i, n in enumerate(FILL_FLOAT_FIELDS)}
     nb = len(FILL_BOOL_FIELDS)
-    bool_cols = {n: it[:, i] for i, n in enumerate(FILL_BOOL_FIELDS)}
+    bool_cols = {n: it_ref[i] for i, n in enumerate(FILL_BOOL_FIELDS)}
     int_cols = {
-        n: it[:, nb + i] for i, n in enumerate(FILL_INT_FIELDS)
+        n: it_ref[nb + i] for i, n in enumerate(FILL_INT_FIELDS)
     }
-    advance = it[:, nb + len(FILL_INT_FIELDS)] != 0
-    st = _block_state(float_cols, bool_cols, int_cols, eb)
+    advance = it_ref[nb + len(FILL_INT_FIELDS)] != 0
+    st = _block_state(float_cols, bool_cols, int_cols, face)
     params = _dummy_params(
-        {n: pp[0, i] for i, n in enumerate(FILL_PARAM_FIELDS)}
+        {n: pp_ref[i] for i, n in enumerate(FILL_PARAM_FIELDS)}
     )
-    o, h, l, c = bars[:, 0], bars[:, 1], bars[:, 2], bars[:, 3]
+    o, h, l, c = bars_ref[0], bars_ref[1], bars_ref[2], bars_ref[3]
 
     # op-for-op the core/env.step bar-venue advance (steps 1, 2, 2b)
     st_f = broker.fill_pending(st, o, params, cfg, h, l)
@@ -152,39 +164,35 @@ def _fill_bracket_kernel(fl_ref, it_ref, bars_ref, pp_ref, out_f_ref,
     st_b = broker.check_brackets(st, o, h, l, cfg, params)
     st = _select(advance, st_b, st)
     if cfg.financing_enabled:
-        accrual = st.pos * c * bars[:, 4]
+        accrual = st.pos * c * bars_ref[4]
         st = st._replace(
             cash_delta=st.cash_delta + jnp.where(advance, accrual, 0.0)
         )
 
-    out_f_ref[...] = jnp.stack(
-        [getattr(st, n) for n in FILL_FLOAT_FIELDS], axis=-1
-    )
-    out_i_ref[...] = jnp.stack(
+    for i, n in enumerate(FILL_FLOAT_FIELDS):
+        out_f_ref[i] = getattr(st, n)
+    out_i = (
         [getattr(st, n).astype(jnp.int32) for n in FILL_BOOL_FIELDS]
         + [getattr(st, n) for n in FILL_INT_FIELDS]
-        + [st.exec_diag[_DENIED_IDX]],
-        axis=-1,
+        + [st.exec_diag[_DENIED_IDX]]
     )
+    for i, col in enumerate(out_i):
+        out_i_ref[i] = col
 
 
 # ---------------------------------------------------------------------------
 # Kernel B: mark_to_market + compute_reward
 # ---------------------------------------------------------------------------
-def _mark_reward_kernel(fl_ref, it_ref, pp_ref, out_ref, *,
+def _mark_reward_kernel(pp_ref, fl_ref, it_ref, out_ref, *,
                         cfg: EnvConfig):
-    fl = fl_ref[...]                        # (eb, NF + 1) f32 (+close)
-    it = it_ref[...]                        # (eb, 2) i32: mark_pred live
-    pp = pp_ref[...]                        # (1, 3) f32
-    eb = fl.shape[0]
-
-    float_cols = {n: fl[:, i] for i, n in enumerate(MARK_FLOAT_FIELDS)}
-    close = fl[:, len(MARK_FLOAT_FIELDS)]
-    mark_pred = it[:, 0] != 0
-    live = it[:, 1] != 0
-    st = _block_state(float_cols, {}, {}, eb)
+    face = fl_ref.shape[1:]
+    float_cols = {n: fl_ref[i] for i, n in enumerate(MARK_FLOAT_FIELDS)}
+    close = fl_ref[len(MARK_FLOAT_FIELDS)]
+    mark_pred = it_ref[0] != 0
+    live = it_ref[1] != 0
+    st = _block_state(float_cols, {}, {}, face)
     params = _dummy_params(
-        {n: pp[0, i] for i, n in enumerate(MARK_PARAM_FIELDS)}
+        {n: pp_ref[i] for i, n in enumerate(MARK_PARAM_FIELDS)}
     )
 
     # op-for-op core/env.step step 4 + the reward block
@@ -192,76 +200,100 @@ def _mark_reward_kernel(fl_ref, it_ref, pp_ref, out_ref, *,
     st = _select(mark_pred, st_m, st)
     st, base_reward = rewards.compute_reward(st, cfg, params, live)
 
-    out_ref[...] = jnp.stack(
-        [getattr(st, n) for n in MARK_OUT_FIELDS] + [base_reward],
-        axis=-1,
-    )
+    for i, n in enumerate(MARK_OUT_FIELDS):
+        out_ref[i] = getattr(st, n)
+    out_ref[len(MARK_OUT_FIELDS)] = base_reward
 
 
 # ---------------------------------------------------------------------------
 # batched pallas dispatch + custom_vmap plumbing
 # ---------------------------------------------------------------------------
-def _env_block(batch: int, interpret: bool) -> int:
-    """Envs per program.  The per-env footprint is a few dozen scalars,
-    so VMEM never binds; 256 keeps the grid small on flagship batches
-    while interpret mode takes the whole batch in one program (the
-    interpreter's per-program overhead dominates there)."""
-    if interpret:
-        return batch
-    for eb in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if batch % eb == 0:
-            return eb
-    return 1
+_LANES = 128
+_MAX_BLOCK_ROWS = 64    # 64 x 128 = 8192 envs per program
 
 
-def _row_specs(widths, eb):
-    return [
-        pl.BlockSpec((eb, w), lambda i: (i, 0)) for w in widths[:-1]
-    ] + [pl.BlockSpec((1, widths[-1]), lambda i: (0, 0))]
+def _fold(x, rows: int):
+    """(B, F) per-env rows -> (F, rows, 128) field faces, zero-padded
+    (a zero env is inert: ``advance``/``mark_pred`` are 0 there and the
+    tail is sliced away by :func:`_unfold`)."""
+    b, f = x.shape
+    x = jnp.pad(x, ((0, rows * _LANES - b), (0, 0)))
+    return x.T.reshape(f, rows, _LANES)
+
+
+def _unfold(y, b: int):
+    """(F, rows, 128) field faces -> (B, F) per-env rows."""
+    return y.reshape(y.shape[0], -1).T[:b]
+
+
+def _rows(batch: int):
+    """(rows, block_rows) of the folded env batch: rows pad to the f32
+    sublane tile (8); a block is the largest divisor up to
+    ``_MAX_BLOCK_ROWS`` (a few dozen f32 faces of 32 KiB — VMEM never
+    binds)."""
+    rows = -(-batch // (8 * _LANES)) * 8
+    rb = next(r for r in (_MAX_BLOCK_ROWS, 32, 16, 8) if rows % r == 0)
+    return rows, rb
+
+
+def _face_call(kernel, ins, out_fields, out_dtypes, pp, rb, interpret):
+    """One pallas_call over (fields, rows, 128) faces, gridded over row
+    blocks; ``pp`` rides whole in SMEM as the scalar-params vector."""
+    rows = ins[0].shape[1]
+
+    def face(f):
+        return pl.BlockSpec((f, rb, _LANES), lambda i: (0, i, 0))
+
+    return pl.pallas_call(
+        kernel,
+        grid=(rows // rb,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [face(x.shape[0]) for x in ins],
+        out_specs=[face(f) for f in out_fields],
+        out_shape=[
+            jax.ShapeDtypeStruct((f, rows, _LANES), d)
+            for f, d in zip(out_fields, out_dtypes)
+        ],
+        interpret=interpret,
+    )(pp, *ins)
+
+
+def _broadcast_unbatched(axis_size, in_batched, args):
+    return tuple(
+        x if bat else jnp.broadcast_to(x[None], (axis_size, *x.shape))
+        for x, bat in zip(args, in_batched)
+    )
 
 
 @functools.lru_cache(maxsize=None)
 def _make_fill_bracket(cfg: EnvConfig, interpret: bool):
     from jax.custom_batching import custom_vmap
 
-    nf, ni = len(FILL_FLOAT_FIELDS), len(FILL_BOOL_FIELDS) + len(FILL_INT_FIELDS) + 1
-    np_ = len(FILL_PARAM_FIELDS)
+    nf = len(FILL_FLOAT_FIELDS)
+    ni = len(FILL_BOOL_FIELDS) + len(FILL_INT_FIELDS) + 1
     kernel = functools.partial(_fill_bracket_kernel, cfg=cfg)
 
-    def batched(fl, it, bars, pp):
+    def batched(fl, it, bars, pp):           # (B, NF) (B, NI) (B, 5) (NP,)
         b = fl.shape[0]
-        eb = _env_block(b, interpret)
-        return pl.pallas_call(
-            kernel,
-            grid=(b // eb,),
-            in_specs=_row_specs((nf, ni, 5, np_), eb),
-            out_specs=[
-                pl.BlockSpec((eb, nf), lambda i: (i, 0)),
-                pl.BlockSpec((eb, ni), lambda i: (i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b, nf), jnp.float32),
-                jax.ShapeDtypeStruct((b, ni), jnp.int32),
-            ],
-            interpret=interpret,
-        )(fl, it, bars, pp)
+        rows, rb = _rows(b)
+        out_f, out_i = _face_call(
+            kernel, [_fold(x, rows) for x in (fl, it, bars)],
+            (nf, ni), (jnp.float32, jnp.int32), pp, rb, interpret,
+        )
+        return _unfold(out_f, b), _unfold(out_i, b)
 
     @custom_vmap
     def one(fl, it, bars, pp):               # (NF,), (NI,), (5,), (NP,)
-        out_f, out_i = batched(
-            fl[None], it[None], bars[None], pp.reshape(1, -1)
-        )
+        out_f, out_i = batched(fl[None], it[None], bars[None], pp)
         return out_f[0], out_i[0]
 
     @one.def_vmap
     def _rule(axis_size, in_batched, fl, it, bars, pp):
-        fl, it, bars, pp = (
-            x if bat else jnp.broadcast_to(x[None], (axis_size, *x.shape))
-            for x, bat in zip((fl, it, bars, pp), in_batched)
+        fl, it, bars, pp = _broadcast_unbatched(
+            axis_size, in_batched, (fl, it, bars, pp)
         )
-        # params are identical across envs: one broadcast row
-        out = batched(fl, it, bars, pp[:1])
-        return out, (True, True)
+        # params are identical across envs: one scalar row
+        return batched(fl, it, bars, pp[0]), (True, True)
 
     return one
 
@@ -270,33 +302,28 @@ def _make_fill_bracket(cfg: EnvConfig, interpret: bool):
 def _make_mark_reward(cfg: EnvConfig, interpret: bool):
     from jax.custom_batching import custom_vmap
 
-    nf = len(MARK_FLOAT_FIELDS) + 1
     no = len(MARK_OUT_FIELDS) + 1
     kernel = functools.partial(_mark_reward_kernel, cfg=cfg)
 
-    def batched(fl, it, pp):
+    def batched(fl, it, pp):                 # (B, NF + 1) (B, 2) (NP,)
         b = fl.shape[0]
-        eb = _env_block(b, interpret)
-        return pl.pallas_call(
-            kernel,
-            grid=(b // eb,),
-            in_specs=_row_specs((nf, 2, len(MARK_PARAM_FIELDS)), eb),
-            out_specs=pl.BlockSpec((eb, no), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, no), jnp.float32),
-            interpret=interpret,
-        )(fl, it, pp)
+        rows, rb = _rows(b)
+        (out,) = _face_call(
+            kernel, [_fold(x, rows) for x in (fl, it)],
+            (no,), (jnp.float32,), pp, rb, interpret,
+        )
+        return _unfold(out, b)
 
     @custom_vmap
     def one(fl, it, pp):
-        return batched(fl[None], it[None], pp.reshape(1, -1))[0]
+        return batched(fl[None], it[None], pp)[0]
 
     @one.def_vmap
     def _rule(axis_size, in_batched, fl, it, pp):
-        fl, it, pp = (
-            x if bat else jnp.broadcast_to(x[None], (axis_size, *x.shape))
-            for x, bat in zip((fl, it, pp), in_batched)
+        fl, it, pp = _broadcast_unbatched(
+            axis_size, in_batched, (fl, it, pp)
         )
-        return batched(fl, it, pp[:1]), True
+        return batched(fl, it, pp[0]), True
 
     return one
 
@@ -312,8 +339,7 @@ def fused_fill_brackets(
     ``core/env.step`` (steps 1, 2, 2b) as one VMEM pass.  Bitwise
     identical to the XLA path by construction (same functions, same
     select gating, packed per-env scalars)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     one = _make_fill_bracket(cfg, bool(interpret))
     d = st.pos.dtype
     fl = jnp.stack(
@@ -363,8 +389,7 @@ def fused_mark_reward(
     (new_state, base_reward); the reward carries are updated at the
     mark's program position — nothing between mark and reward in the
     XLA step reads or writes them, so the final state is identical."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     one = _make_mark_reward(cfg, bool(interpret))
     d = st.pos.dtype
     fl = jnp.stack(

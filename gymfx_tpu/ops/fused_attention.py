@@ -32,8 +32,9 @@ env-blocked single pass.  The plain-XLA twin
 for BOTH directions (tests/test_ops.py), not part of the compiled
 gradient.
 
-Falls back to pallas interpret mode off-TPU, so tests run on CPU; the
-plain-XLA twin remains the parity oracle and the >1024-window fallback.
+``interpret=None`` resolves in ``ops/dispatch.py``: compiled on a TPU,
+the pallas interpreter elsewhere, so tests run on CPU; the plain-XLA
+twin remains the parity oracle and serves windows beyond 1024.
 """
 from __future__ import annotations
 
@@ -42,6 +43,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from gymfx_tpu.ops.dispatch import resolve_interpret
 
 # beyond this window the W x W f32 score blocks (plus q/k/v) stop
 # fitting comfortably in ~16 MB VMEM; longer sequences are the ring /
@@ -58,27 +62,67 @@ MAX_FUSED_WINDOW = 1024
 MIN_FUSED_WINDOW = 192
 
 
-def _env_block(batch: int, window: int, score_blocks_live: int = 1) -> int:
-    """Envs per program: amortize program overhead while keeping the
-    live f32 score blocks (score_blocks_live * eb * W * W * 4 bytes)
-    within a few MB of VMEM.  The backward pass holds three
-    score-shaped values at once (scores/p, dp, ds)."""
-    budget = max(
-        1, (4 * 1024 * 1024) // (score_blocks_live * window * window * 4)
+# what the env block is sized to hold, and the scoped-VMEM limit asked
+# of Mosaic (its default on v5e is 16 MiB of the core's 128 MiB).  The
+# gap is headroom for the compiler's own temporaries; the smallest block
+# (one env) at MAX_FUSED_WINDOW in float32 needs it: its backward with
+# HIGHEST-precision dots allocates 16.8 MB.
+_VMEM_BUDGET = 12 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _env_block(batch: int, window: int, head_dim: int, itemsize: int,
+               io_blocks: int, score_blocks_live: int) -> int:
+    """Envs per program: amortize program overhead while one program's
+    VMEM stays inside ``_VMEM_BUDGET``.  Counted per env, at the size
+    Mosaic really allocates:
+
+      * the ``io_blocks`` (S, D) q/k/v/o (backward: q/k/v/g/dq/dk/dv)
+        faces — D lane-padded to 128, S padded to the dtype's sublane
+        tile, and each DOUBLE-buffered by the pipeline;
+      * the live f32 score-shaped values (forward: scores; backward:
+        scores/p, dp, ds), S x S with the key axis lane-padded.
+
+    The forward at (256 envs, window 256, 4 x 32) in float32 is the
+    shape the old score-only budget got wrong: 16 envs of q/k/v/o are
+    16.8 MB before a single score is computed."""
+    sublane = 8 * max(1, 4 // itemsize)
+    io = (
+        io_blocks * _round_up(window, sublane) * _round_up(head_dim, 128)
+        * itemsize * 2
     )
+    scores = (
+        score_blocks_live * _round_up(window, 8) * _round_up(window, 128) * 4
+    )
+    budget = max(1, _VMEM_BUDGET // (io + scores))
     for eb in (16, 8, 4, 2, 1):
         if eb <= budget and batch % eb == 0:
             return eb
     return 1
 
 
+def _precision(ref):
+    """MXU precision of the in-kernel matmuls.  Mosaic, like XLA:TPU,
+    runs an f32 x f32 dot as ONE bf16 pass unless asked otherwise —
+    measured on the chip (PR 22) that put the float32 kernel 8e-3 from
+    ``full_attention``, not the 2e-5 its tests hold it to.  A float32
+    policy gets true f32 attention (``HIGHEST``); bf16 inputs are exact
+    in one pass and keep the default."""
+    return jax.lax.Precision.HIGHEST if ref.dtype == jnp.float32 else None
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool):
+    prec = _precision(q_ref)
     q = q_ref[:, 0].astype(jnp.float32)   # (eb, S, D)
     k = k_ref[:, 0].astype(jnp.float32)
     v = v_ref[:, 0].astype(jnp.float32)
     scores = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     ) * scale                              # (eb, S, S)
     if causal:
         s = scores.shape[-1]
@@ -89,7 +133,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool):
     p = jnp.exp(scores - m)
     num = jax.lax.dot_general(
         p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     )                                      # (eb, S, D)
     out = num / jnp.sum(p, axis=-1, keepdims=True)
     o_ref[:, 0] = out.astype(o_ref.dtype)
@@ -101,13 +145,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, dq_ref, dk_ref, dv_ref, *,
     q/k (cheaper than ever writing it to HBM), then the standard
     softmax-attention gradients — dV = P^T dO, dP = dO V^T,
     dS = P (dP - rowsum(dP P)), dQ = scale dS K, dK = scale dS^T Q."""
+    prec = _precision(q_ref)
     q = q_ref[:, 0].astype(jnp.float32)   # (eb, S, D)
     k = k_ref[:, 0].astype(jnp.float32)
     v = v_ref[:, 0].astype(jnp.float32)
     g = g_ref[:, 0].astype(jnp.float32)
     scores = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     ) * scale
     if causal:
         s = scores.shape[-1]
@@ -119,21 +164,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, dq_ref, dk_ref, dv_ref, *,
     p = e / jnp.sum(e, axis=-1, keepdims=True)      # (eb, Sq, Sk)
     dv = jax.lax.dot_general(
         p, g, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     )                                               # (eb, Sk, D)
     dp = jax.lax.dot_general(
         g, v, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     )                                               # (eb, Sq, Sk)
     delta = jnp.sum(dp * p, axis=-1, keepdims=True)
     ds = p * (dp - delta) * scale
     dq = jax.lax.dot_general(
         ds, k, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     )
     dk = jax.lax.dot_general(
         ds, q, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     )
     dq_ref[:, 0] = dq.astype(dq_ref.dtype)
     dk_ref[:, 0] = dk.astype(dk_ref.dtype)
@@ -143,7 +188,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, dq_ref, dk_ref, dv_ref, *,
 def _backward_batched(q, k, v, g, causal: bool, interpret: bool):
     """Fused backward on (B, S, H, D) primals + cotangent."""
     b, s, h, d = q.shape
-    eb = _env_block(b, s, score_blocks_live=3)
+    eb = _env_block(b, s, d, q.dtype.itemsize, io_blocks=7,
+                    score_blocks_live=3)
     scale = 1.0 / (d ** 0.5)
     kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal)
     spec = pl.BlockSpec((eb, 1, s, d), lambda i, j: (i, j, 0, 0))
@@ -153,6 +199,7 @@ def _backward_batched(q, k, v, g, causal: bool, interpret: bool):
         in_specs=[spec] * 4,
         out_specs=[spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype)] * 3,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )
     sw = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
@@ -163,7 +210,8 @@ def _backward_batched(q, k, v, g, causal: bool, interpret: bool):
 def _forward_batched(q, k, v, causal: bool, interpret: bool):
     """Fused pass on (B, S, H, D) inputs."""
     b, s, h, d = q.shape
-    eb = _env_block(b, s)
+    eb = _env_block(b, s, d, q.dtype.itemsize, io_blocks=4,
+                    score_blocks_live=1)
     scale = 1.0 / (d ** 0.5)
     kernel = functools.partial(_kernel, scale=scale, causal=causal)
     # (B, H, S, D) layout: heads and env blocks ride the grid; Mosaic
@@ -175,6 +223,7 @@ def _forward_batched(q, k, v, causal: bool, interpret: bool):
         in_specs=[pl.BlockSpec((eb, 1, s, d), lambda i, j: (i, j, 0, 0))] * 3,
         out_specs=pl.BlockSpec((eb, 1, s, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )
     out = call(
@@ -266,8 +315,7 @@ def fused_window_attention(q, k, v, *, causal: bool = False,
     env-block grid).  Differentiable (fused Pallas backward that
     recomputes the probabilities in VMEM — see module docstring).
     Returns (..., W, H, D) in the input dtype."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     *batch, s, h, d = q.shape
     if s > MAX_FUSED_WINDOW:
         raise ValueError(

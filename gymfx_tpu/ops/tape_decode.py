@@ -13,9 +13,9 @@ kernel and oracle agree bit-for-bit on any backend.
 Rows are blocked over a grid (whole-tape curriculum slabs can run to
 hundreds of thousands of rows — far beyond one VMEM face); the column
 axis pads to the int16 sublane tile and the divisor pads with ones, both
-sliced back after the call.  Falls back to pallas interpret mode off-TPU
-so the CI parity leg runs on CPU (the ``data_compress=interpret`` knob
-forces it anywhere).
+sliced back after the call.  ``interpret=None`` resolves in
+``ops/dispatch.py`` (compiled on a TPU, the interpreter elsewhere); the
+``data_compress=interpret`` knob forces the interpreter anywhere.
 """
 from __future__ import annotations
 
@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from gymfx_tpu.ops.dispatch import resolve_interpret
 
 _ROW_BLOCK = 2048
 
@@ -42,8 +44,7 @@ def decode_q16_block(delta, base, inv, *, interpret: bool | None = None):
     (C, rows) f32 = ``(base + delta) / inv``, bitwise-identical to
     ``decode_q16_ref``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     c, rows = delta.shape
     base2 = base.reshape(c, 1).astype(jnp.int32)
     inv2 = inv.reshape(c, 1).astype(jnp.float32)

@@ -21,10 +21,11 @@ in HBM every step.  A ``jax.custom_batching.custom_vmap`` rule folds
 the trainers' per-env ``vmap`` into an env-blocked grid (the
 ``ops/fused_attention.py`` pattern), and the kernel body reproduces
 ``core/obs.scale_feature_window`` op for op, so the plain-XLA path
-stays the bitwise parity oracle (tests/test_ops.py) and the off-TPU
-fallback.
+stays the bitwise parity oracle (tests/test_ops.py) and what a CPU runs
+under ``rollout_obs_kernel=on``.
 
-Falls back to pallas interpret mode off-TPU, so tests run on CPU.
+``interpret=None`` resolves in ``ops/dispatch.py``: compiled on a TPU,
+the pallas interpreter elsewhere, so tests run on CPU.
 """
 from __future__ import annotations
 
@@ -36,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from gymfx_tpu.ops.dispatch import resolve_interpret
 
 
 def _kernel(steps_ref, feat_hbm, mean_ref, std_ref, neutral_ref, out_ref,
@@ -71,8 +74,7 @@ def batched_scaled_windows(
     interpret: bool | None = None,
 ):
     """Scaled feature windows for a batch of steps: (B, window, F)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     b = steps.shape[0]
     f = orig_f = padded_features.shape[-1]
     steps = steps.astype(jnp.int32)
@@ -120,8 +122,7 @@ def batched_scaled_windows(
 def reference_scaled_windows(
     padded_features, feat_mean, feat_std, feat_neutral, steps, *, window, clip=10.0
 ):
-    """Plain-XLA reference implementation (for parity tests and as the
-    fallback path on backends without pallas support)."""
+    """Plain-XLA reference implementation (the parity oracle)."""
 
     def one(step):
         win = jax.lax.dynamic_slice(
@@ -250,8 +251,7 @@ def fused_step_obs(win, mean, std, neutral, *, binary_mask=(), clip=10.0,
     never differentiated — the update replays stored obs — so no
     custom_vjp is needed).  Bitwise-identical to
     ``core/obs.scale_feature_window`` (the parity oracle)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     one = _make_step_obs(
         tuple(bool(x) for x in binary_mask), float(clip), bool(interpret)
     )

@@ -1,5 +1,4 @@
 from gymfx_tpu.parallel.mesh import (  # noqa: F401
-    honor_jax_platforms_env,
     make_mesh,
     mesh_from_config,
     validate_batch_axis,

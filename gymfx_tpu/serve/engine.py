@@ -148,9 +148,9 @@ def resolve_batch_mode(mode: str) -> str:
         )
     if mode != "auto":
         return mode
-    import jax
+    from gymfx_tpu.ops.dispatch import on_tpu
 
-    return "matmul" if jax.default_backend() == "tpu" else "exact"
+    return "matmul" if on_tpu() else "exact"
 
 
 class InferenceEngine:
@@ -221,7 +221,9 @@ class InferenceEngine:
         self._carry0 = jax.tree.map(lambda x: np.asarray(x), carry0)
 
         if donate is None:
-            donate = jax.default_backend() == "tpu"
+            from gymfx_tpu.ops.dispatch import on_tpu
+
+            donate = on_tpu()
         donate_argnums = (1, 2) if donate else ()
 
         thr = jnp.float32(self.continuous_threshold)
@@ -256,7 +258,7 @@ class InferenceEngine:
                 )
 
         self._batched = batched
-        self._donate = bool(donate)
+        self.donate = bool(donate)
         self._fwd = jax.jit(batched, donate_argnums=donate_argnums)
         self._compiled: Dict[int, Any] = {}
         # ---- device-resident session slots (serve/slots.py) ----
@@ -572,10 +574,10 @@ class InferenceEngine:
         # then in place) and the padded obs; TPU only, like the host
         # ladder — CPU ignores donation with a warning
         self._fwd_slots = jax.jit(
-            fused, donate_argnums=(1, 2) if self._donate else ()
+            fused, donate_argnums=(1, 2) if self.donate else ()
         )
         self._seed_fn = jax.jit(
-            seed, donate_argnums=(0,) if self._donate else ()
+            seed, donate_argnums=(0,) if self.donate else ()
         )
         self.slot_cache = cache
         self.warmup_slots()

@@ -469,7 +469,7 @@ def profiler_workload(
     input, so it runs on a copy of the live ``state``; never raises
     (the profiler counts a workload_error instead).
     """
-    from gymfx_tpu.bench_util import compile_with_flops, measure_phase_split
+    from gymfx_tpu.bench_util import compile_train_step, measure_phase_split
 
     info: Dict[str, Any] = {
         "algo": str(algo),
@@ -478,15 +478,8 @@ def profiler_workload(
         "steps_per_iter": int(n_envs) * int(horizon),
     }
     k = max(1, int(k))
-    if k == 1:
-        compiled, flops = compile_with_flops(trainer._train_step, state)
-    else:
-        compiled, flops = compile_with_flops(trainer._train_many, state, k)
-    if compiled is not None:
-        try:
-            info["hlo_text"] = compiled.as_text()
-        except Exception:
-            pass
+    compiled, flops = compile_train_step(trainer, state, None if k == 1 else k)
+    info["hlo_text"] = compiled.as_text()
     info["xla_flops_per_dispatch"] = flops
     info["xla_flops_per_step"] = (flops / k) if flops else None
     try:

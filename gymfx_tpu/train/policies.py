@@ -61,7 +61,9 @@ def dense_window_attention(q, k, v):
     VMEM-resident pallas kernel on TPU for LONG windows
     (ops/fused_attention.py — zero HBM score traffic, VERDICT r4 weak
     #5), the plain-XLA twin for short windows (measured faster there),
-    off-TPU, and beyond the kernel's VMEM budget."""
+    off-TPU, and beyond the kernel's declared window.  A window inside
+    [MIN, MAX] on a TPU is the compiled kernel or its error."""
+    from gymfx_tpu.ops.dispatch import on_tpu
     from gymfx_tpu.ops.fused_attention import (
         MAX_FUSED_WINDOW,
         MIN_FUSED_WINDOW,
@@ -69,11 +71,8 @@ def dense_window_attention(q, k, v):
     )
     from gymfx_tpu.parallel.ring_attention import full_attention
 
-    if (
-        MIN_FUSED_WINDOW <= q.shape[-3] <= MAX_FUSED_WINDOW
-        and jax.default_backend() == "tpu"
-    ):
-        return fused_window_attention(q, k, v)
+    if MIN_FUSED_WINDOW <= q.shape[-3] <= MAX_FUSED_WINDOW and on_tpu():
+        return fused_window_attention(q, k, v, interpret=False)
     return full_attention(q, k, v)
 
 

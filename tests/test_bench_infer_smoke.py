@@ -20,7 +20,6 @@ def test_bench_infer_quick_prints_single_json_line_contract():
     env["JAX_PLATFORMS"] = "cpu"
     # share the suite's persistent compile cache so the smoke pays the
     # bucket ladder's compiles at most once across CI runs
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/gymfx_jax_cache")
     proc = subprocess.run(
         [sys.executable, str(REPO / "bench_infer.py"), "--quick"],
         cwd=str(REPO), env=env, capture_output=True, text=True, timeout=300,

@@ -21,7 +21,6 @@ def test_bench_quick_prints_single_json_line_contract():
     env["JAX_PLATFORMS"] = "cpu"
     # share the suite's persistent compile cache so the smoke pays the
     # big PPO program's compile at most once across CI runs
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/gymfx_jax_cache")
     proc = subprocess.run(
         [sys.executable, str(REPO / "bench.py"), "--quick"],
         cwd=str(REPO), env=env, capture_output=True, text=True, timeout=480,
@@ -41,7 +40,7 @@ def test_bench_quick_prints_single_json_line_contract():
     assert payload["supersteps"] == 1
     assert payload["dispatch_overhead_frac"] is None  # K=1: no comparison
     # r6 phase attribution: the rollout/update split keys must be in
-    # every record (BENCH_r06 reads them to attribute the cycle)
+    # every record (they attribute the cycle)
     for key in ("rollout_ms", "update_ms"):
         assert key in payload, (key, payload)
         assert payload[key] is not None and payload[key] > 0, (key, payload)
@@ -70,7 +69,6 @@ def test_multichip_bench_quick_emits_schema_valid_scaling_row():
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/gymfx_jax_cache")
     proc = subprocess.run(
         [sys.executable, str(REPO / "tools" / "multichip_bench.py"),
          "--quick"],
@@ -87,8 +85,9 @@ def test_multichip_bench_quick_emits_schema_valid_scaling_row():
     assert payload["scaling_efficiency"] > 0
     assert payload["n_devices"] == 8
     assert payload["mesh_shape"] == {"data": 8}
-    # off-TPU the anchor comparison and MFU are null, never fabricated
-    assert payload["vs_single_chip_anchor"] is None
+    # off-TPU the MFU is null, never fabricated, and no record quotes a
+    # single-chip anchor from another day's code
+    assert "vs_single_chip_anchor" not in payload
     assert payload["mfu_analytic"] is None
 
 
@@ -98,7 +97,6 @@ def test_lob_bench_quick_emits_schema_valid_fills_row():
     depth sweep — the row ROADMAP item 3 and docs/lob.md quote."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/gymfx_jax_cache")
     proc = subprocess.run(
         [sys.executable, str(REPO / "bench.py"), "--lob", "--quick"],
         cwd=str(REPO), env=env, capture_output=True, text=True, timeout=480,
@@ -137,7 +135,6 @@ def test_scengen_bench_quick_emits_schema_valid_bars_row():
     sweep over two presets — the row docs/scenarios.md quotes."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/gymfx_jax_cache")
     proc = subprocess.run(
         [sys.executable, str(REPO / "bench.py"), "--scengen", "--quick"],
         cwd=str(REPO), env=env, capture_output=True, text=True, timeout=480,
@@ -170,7 +167,6 @@ def test_lob_bench_full_depth_sweep_at_1024_books():
     emits a schema-valid record (slow: ~1 min of CPU matching)."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/gymfx_jax_cache")
     proc = subprocess.run(
         [sys.executable, str(REPO / "bench.py"), "--lob",
          "--books", "1024", "--messages", "64", "--iters", "2",
@@ -187,3 +183,40 @@ def test_lob_bench_full_depth_sweep_at_1024_books():
     assert payload["messages_per_stream"] == 64
     assert payload["value"] > 0
     assert set(payload["depth_sweep"]) == {"8", "24"}
+
+
+@pytest.mark.parametrize("script", [
+    "bench.py", "bench_infer.py", "tools/multichip_bench.py",
+    "tools/serve_load.py",
+])
+def test_bench_exits_nonzero_when_the_device_probe_fails(script):
+    """No device, no row: a benchmark whose first device op fails exits
+    non-zero with JAX's error.  It never prints a zero-valued record
+    and exits 0 (what the old probe watchdog did)."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "no_such_platform"  # backend init must fail
+    proc = subprocess.run(
+        [sys.executable, str(REPO / script), "--quick"],
+        cwd=str(REPO), env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0, proc.stdout[-2000:]
+    assert "no_such_platform" in proc.stderr
+    assert '"metric"' not in proc.stdout, proc.stdout[-2000:]
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """CPU rehearsal of chip_smoke.py: it must stop at its `device`
+    phase, print "ok": false and exit non-zero — there is no CPU mode."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py")],
+        cwd=str(REPO), env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0, proc.stdout[-2000:]
+    lines = [ln for ln in proc.stdout.strip().splitlines() if ln.strip()]
+    first, last = json.loads(lines[0]), json.loads(lines[-1])
+    assert first["phase"] == "device" and first["ok"] is False
+    assert len(lines) == 2, lines  # no work phase ran
+    assert last["ok"] is False and set(last) == {"ok", "device"}
+    assert last["device"]["platform"] == "cpu"

@@ -528,23 +528,29 @@ def test_initialize_distributed_exhausts_into_typed_error():
     assert isinstance(exc.cause, ConnectionError)
 
 
-def test_initialize_distributed_timeout_kwarg_falls_back_for_old_jax():
-    """Older jax rejects ``initialization_timeout``: the retry layer
-    must drop the kwarg and still initialize, not crash."""
+def test_initialize_distributed_passes_timeout_kwarg_through():
+    """``timeout_s`` reaches ``jax.distributed.initialize`` as
+    ``initialization_timeout`` (the installed JAX takes it); a
+    ``TypeError`` from the call is a bug and is raised as it is, not
+    retried without the kwarg."""
     from gymfx_tpu.parallel.mesh import initialize_distributed
 
     attempts = []
+    initialize_distributed(
+        "host:1234", retries=1, timeout_s=30.9,
+        _initialize=lambda **kw: attempts.append(kw),
+        _sleep=lambda s: None,
+    )
+    assert [a["initialization_timeout"] for a in attempts] == [30]
 
     def init(**kwargs):
-        if "initialization_timeout" in kwargs:
-            raise TypeError("unexpected keyword argument")
-        attempts.append(kwargs)
+        raise TypeError("unexpected keyword argument")
 
-    initialize_distributed(
-        "host:1234", retries=1, timeout_s=30.0,
-        _initialize=init, _sleep=lambda s: None,
-    )
-    assert len(attempts) == 1
+    with pytest.raises(TypeError):
+        initialize_distributed(
+            "host:1234", retries=3, timeout_s=30.0,
+            _initialize=init, _sleep=lambda s: None,
+        )
 
 
 # ---------------------------------------------------------------------------

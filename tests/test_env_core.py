@@ -312,7 +312,7 @@ def _f32_quantize(x, tick):
     of the suite's x64 default."""
     from gymfx_tpu.core import broker
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         return np.asarray(
             jax.device_get(broker.quantize(jnp_f32(x), jnp_f32(tick)))
         )
@@ -370,7 +370,7 @@ def test_quantize_f64_mode_rounds_half_even():
 def test_quantize_composes_under_jit_and_vmap():
     from gymfx_tpu.core import broker
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         xs = jnp_f32([1.100013, 1.100017, 1.099996])
         direct = jax.device_get(broker.quantize(xs, jnp_f32(1e-5)))
         jitted = jax.device_get(

@@ -99,3 +99,51 @@ def test_partial_schema_uses_pandas_backfill(tmp_path):
     assert load_ohlcv_csv(str(p)) is None
     df = load_dataframe({"input_data_file": str(p)})
     np.testing.assert_allclose(df["OPEN"], df["CLOSE"])
+
+
+def test_native_library_is_named_after_its_source_not_its_mtime(tmp_path):
+    """The library a loader picks up is the one built from the
+    csv_loader.cpp on disk: its name carries the source hash, so a
+    ``.so`` carried over from another source (``*.so`` is git-ignored
+    and travels with copied trees) is never preferred, however new its
+    mtime."""
+    import importlib.util
+    import pathlib
+
+    spec = importlib.util.spec_from_file_location(
+        "build_native",
+        pathlib.Path(__file__).resolve().parents[1] / "tools" / "build_native.py",
+    )
+    bn = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bn)
+    if load_ohlcv_csv(SAMPLE) is None:
+        pytest.skip("native loader unavailable in this environment")
+    current = bn.library_path()
+    assert current.exists() and current.name.startswith("libgymfx_csv.")
+    # a library "of another source", newer than everything else
+    stale = bn.NATIVE / "libgymfx_csv.0000000000000000.so"
+    stale.write_bytes(b"not a library")
+    try:
+        assert bn.build() == current          # the stale one is not chosen
+        assert bn.library_path() == current
+        # the hash follows the source text
+        other = tmp_path / "csv_loader.cpp"
+        other.write_text(bn.SOURCE.read_text() + "\n// changed\n")
+        real, bn.SOURCE = bn.SOURCE, other
+        try:
+            assert bn.library_path().name != current.name
+        finally:
+            bn.SOURCE = real
+    finally:
+        stale.unlink(missing_ok=True)
+
+
+def test_served_by_names_the_loader(monkeypatch):
+    from gymfx_tpu.data import native_loader
+
+    if load_ohlcv_csv(SAMPLE) is None:
+        pytest.skip("native loader unavailable in this environment")
+    assert native_loader.served_by(SAMPLE) == "native"
+    monkeypatch.setenv("GYMFX_NATIVE_LOADER", "0")
+    assert load_ohlcv_csv(SAMPLE) is None
+    assert native_loader.served_by(SAMPLE).startswith("pandas")

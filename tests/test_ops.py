@@ -253,3 +253,49 @@ def test_dense_window_attention_dispatch_off_tpu_is_reference():
         np.asarray(dense_window_attention(q, k, v)),
         np.asarray(full_attention(q, k, v)),
     )
+
+
+# ---------------------------------------------------------------------------
+# ops/dispatch.py: the one platform decision behind the kernel switches
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("mode, tpu, expected", [
+    ("off", False, None), ("off", True, None),
+    ("interpret", False, True), ("interpret", True, True),
+    # "on": the compiled kernel on a TPU, the plain-XLA twin on a CPU —
+    # never the interpreter, and on a TPU never the twin
+    ("on", True, False), ("on", False, None),
+])
+def test_kernel_switch_resolves_in_one_place(monkeypatch, mode, tpu, expected):
+    from gymfx_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: tpu)
+    assert dispatch.kernel_interpret(mode) is expected
+
+
+def test_kernel_switch_rejects_unknown_mode_and_entry_default(monkeypatch):
+    from gymfx_tpu.ops import dispatch
+
+    with pytest.raises(ValueError, match="off"):
+        dispatch.kernel_interpret("sideways")
+    # interpret=None at a kernel entry: compiled on a TPU, interpreter
+    # elsewhere; an explicit choice always wins
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    assert dispatch.resolve_interpret(None) is False
+    assert dispatch.resolve_interpret(True) is True
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: False)
+    assert dispatch.resolve_interpret(None) is True
+    assert dispatch.resolve_interpret(False) is False
+
+
+def test_no_platform_test_outside_the_dispatch_helper():
+    """`jax.default_backend()` is asked in ops/dispatch.py and nowhere
+    else in the package: eleven copies of the decision were how "on"
+    came to mean three different things."""
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parents[1] / "gymfx_tpu"
+    hits = [
+        str(p.relative_to(root)) for p in root.rglob("*.py")
+        if "default_backend()" in p.read_text(encoding="utf-8")
+    ]
+    assert hits == ["ops/dispatch.py"], hits

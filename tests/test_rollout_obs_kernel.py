@@ -91,3 +91,20 @@ def test_rollout_obs_kernel_knob_validation():
     config.update(window_size=8, rollout_obs_kernel="sideways")
     with pytest.raises(ValueError, match="rollout_obs_kernel"):
         make_env_config(config, n_bars=64, n_features=2)
+
+
+@pytest.mark.parametrize("mode", ["on", "interpret"])
+def test_rollout_obs_kernel_refused_without_feature_columns(mode):
+    """Honor-or-reject: with no feature columns there is no feature
+    window to scale, and core/obs.build_obs would never reach the
+    kernel — the switch is refused instead of accepted and ignored
+    (what bench.py's flagship did until PR 22)."""
+    from gymfx_tpu.core.types import make_env_config
+
+    config = dict(DEFAULT_VALUES)
+    config.update(window_size=8, rollout_obs_kernel=mode)
+    with pytest.raises(ValueError, match="n_features > 0"):
+        make_env_config(config, n_bars=64, n_features=0)
+    assert make_env_config(
+        config, n_bars=64, n_features=2
+    ).rollout_obs_kernel == mode

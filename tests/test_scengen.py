@@ -205,7 +205,6 @@ def test_subprocess_bitwise_determinism_same_seed_same_frame():
     )
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/gymfx_jax_cache")
     digests = []
     for _ in range(2):
         proc = subprocess.run(

@@ -1,0 +1,235 @@
+"""Ahead-of-time compiles for a described TPU v5e — no chip attached.
+
+The chip's compiler is installed in the sandbox and compiles for a
+topology that is DESCRIBED, not attached (on-chip-measurement guide
+§2.3).  Interpret-mode parity tests cannot see what Mosaic refuses —
+an unaligned block, a scatter, a select on mask vectors, more scoped
+VMEM than a kernel may use — so each of the five Pallas kernels is
+compiled here, with ``interpret=False`` steered in the test, at the
+widths ``chip_smoke.py`` runs on the chip.  A compile that passes is
+not a chip run: nothing executes, and no time or result comes of it.
+
+All of it lives in this ONE file, behind a module-scoped fixture: only
+one process may load libtpu, pytest-xdist hands a file to one worker,
+and describing the topology at import (or in a ``skipif``) would give
+the workers different collections.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from gymfx_tpu.config import DEFAULT_VALUES
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the suite runs with x64 on; the chip path is f32 (broker.quantize
+    # would otherwise put f64 arithmetic inside the env kernels)
+    with jax.enable_x64(False):
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes, sharding):
+    """Lower + compile ``fn`` for the described chip; returns the HLO
+    text so the caller can count ``tpu_custom_call``s."""
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        shapes,
+    )
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# fused window attention: (envs, window, heads, head_dim) of
+# RingTransformerEncoder (train/policies.py) — forward and backward
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("window, dtype", [
+    (256, jnp.float32),      # the shape the score-only VMEM budget broke
+    (256, jnp.bfloat16),
+    (1024, jnp.float32),     # MAX_FUSED_WINDOW
+])
+def test_fused_attention_compiles(one_chip, window, dtype, direction):
+    from gymfx_tpu.ops.fused_attention import (
+        MAX_FUSED_WINDOW,
+        fused_window_attention,
+    )
+
+    assert window <= MAX_FUSED_WINDOW
+    x = _sds((256, window, 4, 32), dtype)
+
+    def fwd(q, k, v):
+        return fused_window_attention(q, k, v, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    hlo = _compile(fwd if direction == "fwd" else bwd, x, x, x,
+                   sharding=one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# per-step obs kernel, the trainers' per-env vmap folded into the grid
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("features", [5, 8])
+def test_fused_step_obs_compiles(one_chip, features):
+    from gymfx_tpu.ops.window_zscore import fused_step_obs
+
+    def obs(win, mean, std, neutral):
+        return jax.vmap(
+            lambda *a: fused_step_obs(
+                *a, binary_mask=(False,) * features, clip=10.0,
+                interpret=False,
+            )
+        )(win, mean, std, neutral)
+
+    hlo = _compile(
+        obs,
+        _sds((8192, 32, features), jnp.float32),
+        _sds((8192, features), jnp.float32),
+        _sds((8192, features), jnp.float32),
+        _sds((8192,), jnp.bool_),
+        sharding=one_chip,
+    )
+    assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# env dynamics: kernel A (fill + brackets) and kernel B (mark + reward)
+# at the flagship's 8192 envs, on the broker chain's config axes
+# ---------------------------------------------------------------------------
+def _env_cfg(**over):
+    from gymfx_tpu.core.types import make_env_config, make_env_params
+
+    config = dict(DEFAULT_VALUES)
+    config.update(window_size=32, timeframe="M1", rollout_env_kernel="on")
+    config.update(over)
+    cfg = make_env_config(config, n_bars=500)
+    return cfg, make_env_params(config, cfg)
+
+
+def _batched_state(cfg, n):
+    from gymfx_tpu.core.types import initial_state
+
+    one = jax.eval_shape(lambda: initial_state(cfg))
+    return jax.tree.map(lambda s: _sds((n, *s.shape), s.dtype), one)
+
+
+_BROKER_AXES = [
+    {},
+    {"strategy_plugin": "direct_fixed_sltp", "slip_limit": True,
+     "slip_match": True, "slippage": 2e-4, "venue_quantization": True,
+     "instrument": "EUR_USD", "intrabar_collision_policy": "ohlc",
+     "limit_fill_policy": "conservative"},
+]
+
+
+@pytest.mark.parametrize("over", _BROKER_AXES, ids=["default", "all_axes"])
+def test_env_fill_brackets_kernel_compiles(one_chip, over):
+    from gymfx_tpu.ops.env_dynamics import fused_fill_brackets
+
+    cfg, params = _env_cfg(**over)
+    n = 8192
+    bar = _sds((n,), jnp.float32)
+
+    def kernel_a(st, o, h, l, c, advance):
+        return jax.vmap(
+            lambda st, o, h, l, c, adv: fused_fill_brackets(
+                st, o, h, l, c, None, adv, cfg, params, interpret=False
+            )
+        )(st, o, h, l, c, advance)
+
+    hlo = _compile(
+        kernel_a, _batched_state(cfg, n), bar, bar, bar, bar,
+        _sds((n,), jnp.bool_), sharding=one_chip,
+    )
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize(
+    "reward", ["pnl_reward", "dd_penalized_reward"]
+)
+def test_env_mark_reward_kernel_compiles(one_chip, reward):
+    from gymfx_tpu.ops.env_dynamics import fused_mark_reward
+
+    cfg, params = _env_cfg(reward_plugin=reward)
+    n = 8192
+    flag = _sds((n,), jnp.bool_)
+
+    def kernel_b(st, c, mark, live):
+        return jax.vmap(
+            lambda st, c, m, lv: fused_mark_reward(
+                st, c, m, lv, cfg, params, interpret=False
+            )
+        )(st, c, mark, live)
+
+    hlo = _compile(
+        kernel_b, _batched_state(cfg, n), _sds((n,), jnp.float32),
+        flag, flag, sharding=one_chip,
+    )
+    assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# LOB stream matcher: 1024 books x 24 levels x 4 slots (venue default)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("n_msgs", [16, 256], ids=["seed16", "bench256"])
+def test_lob_match_kernel_compiles(one_chip, n_msgs):
+    from gymfx_tpu.lob.book import BookState, Messages
+    from gymfx_tpu.ops.lob_match import fused_process_stream
+
+    books, depth, slots = 1024, 24, 4
+    lvl = _sds((books, depth), jnp.int32)
+    slab = _sds((books, depth, slots), jnp.int32)
+    book = BookState(lvl, slab, slab, lvl, slab, slab)
+    msgs = Messages(*(_sds((books, n_msgs), jnp.int32) for _ in range(5)))
+
+    def match(book, msgs):
+        return jax.vmap(
+            lambda b, m: fused_process_stream(b, m, interpret=False)
+        )(book, msgs)
+
+    hlo = _compile(match, book, msgs, sharding=one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# q16 tape decode: one streamed shard (rows not lane-aligned on purpose)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("cols, rows", [(5, 100_000), (9, 16_385)])
+def test_tape_decode_kernel_compiles(one_chip, cols, rows):
+    from gymfx_tpu.ops.tape_decode import decode_q16_block
+
+    hlo = _compile(
+        lambda d, b, i: decode_q16_block(d, b, i, interpret=False),
+        _sds((cols, rows), jnp.int16), _sds((cols,), jnp.int32),
+        _sds((cols,), jnp.float32), sharding=one_chip,
+    )
+    assert "tpu_custom_call" in hlo

@@ -317,9 +317,6 @@ def main(argv=None) -> int:
             f"{VIRTUAL_DEVICES}"
         ).strip()
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from gymfx_tpu.parallel import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
 
     from gymfx_tpu.config.defaults import DEFAULT_VALUES
 

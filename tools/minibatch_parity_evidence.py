@@ -5,8 +5,9 @@
 Round 6 makes ``ppo_minibatch_scheme=env_permute`` the product default
 (config/defaults.py): trajectory (env-permuted) minibatches turn the
 update phase's T*N random sample gather into contiguous whole-
-trajectory DMA, which is what closes the wide-batch rollover on TPU
-(examples/results/tpu_bench_sweep.json).  A default flip needs quality
+trajectory DMA, which is what closed the wide-batch rollover on TPU
+(tools/tpu_bench.py sweep; its committed artifact was deleted in PR 22
+and is not measured on today's code).  A default flip needs quality
 evidence, not just speed evidence — this tool trains the flagship
 recipe under BOTH schemes across several seeds with only the minibatch
 scheme differing, evaluates every run on the chronological holdout,
@@ -38,9 +39,9 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from gymfx_tpu.bench_util import ensure_cpu_if_requested
+from gymfx_tpu.compile_cache import enable_compile_cache
 
-ensure_cpu_if_requested()
+enable_compile_cache()
 
 SCHEMES = ("env_permute", "sample_permute")
 

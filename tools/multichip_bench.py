@@ -15,10 +15,7 @@ the per-chip rate, and
 
     scaling_efficiency = (aggregate / single_device) / n_devices
 
-(1.0 = perfect strong scaling of the same global batch).  The per-chip
-rate is also compared against the committed single-chip anchor
-(12.72M env steps/sec/chip, BENCH_r05) — null off-TPU, where the anchor
-is meaningless.  Per-phase rollout/update split and the analytic
+(1.0 = perfect strong scaling of the same global batch).  Per-phase rollout/update split and the analytic
 per-chip MFU slice (telemetry/mfu.py) ride along, all validated by
 ``tools/bench_contract_schema.json`` (metric
 ``multichip_env_steps_per_sec``).
@@ -39,13 +36,9 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from gymfx_tpu.bench_util import ensure_cpu_if_requested
+from gymfx_tpu.compile_cache import enable_compile_cache
 
-ensure_cpu_if_requested()
-
-# BENCH_r05 flagship: 12.72M env steps/sec/chip (101.7x the 125k/chip
-# baseline) — the single-chip anchor mesh efficiency is judged against
-SINGLE_CHIP_ANCHOR = 12_720_000.0
+enable_compile_cache()
 
 
 def _trainer(n_envs: int, horizon: int, mesh=None):
@@ -164,7 +157,6 @@ def build_record(*, n_envs: int, horizon: int, iters: int,
 
     per_chip = aggregate / n
     efficiency = (aggregate / sps_single) / n
-    on_tpu = device.platform == "tpu"
     return stamp_comparability({
         "metric": "multichip_env_steps_per_sec",
         "value": round(aggregate, 1),
@@ -178,12 +170,6 @@ def build_record(*, n_envs: int, horizon: int, iters: int,
         "scaling_efficiency": round(efficiency, 4),
         "n_devices": n,
         "mesh_shape": runtime.mesh_shape,
-        "anchor_steps_per_sec_per_chip": SINGLE_CHIP_ANCHOR,
-        # per-chip rate vs the committed single-chip flagship number;
-        # null off-TPU (the anchor was measured on a TPU chip)
-        "vs_single_chip_anchor": (
-            round(per_chip / SINGLE_CHIP_ANCHOR, 4) if on_tpu else None
-        ),
         "rollout_ms": round(rollout_ms, 3) if rollout_ms is not None else None,
         "update_ms": round(update_ms, 3) if update_ms is not None else None,
         # analytic per-chip FLOP model + memory accounting
@@ -219,11 +205,7 @@ def main() -> int:
 
     from gymfx_tpu.bench_util import probe_device
 
-    probe_device(
-        "multichip_env_steps_per_sec",
-        unit="aggregate env steps/sec across the mesh",
-        extra={"aggregate_steps_per_sec": 0.0, "scaling_efficiency": 0.0},
-    )
+    probe_device()
 
     mesh_shape = json.loads(args.mesh_shape) if args.mesh_shape else None
     record = build_record(

@@ -20,9 +20,10 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from gymfx_tpu.bench_util import DEFAULT_BENCH_ITERS, ensure_cpu_if_requested
+from gymfx_tpu.bench_util import DEFAULT_BENCH_ITERS
+from gymfx_tpu.compile_cache import enable_compile_cache
 
-ensure_cpu_if_requested()
+enable_compile_cache()
 
 
 def main() -> int:
@@ -38,6 +39,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from gymfx_tpu.ops.dispatch import on_tpu
     from gymfx_tpu.ops.window_zscore import (
         batched_scaled_windows,
         reference_scaled_windows,
@@ -94,7 +96,7 @@ def main() -> int:
         "pallas_seconds_per_call": round(pallas_s, 6),
         "xla_reference_seconds_per_call": round(xla_s, 6),
         "speedup": round(xla_s / pallas_s, 2) if pallas_s > 0 else None,
-        "interpret_mode": jax.default_backend() != "tpu",
+        "interpret_mode": not on_tpu(),
     }
     print(json.dumps({k: artifact[k] for k in (
         "max_abs_err_vs_xla_reference", "pallas_seconds_per_call",

@@ -5,16 +5,15 @@
 #   tools/run_tests.sh            # tier-1 (everything not marked slow)
 #   tools/run_tests.sh -k serve   # extra args forwarded to pytest
 #
-# Cache hygiene: tests/conftest.py points the jax persistent compile
-# cache at a FRESH per-session directory and exports it, so the main
-# process warms it for the subprocess tests (CLI roundtrips, bench
-# smokes) but no run ever deserializes another run's entries —
-# reading large vmapped programs from a stale cache corrupts the heap
-# on the CPU backend and segfaults minutes later at an unrelated
-# allocation.  If a run still dies mid-suite with "Fatal Python
-# error: Segmentation fault" during garbage collection or tracing,
-# suspect a shared/stale JAX_COMPILATION_CACHE_DIR leaking in from
-# the environment before blaming the test that happened to be running.
+# Compile cache: tests/conftest.py follows the one rule of
+# gymfx_tpu/compile_cache.py — JAX_COMPILATION_CACHE_DIR if it is set,
+# else the fixed, git-ignored .jax_cache/ in the checkout — and exports
+# the directory, so the main process warms it for the subprocess tests
+# (CLI roundtrips, bench smokes).  The per-session mkdtemp directory of
+# earlier rounds guarded against a stale-cache crash that PR 22 could
+# not reproduce on JAX 0.9.0 (a second run of the heavy trainer files
+# over the first run's cache: 62 passed, 183 s against 321 s).  Every
+# leg below is a CPU leg: it sets JAX_PLATFORMS=cpu or never imports JAX.
 #
 # After the suite: the scenario robustness gate in quick mode (three
 # scengen presets + the serving-fallback leg, schema-pinned report —

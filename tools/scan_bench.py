@@ -21,9 +21,9 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from gymfx_tpu.bench_util import ensure_cpu_if_requested
+from gymfx_tpu.compile_cache import enable_compile_cache
 
-ensure_cpu_if_requested()
+enable_compile_cache()
 
 CHUNK = 64
 CHUNKS = 6

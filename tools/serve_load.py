@@ -39,9 +39,9 @@ import time
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
-from gymfx_tpu.bench_util import ensure_cpu_if_requested
+from gymfx_tpu.compile_cache import enable_compile_cache
 
-ensure_cpu_if_requested()
+enable_compile_cache()
 
 
 def main() -> None:
@@ -77,11 +77,7 @@ def main() -> None:
 
     from gymfx_tpu.bench_util import probe_device
 
-    probe_device(
-        "serve_load_decisions_per_sec",
-        unit="decisions/sec sustained",
-        extra={"p50_ms": 0.0, "p99_ms": 0.0},
-    )
+    probe_device()
 
     import numpy as np
     import jax

@@ -28,9 +28,9 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from gymfx_tpu.bench_util import ensure_cpu_if_requested
+from gymfx_tpu.compile_cache import enable_compile_cache
 
-ensure_cpu_if_requested()
+enable_compile_cache()
 
 MIN_SHARPE = 1.0
 MIN_TRADES = 30
