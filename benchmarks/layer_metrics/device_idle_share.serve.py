@@ -1,0 +1,6 @@
+"""1 - (union of device-op intervals / traced window), serving cells."""
+from reduce_trace import idle_share
+
+
+def read(run):
+    return idle_share(run.get("trace"))
