@@ -34,10 +34,17 @@ def compile_train_step(trainer: Any, state: Any, k: Optional[int] = None):
     K-step ``train_many`` superstep for ``state``: ``(compiled,
     flops_or_None)``.  The executable is what the benchmarks and
     ``chip_smoke.py`` run and read (``as_text()``, cost analysis), so
-    the program is compiled once."""
+    the program is compiled once.  It is also registered as the newest
+    step program (``telemetry/scopes.py``), so that a trace of it can be
+    joined to the layers its instructions were written in."""
+    from gymfx_tpu.telemetry import scopes
+
     if k is None:
-        return compile_with_flops(trainer._train_step, state)
-    return compile_with_flops(trainer._train_many, state, int(k))
+        compiled, flops = compile_with_flops(trainer._train_step, state)
+    else:
+        compiled, flops = compile_with_flops(trainer._train_many, state, int(k))
+    scopes.register_step(compiled)
+    return compiled, flops
 
 
 def measure_train_step(trainer: Any, state: Any, iters: int):

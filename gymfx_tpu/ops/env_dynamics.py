@@ -42,6 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gymfx_tpu.core import broker, rewards
 from gymfx_tpu.ops.dispatch import resolve_interpret
+from gymfx_tpu.telemetry.scopes import KERNEL_FILL_BRACKETS, KERNEL_MARK_REWARD
 from gymfx_tpu.core.types import (
     EXEC_DIAG_INDEX,
     EXEC_DIAG_KEYS,
@@ -236,9 +237,11 @@ def _rows(batch: int):
     return rows, rb
 
 
-def _face_call(kernel, ins, out_fields, out_dtypes, pp, rb, interpret):
+def _face_call(kernel, name, ins, out_fields, out_dtypes, pp, rb, interpret):
     """One pallas_call over (fields, rows, 128) faces, gridded over row
-    blocks; ``pp`` rides whole in SMEM as the scalar-params vector."""
+    blocks; ``pp`` rides whole in SMEM as the scalar-params vector.
+    ``name`` is the kernel's stable name in the compiled program and in a
+    device trace (telemetry/scopes.py)."""
     rows = ins[0].shape[1]
 
     def face(f):
@@ -255,6 +258,7 @@ def _face_call(kernel, ins, out_fields, out_dtypes, pp, rb, interpret):
             for f, d in zip(out_fields, out_dtypes)
         ],
         interpret=interpret,
+        name=name,
     )(pp, *ins)
 
 
@@ -277,7 +281,8 @@ def _make_fill_bracket(cfg: EnvConfig, interpret: bool):
         b = fl.shape[0]
         rows, rb = _rows(b)
         out_f, out_i = _face_call(
-            kernel, [_fold(x, rows) for x in (fl, it, bars)],
+            kernel, KERNEL_FILL_BRACKETS,
+            [_fold(x, rows) for x in (fl, it, bars)],
             (nf, ni), (jnp.float32, jnp.int32), pp, rb, interpret,
         )
         return _unfold(out_f, b), _unfold(out_i, b)
@@ -309,7 +314,7 @@ def _make_mark_reward(cfg: EnvConfig, interpret: bool):
         b = fl.shape[0]
         rows, rb = _rows(b)
         (out,) = _face_call(
-            kernel, [_fold(x, rows) for x in (fl, it)],
+            kernel, KERNEL_MARK_REWARD, [_fold(x, rows) for x in (fl, it)],
             (no,), (jnp.float32,), pp, rb, interpret,
         )
         return _unfold(out, b)

@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from gymfx_tpu.ops.dispatch import resolve_interpret
+from gymfx_tpu.telemetry.scopes import KERNEL_ATTENTION_BWD, KERNEL_ATTENTION_FWD
 
 # beyond this window the W x W f32 score blocks (plus q/k/v) stop
 # fitting comfortably in ~16 MB VMEM; longer sequences are the ring /
@@ -201,6 +202,7 @@ def _backward_batched(q, k, v, g, causal: bool, interpret: bool):
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype)] * 3,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=KERNEL_ATTENTION_BWD,
     )
     sw = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
     dq, dk, dv = call(sw(q), sw(k), sw(v), sw(g))
@@ -225,6 +227,7 @@ def _forward_batched(q, k, v, causal: bool, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=KERNEL_ATTENTION_FWD,
     )
     out = call(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)

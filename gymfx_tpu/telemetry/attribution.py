@@ -35,11 +35,8 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence
 
 from gymfx_tpu.telemetry.profiler import MANIFEST_NAME, SCOPE_MAP_NAME
-from gymfx_tpu.telemetry.trace_parse import (
-    PHASE_SCOPES,
-    group_by_scope,
-    parse_trace,
-)
+from gymfx_tpu.telemetry.scopes import PHASE_SCOPES
+from gymfx_tpu.telemetry.trace_parse import group_by_scope, parse_trace
 
 SCHEMA_PATH = Path(__file__).resolve().parent / "profile_report_schema.json"
 

@@ -55,7 +55,7 @@ import time
 from pathlib import Path
 from typing import Any, Callable, Dict, Iterable, Optional, Sequence, Union
 
-from gymfx_tpu.telemetry.trace_parse import PHASE_SCOPES
+from gymfx_tpu.telemetry.scopes import PHASE_SCOPES
 
 MANIFEST_NAME = "manifest.json"
 SCOPE_MAP_NAME = "scope_map.json"
@@ -292,9 +292,12 @@ class ProfilerSession:
         hlo_text = info.pop("hlo_text", None)
         if hlo_text:
             try:
-                from gymfx_tpu.telemetry.trace_parse import scope_map_from_hlo
+                from gymfx_tpu.telemetry.scopes import scope_map_from_hlo
 
-                scope_map = scope_map_from_hlo(hlo_text, scopes=self.scopes)
+                scope_map = {
+                    name: scope.path for name, scope in
+                    scope_map_from_hlo(hlo_text, scopes=self.scopes).items()
+                }
                 if scope_map:
                     (bundle / SCOPE_MAP_NAME).write_text(
                         json.dumps(scope_map), encoding="utf-8"

@@ -1,0 +1,271 @@
+"""The layers of the fused train step, by name, on the device.
+
+One vocabulary of ``jax.named_scope`` names, planted at each layer
+boundary of the step program (``core/env.py``, ``train/ppo.py``,
+``train/policies.py``; ``train/impala.py`` plants the two phases) and read
+back from the compiled executable's text.  A device trace does NOT carry
+the op path (PR 24: an ``XLA Ops`` event is named by its instruction text
+without ``metadata={...}``), the optimized HLO does: ``op_name=
+"jit(_train_step_impl)/update/while/body/loss/transpose(jvp(policy_forward))/
+vmap(MLPPolicy)/Dense_0/dot_general"``.  :func:`scope_map_from_hlo` turns
+that text into ``{instruction name: OpScope}``, the join key for a trace's
+events; :func:`last_step_scope_map` gives the map of the newest step
+program ``bench_util.compile_train_step`` handed out.
+
+``named_scope`` is metadata of the trace only: the compiled program and
+its numerics do not change (tests/test_scopes.py pins both).
+
+The scope paths, under ``jit(...)``:
+
+  rollout/policy_act            policy forward, sampling, log-prob pick
+  rollout/env_step/tape_read    the tape's columns read by bar index
+  rollout/env_step/dynamics     action coercion, event overlay, fills and
+                                brackets, financing, margin, mark, reward,
+                                termination (env-dynamics kernels A and B)
+  rollout/env_step/obs          obs window update, build_obs, encode
+  rollout/auto_reset            masked resets and the trajectory's casts
+  update/gae                    advantages and returns
+  update/minibatch_take         permutation, slice and gather of a minibatch
+  update/loss                   value_and_grad of the loss; the policy call
+  update/loss/policy_forward    inside it, wrapped ``jvp(...)`` forward and
+                                ``transpose(jvp(...))`` backward
+  update/optimizer              optimizer update, apply, the guard's select
+  update/guard                  metrics, quarantine, masked resets
+  .../attention, .../ffn        the two halves of a transformer block, under
+                                ``policy_act`` and ``policy_forward``
+"""
+from __future__ import annotations
+
+import re
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+
+ROLLOUT = "rollout"
+UPDATE = "update"
+POLICY_ACT = "policy_act"
+ENV_STEP = "env_step"
+TAPE_READ = "tape_read"
+DYNAMICS = "dynamics"
+OBS = "obs"
+AUTO_RESET = "auto_reset"
+GAE = "gae"
+MINIBATCH_TAKE = "minibatch_take"
+LOSS = "loss"
+POLICY_FORWARD = "policy_forward"
+OPTIMIZER = "optimizer"
+GUARD = "guard"
+ATTENTION = "attention"
+FFN = "ffn"
+
+# the two phases every trainer's fused step plants (PR 6)
+PHASE_SCOPES = (ROLLOUT, UPDATE)
+SCOPE_NAMES = PHASE_SCOPES + (
+    POLICY_ACT, ENV_STEP, TAPE_READ, DYNAMICS, OBS, AUTO_RESET, GAE,
+    MINIBATCH_TAKE, LOSS, POLICY_FORWARD, OPTIMIZER, GUARD, ATTENTION, FFN,
+)
+
+
+def join(*names: str) -> str:
+    return "/".join(names)
+
+
+# the layers of the PPO step, as paths: where the work happens
+LAYERS = (
+    join(ROLLOUT, POLICY_ACT),
+    join(ROLLOUT, POLICY_ACT, ATTENTION),
+    join(ROLLOUT, POLICY_ACT, FFN),
+    join(ROLLOUT, ENV_STEP, TAPE_READ),
+    join(ROLLOUT, ENV_STEP, DYNAMICS),
+    join(ROLLOUT, ENV_STEP, OBS),
+    join(ROLLOUT, AUTO_RESET),
+    join(UPDATE, GAE),
+    join(UPDATE, MINIBATCH_TAKE),
+    join(UPDATE, LOSS),
+    join(UPDATE, LOSS, POLICY_FORWARD),
+    join(UPDATE, LOSS, POLICY_FORWARD, ATTENTION),
+    join(UPDATE, LOSS, POLICY_FORWARD, FFN),
+    join(UPDATE, OPTIMIZER),
+    join(UPDATE, GUARD),
+)
+# scopes that only group others: time charged to exactly one of them (a
+# scan's loop bookkeeping, an op XLA left between two layers) lies in no
+# layer, and neither does time with no scope at all
+GROUP_SCOPES = (ROLLOUT, UPDATE, join(ROLLOUT, ENV_STEP))
+
+# the ``name`` of every ``pl.pallas_call`` of the step program: a Mosaic
+# kernel's instruction is then ``%<name>.<n>`` whatever module it sits in
+KERNEL_FILL_BRACKETS = "env_dynamics_fill_brackets"
+KERNEL_MARK_REWARD = "env_dynamics_mark_reward"
+KERNEL_ATTENTION_FWD = "fused_attention_fwd"
+KERNEL_ATTENTION_BWD = "fused_attention_bwd"
+KERNEL_NAMES = (KERNEL_FILL_BRACKETS, KERNEL_MARK_REWARD,
+                KERNEL_ATTENTION_FWD, KERNEL_ATTENTION_BWD)
+
+FWD, BWD = "fwd", "bwd"
+
+
+class OpScope(NamedTuple):
+    path: str                  # "rollout/env_step/tape_read"
+    direction: Optional[str]   # FWD under jvp(...), BWD under transpose(...)
+
+
+# computation header at column 0: `%region_2.101 (arg: ...) -> ... {`
+# or `ENTRY %main.2164 (...) -> ... {`
+_COMPUTATION_RE = re.compile(r"(ENTRY\s+)?%?([\w.\-]+)\s*[({]")
+# `  ROOT %fusion.3 = f32[8]{0} fusion(...), ..., metadata={op_name="..."}`
+_INSTRUCTION_RE = re.compile(r"\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+# the computation a `while` or a `fusion` runs
+_CALLEE_RE = re.compile(r"\b(?:while\([^\n]*?\bbody|fusion\([^\n]*?\bcalls)=%?([\w.\-]+)")
+# computations that run instruction by instruction (each one a trace
+# event), as against a fusion's or a reducer's
+_RUNS_RE = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}"
+    r"|\bcall\([^\n]*?\bto_apply=%?([\w.\-]+)"
+)
+# one path component: `transpose(jvp(policy_forward))` -> wrappers, name
+_COMPONENT_RE = re.compile(r"((?:\w+\()*)([^()]*)\)*$")
+
+
+def _op_scope(op_name: str, scopes: Optional[Sequence[str]]) -> OpScope:
+    """The vocabulary's names on an op path, in order, transforms
+    unwrapped (a jitted helper, ``jit(name)``, is no scope).  The backward
+    of a custom VJP carries the scope it is called under in front of the
+    scopes it was defined under, ``loss/transpose(loss)/jvp(policy_forward)/
+    .../fused_attention_bwd``: a name that repeats the one before it is
+    dropped."""
+    direction = BWD if "transpose(" in op_name else (
+        FWD if "jvp(" in op_name else None)
+    if scopes is None:
+        return OpScope(op_name, direction)
+    found: List[str] = []
+    for part in op_name.split("/"):
+        wrappers, name = _COMPONENT_RE.match(part).groups()
+        if name in scopes and "jit(" not in wrappers and found[-1:] != [name]:
+            found.append(name)
+    return OpScope(_rooted(join(*found)), direction)
+
+
+def _rooted(path: str) -> str:
+    """Ops traced inside a ``custom_vmap`` rule (the packing round the
+    env-dynamics kernels) lose the outer part of their name stack,
+    ``vmap(env_step)/dynamics/add``: a path that starts below a phase gets
+    its root from the tree of layers, where only one root fits."""
+    if not path or path.split("/")[0] in PHASE_SCOPES:
+        return path
+    roots = set()
+    for layer in LAYERS:
+        at = f"/{layer}/".find(f"/{path}/")
+        if at > 0:
+            roots.add(layer[:at - 1])
+    return join(roots.pop(), path) if len(roots) == 1 else path
+
+
+def scope_map_from_hlo(
+    hlo_text: str, scopes: Optional[Sequence[str]] = SCOPE_NAMES,
+) -> Dict[str, OpScope]:
+    """``{instruction name: OpScope}`` for the top-level instructions of
+    an optimized-HLO text: those of the entry computation, of the
+    ``while`` bodies and conditions and of called computations, which are
+    the instructions a device trace has an event for (a fusion's insides
+    run as the fusion).  ``path`` holds the members of ``scopes`` on the
+    instruction's ``op_name`` path; ``scopes=None`` keeps the whole
+    ``op_name``.  An instruction with no scope on its path stays out of
+    the map.
+
+    XLA's scan loops surface as ``while`` instructions that carry no
+    ``op_name`` of their own yet hold self time in the trace (the loop's
+    bookkeeping), and some fusions lose theirs while their insides keep
+    it.  Such an instruction inherits from the computation it calls: the
+    phase of the strict majority of that computation's scoped
+    instructions, then as deep as all of those share a path (the rollout
+    scan is ``rollout``, not one of its layers), and their direction if
+    they share one.  Never raises: text that is no HLO gives ``{}``."""
+    try:
+        return _parse(hlo_text or "", scopes)
+    except Exception:
+        return {}
+
+
+def _parse(hlo_text: str, scopes) -> Dict[str, OpScope]:
+    by_computation: Dict[str, Dict[str, OpScope]] = {}
+    callers: List[Tuple[str, str, str]] = []  # (name, computation, callee)
+    runs = set()
+    computation = "?"
+    for line in hlo_text.splitlines():
+        if line[:1] not in (" ", "\t", ""):
+            match = _COMPUTATION_RE.match(line)
+            if match:
+                computation = match.group(2)
+                if match.group(1):
+                    runs.add(computation)
+            continue
+        match = _INSTRUCTION_RE.match(line)
+        if not match:
+            continue
+        name = match.group(1)
+        at = line.rfind("metadata={")
+        op_name = _OP_NAME_RE.match(line, at) if at >= 0 else None
+        scope = _op_scope(op_name.group(1), scopes) if op_name else None
+        if scope and scope.path:
+            by_computation.setdefault(computation, {})[name] = scope
+        elif scopes is not None:
+            callee = _CALLEE_RE.search(line)
+            if callee:
+                callers.append((name, computation, callee.group(1)))
+        for targets in _RUNS_RE.findall(line):
+            for target in ",".join(targets).split(","):
+                if target.strip():
+                    runs.add(target.strip().lstrip("%"))
+    # callees come before their callers in the text, so one pass in order
+    # lets a nested scan count in its parent's body
+    for name, computation, callee in callers:
+        scope = _shared_scope(by_computation.get(callee, {}).values())
+        if scope.path:
+            by_computation.setdefault(computation, {})[name] = scope
+    out: Dict[str, OpScope] = {}
+    for computation in runs:
+        out.update(by_computation.get(computation, {}))
+    return out
+
+
+def _shared_scope(called) -> OpScope:
+    called = list(called)
+    paths = [scope.path.split("/") for scope in called]
+    phases = [path[0] for path in paths]
+    phase = max(set(phases), key=phases.count) if phases else ""
+    if phases.count(phase) * 2 <= len(phases):
+        return OpScope("", None)
+    paths = [path for path in paths if path[0] == phase]
+    shared = 0
+    while all(len(p) > shared and p[shared] == paths[0][shared] for p in paths):
+        shared += 1
+    directions = {scope.direction for scope in called}
+    return OpScope(join(*paths[0][:shared]),
+                   directions.pop() if len(directions) == 1 else None)
+
+
+# ---------------------------------------------------------------------------
+# the newest step program handed out (bench_util.compile_train_step)
+# ---------------------------------------------------------------------------
+_executable: Any = None
+_scope_map: Optional[Dict[str, OpScope]] = None
+
+
+def register_step(executable: Any) -> None:
+    """Remember the newest step executable, and only it: registering does
+    no work (the text of the flagship step is megabytes), and an older
+    program is let go the moment a newer one is handed out."""
+    global _executable, _scope_map
+    _executable, _scope_map = executable, None
+
+
+def last_step_scope_map() -> Optional[Dict[str, OpScope]]:
+    """The scope map of the newest step program handed out, or ``None``
+    when there was none.  Computed when first asked for; the executable
+    is let go then and the map kept."""
+    global _executable, _scope_map
+    if _executable is not None:
+        _scope_map = scope_map_from_hlo(_executable.as_text())
+        _executable = None
+    return _scope_map

@@ -17,11 +17,10 @@ else is host-side (python dispatch, ``TraceAnnotation`` spans).
 
 Scope grouping: TPU device events often carry the full
 ``jit(...)/rollout/...`` op path in their args; CPU thunk events carry
-only the bare HLO instruction name.  :func:`scope_map_from_hlo`
+only the bare HLO instruction name.  ``scopes.scope_map_from_hlo``
 recovers the mapping from the compiled executable's optimized-HLO
-``op_name`` metadata (where the ``jax.named_scope("rollout")`` /
-``("update")`` annotations the trainers plant survive compilation), and
-the profiler stores it as a ``scope_map.json`` sidecar in the capture
+``op_name`` metadata (where the ``jax.named_scope`` annotations the
+trainers plant survive compilation), and the profiler stores it as a ``scope_map.json`` sidecar in the capture
 bundle so grouping works on any backend.
 
 Never-raises contract: a malformed capture yields ``ok=False`` and an
@@ -31,18 +30,12 @@ from __future__ import annotations
 
 import gzip
 import json
-import re
 from pathlib import Path
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
-# the phase annotations PR 6 plants in every trainer's fused step
-PHASE_SCOPES = ("rollout", "update")
-
-# optimized-HLO instruction with op_name metadata, e.g.
-#   %copy.340 = f32[...] copy(...), metadata={op_name="jit(main)/rollout/..."}
-_HLO_OP_NAME_RE = re.compile(
-    r'%?([A-Za-z0-9_.\-]+)\s*=\s*[^\n]*metadata=\{[^}]*op_name="([^"]*)"'
-)
+# the scope vocabulary and the HLO scope map live in scopes.py; this
+# module keeps the perfetto half
+from gymfx_tpu.telemetry.scopes import PHASE_SCOPES
 
 
 def find_trace_files(root: str) -> List[str]:
@@ -78,84 +71,6 @@ def _scope_from_path(path: str,
         if part in scopes:
             return part
     return None
-
-
-# computation header at column 0: `%region_2.101 (arg: ...) -> ... {`
-# or `ENTRY %main.2164 (...) -> ... {`
-_HLO_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([A-Za-z0-9_.\-]+)\s*[({]")
-# `%while.158 = (...) while(%tuple.5), condition=..., body=%region_2.101`
-_HLO_WHILE_RE = re.compile(
-    r"%?([A-Za-z0-9_.\-]+)\s*=[^\n]*?\bwhile\("
-    r"[^\n]*?body=%?([A-Za-z0-9_.\-]+)"
-)
-
-
-def scope_map_from_hlo(hlo_text: str,
-                       scopes: Optional[Sequence[str]] = PHASE_SCOPES,
-                       ) -> Dict[str, str]:
-    """``{instruction_name: scope}`` from optimized-HLO ``op_name``
-    metadata.  With ``scopes`` (default rollout/update) only
-    instructions under one of those named scopes are kept; with
-    ``scopes=None`` the full op path is returned instead.  Trace event
-    names on the CPU backend are the top-level optimized-HLO
-    instruction names, so this map is exactly the join key
-    :func:`group_by_scope` needs.
-
-    XLA's scan loops surface as ``while`` instructions that carry no
-    ``op_name`` of their own (the scan is a compiler artifact) yet hold
-    real self time in the trace (loop bookkeeping + inlined body work),
-    so an unscoped ``while`` inherits the strict-majority scope of the
-    instructions in its body computation — the rollout scan body is
-    wall-to-wall rollout-tagged ops."""
-    out: Dict[str, str] = {}
-    if scopes is None:
-        try:
-            for name, op_name in _HLO_OP_NAME_RE.findall(hlo_text or ""):
-                out[name] = op_name
-        except Exception:
-            return {}
-        return out
-    try:
-        # one line walk: per-instruction scope, per-computation scope
-        # histogram, and while -> body-computation edges
-        comp_counts: Dict[str, Dict[str, int]] = {}
-        while_edges: List[Tuple[str, str, str]] = []  # (name, comp, body)
-        comp = "?"
-        for line in (hlo_text or "").splitlines():
-            if line[:1] not in (" ", "\t", ""):
-                match = _HLO_COMP_RE.match(line)
-                if match:
-                    comp = match.group(1)
-                continue
-            match = _HLO_OP_NAME_RE.search(line)
-            if match:
-                scope = _scope_from_path(match.group(2), scopes)
-                if scope is not None:
-                    out[match.group(1)] = scope
-                    counts = comp_counts.setdefault(comp, {})
-                    counts[scope] = counts.get(scope, 0) + 1
-            if "while(" in line:
-                match = _HLO_WHILE_RE.search(line)
-                if match:
-                    while_edges.append((match.group(1), comp, match.group(2)))
-        # resolve unscoped whiles inner-to-outer so a nested scan feeds
-        # its parent's histogram (two passes reach any practical depth)
-        for _ in range(2):
-            for name, comp, body in while_edges:
-                if name in out:
-                    continue
-                counts = comp_counts.get(body, {})
-                total = sum(counts.values())
-                if not total:
-                    continue
-                scope, votes = max(counts.items(), key=lambda kv: kv[1])
-                if votes * 2 > total:
-                    out[name] = scope
-                    parent = comp_counts.setdefault(comp, {})
-                    parent[scope] = parent.get(scope, 0) + 1
-    except Exception:
-        return {}
-    return out
 
 
 def _merged_span_us(intervals: List[Tuple[float, float]]) -> float:

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 # DelayedLogger grew into the telemetry device stream (same delayed-
 # drain discipline, optionally feeding a MetricsRegistry/JSONL sink);
 # the original class name and construction stay importable from here.
+from gymfx_tpu.telemetry import scopes
 from gymfx_tpu.telemetry.device_stream import (  # noqa: F401
     DelayedLogger,
     DeviceMetricStream,
@@ -385,26 +386,33 @@ def minibatch_plan(fields, *, scheme: str, n_envs: int, horizon: int,
                       trajectories — contiguous DMA, the wide-batch
                       HBM fix (VERDICT r4 #4) and the standard
                       recurrent sequence-minibatch treatment.
+
+    The re-layout here and every ``take`` carry the ``minibatch_take``
+    scope (telemetry/scopes.py).
     """
     if scheme == "env_permute":
-        source = jax.tree.map(lambda x: jnp.swapaxes(x, 0, 1), fields)
+        with jax.named_scope(scopes.MINIBATCH_TAKE):
+            source = jax.tree.map(lambda x: jnp.swapaxes(x, 0, 1), fields)
         mb = n_envs // minibatches
 
         def take(idx):
-            return jax.tree.map(
-                lambda x: x[idx].reshape(mb * horizon, *x.shape[2:]),
-                source,
-            )
+            with jax.named_scope(scopes.MINIBATCH_TAKE):
+                return jax.tree.map(
+                    lambda x: x[idx].reshape(mb * horizon, *x.shape[2:]),
+                    source,
+                )
 
         return n_envs, mb, take
 
     n_total = horizon * n_envs
-    source = jax.tree.map(
-        lambda x: x.reshape(n_total, *x.shape[2:]), fields
-    )
+    with jax.named_scope(scopes.MINIBATCH_TAKE):
+        source = jax.tree.map(
+            lambda x: x.reshape(n_total, *x.shape[2:]), fields
+        )
 
     def take(idx):
-        return jax.tree.map(lambda x: x[idx], source)
+        with jax.named_scope(scopes.MINIBATCH_TAKE):
+            return jax.tree.map(lambda x: x[idx], source)
 
     return n_total, n_total // minibatches, take
 

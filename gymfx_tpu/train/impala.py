@@ -32,6 +32,7 @@ import optax
 from gymfx_tpu.core import env as env_core
 from gymfx_tpu.core.runtime import Environment
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
+from gymfx_tpu.telemetry import scopes
 from gymfx_tpu.train.common import masked_reset
 from gymfx_tpu.train.policies import (
     flatten_obs,
@@ -494,9 +495,9 @@ class ImpalaTrainer:
     def _train_step_impl(self, state: ImpalaState, data=None):
         # phase-named XLA ops for profiler attribution (trace-time
         # metadata only; numerics unchanged) — same scheme as PPO
-        with jax.named_scope("rollout"):
+        with jax.named_scope(scopes.ROLLOUT):
             inter, rollout_out = self._rollout_phase(state, data)
-        with jax.named_scope("update"):
+        with jax.named_scope(scopes.UPDATE):
             return self._update_phase(inter, rollout_out, data)
 
     # ------------------------------------------------------------------

@@ -19,6 +19,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from gymfx_tpu.telemetry import scopes
+
 
 class ObsSpec(NamedTuple):
     """Static layout of a Dict observation: the sorted key order plus
@@ -243,31 +245,39 @@ class RingTransformerEncoder(nn.Module):
             pos_local = pos
         x = x + pos_local.astype(self.dtype)
 
+        # the two halves of each block, by name, for a device trace
+        # (telemetry/scopes.py: metadata only; a scope is no flax module, so
+        # the parameters keep their names)
         for _ in range(self.n_layers):
-            y = nn.LayerNorm(dtype=self.dtype)(x)
-            q = nn.DenseGeneral((self.n_heads, head_dim), dtype=self.dtype)(y)
-            k = nn.DenseGeneral((self.n_heads, head_dim), dtype=self.dtype)(y)
-            v = nn.DenseGeneral((self.n_heads, head_dim), dtype=self.dtype)(y)
-            if self.seq_axis is not None:
-                sp_attention = (
-                    ulysses_attention_inner
-                    if self.sp_backend == "ulysses"
-                    else ring_attention_inner
-                )
-                a = sp_attention(
-                    q, k, v, axis=self.seq_axis, n_shards=self.seq_shards
-                )
-            else:
-                a = dense_window_attention(q, k, v)
-            y = nn.DenseGeneral(
-                self.d_model, axis=(-2, -1), dtype=self.dtype
-            )(a)
-            x = x + y
-            y = nn.LayerNorm(dtype=self.dtype)(x)
-            y = nn.Dense(self.d_model * 4, dtype=self.dtype)(y)
-            y = nn.gelu(y)
-            y = nn.Dense(self.d_model, dtype=self.dtype)(y)
-            x = x + y
+            with jax.named_scope(scopes.ATTENTION):
+                y = nn.LayerNorm(dtype=self.dtype)(x)
+                q = nn.DenseGeneral(
+                    (self.n_heads, head_dim), dtype=self.dtype)(y)
+                k = nn.DenseGeneral(
+                    (self.n_heads, head_dim), dtype=self.dtype)(y)
+                v = nn.DenseGeneral(
+                    (self.n_heads, head_dim), dtype=self.dtype)(y)
+                if self.seq_axis is not None:
+                    sp_attention = (
+                        ulysses_attention_inner
+                        if self.sp_backend == "ulysses"
+                        else ring_attention_inner
+                    )
+                    a = sp_attention(
+                        q, k, v, axis=self.seq_axis, n_shards=self.seq_shards
+                    )
+                else:
+                    a = dense_window_attention(q, k, v)
+                y = nn.DenseGeneral(
+                    self.d_model, axis=(-2, -1), dtype=self.dtype
+                )(a)
+                x = x + y
+            with jax.named_scope(scopes.FFN):
+                y = nn.LayerNorm(dtype=self.dtype)(x)
+                y = nn.Dense(self.d_model * 4, dtype=self.dtype)(y)
+                y = nn.gelu(y)
+                y = nn.Dense(self.d_model, dtype=self.dtype)(y)
+                x = x + y
 
         x = nn.LayerNorm(dtype=self.dtype)(x)
         pooled = jnp.mean(x, axis=-2)

@@ -29,6 +29,7 @@ import optax
 from gymfx_tpu.core import env as env_core
 from gymfx_tpu.core.runtime import Environment
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
+from gymfx_tpu.telemetry import scopes
 from gymfx_tpu.train.common import masked_reset
 from gymfx_tpu.train.policies import (
     flatten_obs,
@@ -340,41 +341,46 @@ class PPOTrainer:
 
         def body(carry, _):
             env_states, obs_vec, pcarry, rng = carry
-            rng, k = jax.random.split(rng)
-            dist, value, pcarry2 = fwd(params, obs_vec, pcarry)
-            if continuous:
-                mu, log_std = dist
-                action = sample_normal(k, dist)
-                logp = _normal_logp(action, mu, log_std)
-            else:
-                logits = dist
-                keys = jax.random.split(k, logits.shape[0])
-                action = jax.vmap(jax.random.categorical)(keys, logits)
-                logp = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits), action[:, None], axis=1
-                )[:, 0]
+            with jax.named_scope(scopes.POLICY_ACT):
+                rng, k = jax.random.split(rng)
+                dist, value, pcarry2 = fwd(params, obs_vec, pcarry)
+                if continuous:
+                    mu, log_std = dist
+                    action = sample_normal(k, dist)
+                    logp = _normal_logp(action, mu, log_std)
+                else:
+                    logits = dist
+                    keys = jax.random.split(k, logits.shape[0])
+                    action = jax.vmap(jax.random.categorical)(keys, logits)
+                    logp = jnp.take_along_axis(
+                        jax.nn.log_softmax(logits), action[:, None], axis=1
+                    )[:, 0]
+            # env_core.step plants env_step/{tape_read,dynamics,obs}
             env_states2, obs2, reward, done, _ = vstep(
                 cfg, eparams, data, env_states, action
             )
-            obs_vec2 = vencode(obs2)
-            # auto-reset terminated envs (fresh episode, fresh carry)
-            env_states2 = masked_reset(done, reset_state, env_states2)
-            obs_vec2 = masked_reset(done, reset_vec, obs_vec2)
-            pcarry2 = masked_reset(done, carry0, pcarry2)
-            out = dict(
-                # store obs in the resolved collect dtype (never wider
-                # than the policy's entry cast — resolve_collect_dtype):
-                # the (T*N, obs_dim) buffer is the rollout's widest
-                # write and the update's widest read, and it halves
-                # under bf16
-                obs=obs_vec.astype(self.pcfg.collect_dtype),
-                action=action, logp=logp, value=value,
-                reward=reward.astype(jnp.float32), done=done,
-                # the carry that ENTERED this step — replayed during the
-                # minibatch passes so recurrent policies see exactly the
-                # state they acted with (stored-state recurrent replay)
-                pcarry=pcarry,
-            )
+            with jax.named_scope(scopes.join(scopes.ENV_STEP, scopes.OBS)):
+                obs_vec2 = vencode(obs2)
+            with jax.named_scope(scopes.AUTO_RESET):
+                # auto-reset terminated envs (fresh episode, fresh carry)
+                env_states2 = masked_reset(done, reset_state, env_states2)
+                obs_vec2 = masked_reset(done, reset_vec, obs_vec2)
+                pcarry2 = masked_reset(done, carry0, pcarry2)
+                out = dict(
+                    # store obs in the resolved collect dtype (never wider
+                    # than the policy's entry cast — resolve_collect_dtype):
+                    # the (T*N, obs_dim) buffer is the rollout's widest
+                    # write and the update's widest read, and it halves
+                    # under bf16
+                    obs=obs_vec.astype(self.pcfg.collect_dtype),
+                    action=action, logp=logp, value=value,
+                    reward=reward.astype(jnp.float32), done=done,
+                    # the carry that ENTERED this step — replayed during
+                    # the minibatch passes so recurrent policies see
+                    # exactly the state they acted with (stored-state
+                    # recurrent replay)
+                    pcarry=pcarry,
+                )
             return (env_states2, obs_vec2, pcarry2, rng), out
 
         (env_states, obs_vec, pcarry, rng), traj = jax.lax.scan(
@@ -382,7 +388,8 @@ class PPOTrainer:
             length=self.pcfg.horizon,
         )
         # bootstrap value for the final obs
-        logits, last_value, _ = fwd(params, obs_vec, pcarry)
+        with jax.named_scope(scopes.POLICY_ACT):
+            logits, last_value, _ = fwd(params, obs_vec, pcarry)
         return env_states, obs_vec, pcarry, rng, traj, last_value
 
     def _gae(self, traj, last_value):
@@ -413,7 +420,10 @@ class PPOTrainer:
             # staging every minibatch activation through HBM; on TPU the
             # whole loss GEMM chain then runs VMEM-resident
             fwd = jax.remat(fwd)
-        dist, value, _ = fwd(params, batch["obs"], batch["pcarry"])
+        # inside value_and_grad the scope reads jvp(policy_forward) on the
+        # forward pass and transpose(jvp(policy_forward)) on the backward
+        with jax.named_scope(scopes.POLICY_FORWARD):
+            dist, value, _ = fwd(params, batch["obs"], batch["pcarry"])
         if self._continuous:
             mu, log_std = dist
             logp = _normal_logp(batch["action"], mu, log_std)
@@ -484,7 +494,8 @@ class PPOTrainer:
         env_states, obs_vec, pcarry_end, rng = (
             state.env_states, state.obs_vec, state.policy_carry, state.rng
         )
-        advs, returns = self._gae(traj, last_value)
+        with jax.named_scope(scopes.GAE):
+            advs, returns = self._gae(traj, last_value)
 
         # Stored-state recurrent replay: each step replays with the carry
         # it was collected under (R2D2-style stored state), so at the
@@ -517,29 +528,33 @@ class PPOTrainer:
 
         def epoch_body(carry, k):
             params, opt_state = carry
-            perm = jax.random.permutation(k, n_perm)
+            with jax.named_scope(scopes.MINIBATCH_TAKE):
+                perm = jax.random.permutation(k, n_perm)
 
             def mb_body(carry, i):
                 params, opt_state = carry
-                idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
-                batch = take(idx)
-                (loss, aux), grads = jax.value_and_grad(self._loss, has_aux=True)(
-                    params, batch
-                )
-                updates, new_opt_state = self.optimizer.update(
-                    grads, opt_state, params
-                )
-                new_params = optax.apply_updates(params, updates)
-                if guard:
-                    # non-finite loss/grads: keep last-good params and
-                    # opt-state bit-for-bit (one NaN minibatch would
-                    # otherwise poison the Adam moments forever)
-                    ok = jnp.isfinite(loss) & tree_all_finite(grads)
-                    params = select_tree(ok, new_params, params)
-                    opt_state = select_tree(ok, new_opt_state, opt_state)
-                else:
-                    ok = jnp.asarray(True)
-                    params, opt_state = new_params, new_opt_state
+                with jax.named_scope(scopes.MINIBATCH_TAKE):
+                    idx = jax.lax.dynamic_slice_in_dim(perm, i * mb, mb)
+                batch = take(idx)  # minibatch_plan plants the same scope
+                with jax.named_scope(scopes.LOSS):
+                    (loss, aux), grads = jax.value_and_grad(
+                        self._loss, has_aux=True
+                    )(params, batch)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    updates, new_opt_state = self.optimizer.update(
+                        grads, opt_state, params
+                    )
+                    new_params = optax.apply_updates(params, updates)
+                    if guard:
+                        # non-finite loss/grads: keep last-good params and
+                        # opt-state bit-for-bit (one NaN minibatch would
+                        # otherwise poison the Adam moments forever)
+                        ok = jnp.isfinite(loss) & tree_all_finite(grads)
+                        params = select_tree(ok, new_params, params)
+                        opt_state = select_tree(ok, new_opt_state, opt_state)
+                    else:
+                        ok = jnp.asarray(True)
+                        params, opt_state = new_params, new_opt_state
                 return (params, opt_state), (loss, aux, ok)
 
             (params, opt_state), (losses, auxes, oks) = jax.lax.scan(
@@ -552,63 +567,64 @@ class PPOTrainer:
             epoch_body, (params, opt_state), jnp.stack(ks)
         )
 
-        if guard:
-            okf = oks.astype(jnp.float32)
-            n_ok = okf.sum()
+        with jax.named_scope(scopes.GUARD):
+            if guard:
+                okf = oks.astype(jnp.float32)
+                n_ok = okf.sum()
 
-            def mmean(x):
-                # mean over SURVIVING minibatches only; NaN iff every
-                # update this step was skipped (an honest signal — a
-                # finite number here would hide total divergence)
-                safe = jnp.where(jnp.isfinite(x), x, 0.0)
-                return jnp.where(
-                    n_ok > 0, (safe * okf).sum() / jnp.maximum(n_ok, 1.0),
-                    jnp.nan,
+                def mmean(x):
+                    # mean over SURVIVING minibatches only; NaN iff every
+                    # update this step was skipped (an honest signal — a
+                    # finite number here would hide total divergence)
+                    safe = jnp.where(jnp.isfinite(x), x, 0.0)
+                    return jnp.where(
+                        n_ok > 0, (safe * okf).sum() / jnp.maximum(n_ok, 1.0),
+                        jnp.nan,
+                    )
+
+                metrics = dict(
+                    loss=mmean(losses),
+                    policy_loss=mmean(auxes["policy_loss"]),
+                    value_loss=mmean(auxes["value_loss"]),
+                    entropy=mmean(auxes["entropy"]),
+                    mean_reward=traj["reward"].mean(),
+                    mean_episode_done=traj["done"].mean(),
+                    nonfinite_skips=(1.0 - okf).sum(),
+                    guard_updates=jnp.asarray(
+                        float(pcfg.epochs * pcfg.minibatches), jnp.float32
+                    ),
                 )
-
-            metrics = dict(
-                loss=mmean(losses),
-                policy_loss=mmean(auxes["policy_loss"]),
-                value_loss=mmean(auxes["value_loss"]),
-                entropy=mmean(auxes["entropy"]),
-                mean_reward=traj["reward"].mean(),
-                mean_episode_done=traj["done"].mean(),
-                nonfinite_skips=(1.0 - okf).sum(),
-                guard_updates=jnp.asarray(
-                    float(pcfg.epochs * pcfg.minibatches), jnp.float32
-                ),
-            )
-            # quarantine: envs whose rollout or carried state went
-            # non-finite restart from a fresh episode — NaN equity would
-            # otherwise stick and re-poison every later rollout
-            poison = quarantine_mask(
-                {
-                    "reward": traj["reward"],
-                    "obs": traj["obs"],
-                    "value": traj["value"],
-                    "logp": traj["logp"],
-                },
-                env_axis=1,
-            ) | quarantine_mask(
-                # NaN-only for carried state: env peak/min/max trackers
-                # hold ±inf sentinels by design (core/types.py)
-                {"obs_vec": obs_vec, "env_states": env_states},
-                env_axis=0, mode="nan",
-            )
-            carry0 = self.policy.initial_carry(())
-            env_states = masked_reset(poison, reset_state, env_states)
-            obs_vec = masked_reset(poison, reset_vec, obs_vec)
-            pcarry_end = masked_reset(poison, carry0, pcarry_end)
-            metrics["poisoned_env_resets"] = poison.astype(jnp.float32).sum()
-        else:
-            metrics = dict(
-                loss=losses.mean(),
-                policy_loss=auxes["policy_loss"].mean(),
-                value_loss=auxes["value_loss"].mean(),
-                entropy=auxes["entropy"].mean(),
-                mean_reward=traj["reward"].mean(),
-                mean_episode_done=traj["done"].mean(),
-            )
+                # quarantine: envs whose rollout or carried state went
+                # non-finite restart from a fresh episode — NaN equity would
+                # otherwise stick and re-poison every later rollout
+                poison = quarantine_mask(
+                    {
+                        "reward": traj["reward"],
+                        "obs": traj["obs"],
+                        "value": traj["value"],
+                        "logp": traj["logp"],
+                    },
+                    env_axis=1,
+                ) | quarantine_mask(
+                    # NaN-only for carried state: env peak/min/max trackers
+                    # hold ±inf sentinels by design (core/types.py)
+                    {"obs_vec": obs_vec, "env_states": env_states},
+                    env_axis=0, mode="nan",
+                )
+                carry0 = self.policy.initial_carry(())
+                env_states = masked_reset(poison, reset_state, env_states)
+                obs_vec = masked_reset(poison, reset_vec, obs_vec)
+                pcarry_end = masked_reset(poison, carry0, pcarry_end)
+                metrics["poisoned_env_resets"] = poison.astype(jnp.float32).sum()
+            else:
+                metrics = dict(
+                    loss=losses.mean(),
+                    policy_loss=auxes["policy_loss"].mean(),
+                    value_loss=auxes["value_loss"].mean(),
+                    entropy=auxes["entropy"].mean(),
+                    mean_reward=traj["reward"].mean(),
+                    mean_episode_done=traj["done"].mean(),
+                )
         new_state = TrainState(
             params, opt_state, env_states, obs_vec, pcarry_end, rng
         )
@@ -618,9 +634,9 @@ class PPOTrainer:
         # named_scope labels the XLA ops by phase (trace-time metadata
         # only — the compiled program and numerics are unchanged), so a
         # profiler capture attributes device time to rollout vs update
-        with jax.named_scope("rollout"):
+        with jax.named_scope(scopes.ROLLOUT):
             inter, rollout_out = self._rollout_phase(state, data)
-        with jax.named_scope("update"):
+        with jax.named_scope(scopes.UPDATE):
             return self._update_phase(inter, rollout_out, data)
 
     # ------------------------------------------------------------------

@@ -154,24 +154,65 @@ ENTRY %main.30 (Arg_0.1: f32[4]) -> f32[] {
 """
 
 
-def test_scope_map_from_hlo_metadata_and_while_bodies():
-    from gymfx_tpu.telemetry.trace_parse import scope_map_from_hlo
-
-    m = scope_map_from_hlo(HLO_SNIPPET)
-    assert m["dot.3"] == "rollout" and m["add.4"] == "rollout"
-    assert m["dot.7"] == "update" and m["fusion.1"] == "update"
+# the function moved to telemetry/scopes.py (PR 26) and gives an OpScope
+# (path, direction) per instruction; the observatory reads the path
+SCOPE_MAP_CASES = {
+    "ops under the rollout scope": ("dot.3", "rollout"),
+    "ops under the rollout scope, second": ("add.4", "rollout"),
+    "ops under the update scope, in a scan body": ("dot.7", "update"),
+    "ops under the update scope, in the entry": ("fusion.1", "update"),
     # the scan `while` carries no op_name of its own: it inherits the
     # strict-majority scope of its body computation
-    assert m["while.9"] == "rollout"
-    assert m["while.19"] == "update"
+    "the rollout scan inherits its body's scope": ("while.9", "rollout"),
+    "the update scan inherits its body's scope": ("while.19", "update"),
     # the untagged copy stays out of the map (honestly unattributed)
-    assert "copy.3" not in m
+    "an untagged copy stays out": ("copy.3", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCOPE_MAP_CASES))
+@pytest.mark.parametrize("vocabulary", ["phases", "layers"])
+def test_scope_map_from_hlo_metadata_and_while_bodies(case, vocabulary):
+    from gymfx_tpu.telemetry.scopes import (
+        PHASE_SCOPES,
+        SCOPE_NAMES,
+        scope_map_from_hlo,
+    )
+
+    # what the profiler asks for (the two phases) and the default (every
+    # layer) agree where the text names no layer
+    scopes = PHASE_SCOPES if vocabulary == "phases" else SCOPE_NAMES
+    m = scope_map_from_hlo(HLO_SNIPPET, scopes=scopes)
+    name, path = SCOPE_MAP_CASES[case]
+    assert (m[name].path if name in m else None) == path
+    assert m is not None and all(s.direction is None for s in m.values())
+
+
+def test_scope_map_from_hlo_full_paths_and_garbage():
+    from gymfx_tpu.telemetry.scopes import scope_map_from_hlo
+
     # scopes=None returns full op paths instead
     full = scope_map_from_hlo(HLO_SNIPPET, scopes=None)
-    assert full["dot.3"].endswith("rollout/while/body/dot_general")
+    assert full["dot.3"].path.endswith("rollout/while/body/dot_general")
     # never raises on garbage
     assert scope_map_from_hlo(None) == {}
     assert scope_map_from_hlo("not hlo at all") == {}
+
+
+def test_profiler_sidecar_holds_plain_scope_strings(tmp_path):
+    """The capture bundle's scope_map.json stays {op: "rollout"|"update"}
+    (what group_by_scope and the report read) now that scope_map_from_hlo
+    gives (path, direction) pairs."""
+    from gymfx_tpu.telemetry.profiler import SCOPE_MAP_NAME, ProfilerSession
+
+    session = ProfilerSession(str(tmp_path), supersteps="0")
+    session.set_workload_source(lambda it, k: {"hlo_text": HLO_SNIPPET})
+    assert session.start_capture(0, 1)
+    bundle = session.finish_capture()
+    assert bundle is not None
+    sidecar = json.loads((Path(bundle) / SCOPE_MAP_NAME).read_text())
+    assert sidecar["while.9"] == "rollout" and sidecar["dot.7"] == "update"
+    assert "copy.3" not in sidecar
 
 
 # ----------------------------------------------------------------------

@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from gymfx_tpu.config import DEFAULT_VALUES
+from gymfx_tpu.telemetry import scopes
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +64,17 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _assert_kernels_named(hlo, name):
+    """Every Mosaic custom call of the compiled text is named by the
+    kernel's ``name`` (telemetry/scopes.py): ``%fused_attention_fwd.3`` in a
+    step, where the call sits inside other scopes; called bare under a
+    transform, as here, the transform wraps it
+    (``%vmap_env_dynamics_fill_brackets_.1``)."""
+    names = [line.split("=")[0].split()[-1] for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert names and all(name in n for n in names), names
+
+
 # ---------------------------------------------------------------------------
 # fused window attention: (envs, window, heads, head_dim) of
 # RingTransformerEncoder (train/policies.py) — forward and backward
@@ -93,6 +105,9 @@ def test_fused_attention_compiles(one_chip, window, dtype, direction):
     hlo = _compile(fwd if direction == "fwd" else bwd, x, x, x,
                    sharding=one_chip)
     assert "tpu_custom_call" in hlo
+    # (the gradient of a sum needs no forward output: the backward alone)
+    _assert_kernels_named(hlo, {"fwd": scopes.KERNEL_ATTENTION_FWD,
+                                "bwd": scopes.KERNEL_ATTENTION_BWD}[direction])
 
 
 # ---------------------------------------------------------------------------
@@ -171,6 +186,7 @@ def test_env_fill_brackets_kernel_compiles(one_chip, over):
         _sds((n,), jnp.bool_), sharding=one_chip,
     )
     assert "tpu_custom_call" in hlo
+    _assert_kernels_named(hlo, scopes.KERNEL_FILL_BRACKETS)
 
 
 @pytest.mark.parametrize(
@@ -195,6 +211,7 @@ def test_env_mark_reward_kernel_compiles(one_chip, reward):
         flag, flag, sharding=one_chip,
     )
     assert "tpu_custom_call" in hlo
+    _assert_kernels_named(hlo, scopes.KERNEL_MARK_REWARD)
 
 
 # ---------------------------------------------------------------------------
