@@ -45,7 +45,7 @@ from gymfx_tpu.core.types import (
     EnvState,
     initial_state,
 )
-from gymfx_tpu.data.feed import MarketData
+from gymfx_tpu.data.feed import MarketData, read_bar
 from gymfx_tpu.ops.dispatch import kernel_interpret
 from gymfx_tpu.telemetry import scopes
 
@@ -86,7 +86,8 @@ def reset_at(
     r0 = data.row0
     state = initial_state(cfg)
     state = state._replace(t=t0)
-    state = broker.mark_to_market(state, data.close[t0 - r0], params)
+    bar = read_bar(data, t0)
+    state = broker.mark_to_market(state, bar["close"], params)
     state = state._replace(
         prev_equity_delta=state.equity_delta,
         price_window=jax.lax.dynamic_slice(
@@ -98,7 +99,7 @@ def reset_at(
             (cfg.window_size, cfg.n_features),
         ),
     )
-    return state, build_obs(state, data, cfg, params)
+    return state, build_obs(state, data, cfg, params, bar=bar)
 
 
 def step(
@@ -111,8 +112,9 @@ def step(
     """Pure step. Returns (state, obs, reward, done, info)."""
     # the step's layers, by name, for a device trace (telemetry/scopes.py:
     # metadata only, the program does not change): every read of the tape
-    # by bar index is `tape_read`, the obs windows and build_obs are `obs`,
-    # all the rest is `dynamics`
+    # by bar index is `tape_read` (one packed row per distinct index,
+    # data/feed.py read_bar), the obs windows and build_obs are `obs`, all
+    # the rest is `dynamics`
     with jax.named_scope(scopes.ENV_STEP):
         return _step(cfg, params, data, state, action)
 
@@ -150,24 +152,18 @@ def _step(cfg, params, data, state, action):
         act_strategy = live & ~exhausted          # warmup or advancing step
 
         t_new = jnp.where(advance, state.t + 1, state.t)
-    r0 = data.row0  # shard-local rebase (0 when fully resident)
     with jax.named_scope(scopes.TAPE_READ):
-        o = data.open[t_new - r0]
-        h = data.high[t_new - r0]
-        l = data.low[t_new - r0]
-        c = data.close[t_new - r0]
-        mow = data.minute_of_week[t_new - r0]
-        # the rollover accrual (2b below) and the LOB venue's scenario
-        # bitmask (feed=scengen; a static gate, so replay feeds never trace
-        # the scen_flags leaf), read here for the arms that use them
-        accrual_rate = (
-            data.rollover_accrual[t_new - r0] if cfg.financing_enabled
-            else None
-        )
-        scen = (
-            data.scen_flags[t_new - r0]
-            if cfg.venue == "lob" and cfg.lob_flow_from_scengen else None
-        )
+        # the new bar's packed row, fetched once; what no arm uses is dead
+        bar = read_bar(data, t_new)
+    o, h, l, c = bar["open"], bar["high"], bar["low"], bar["close"]
+    mow = bar["minute_of_week"]
+    # the rollover accrual (2b below) and the LOB venue's scenario bitmask
+    # (feed=scengen), for the arms that use them
+    accrual_rate = bar["rollover_accrual"] if cfg.financing_enabled else None
+    scen = (
+        bar["scen_flags"]
+        if cfg.venue == "lob" and cfg.lob_flow_from_scengen else None
+    )
 
     with jax.named_scope(scopes.DYNAMICS):
         st = state._replace(
@@ -311,7 +307,9 @@ def _step(cfg, params, data, state, action):
             )
     if cfg.n_features > 0:
         with jax.named_scope(scopes.TAPE_READ):
-            new_feat_row = data.padded_features[t_new + cfg.window_size - r0]
+            new_feat_row = data.padded_features[
+                t_new + cfg.window_size - data.row0
+            ]
         with jax.named_scope(scopes.OBS):
             new_feat = jnp.concatenate(
                 [st.feat_window[1:], new_feat_row[None, :]]
@@ -330,9 +328,13 @@ def _step(cfg, params, data, state, action):
             st, base_reward = rewards.compute_reward(st, cfg, params, live)
         fc_row = jnp.minimum(st.t + 1, n - 1)
     with jax.named_scope(scopes.TAPE_READ):
-        force_close = data.force_close[fc_row - r0]
+        # one bar ahead: the force-close block here, and with the calendar
+        # block again in build_obs / build_info, from the same row
+        next_bar = read_bar(data, fc_row)
     with jax.named_scope(scopes.DYNAMICS):
-        penalty = rewards.force_close_penalty(st, force_close, cfg, params)
+        penalty = rewards.force_close_penalty(
+            st, next_bar["force_close"], cfg, params
+        )
         penalty = jnp.where(live, penalty, 0.0)
         reward = base_reward - penalty
 
@@ -358,8 +360,10 @@ def _step(cfg, params, data, state, action):
         )
 
     with jax.named_scope(scopes.OBS):
-        obs = build_obs(st, data, cfg, params)
-        info = build_info(st, data, cfg, params, event_info)
+        obs = build_obs(st, data, cfg, params, bar=bar, next_bar=next_bar)
+        info = build_info(
+            st, data, cfg, params, event_info, bar=bar, next_bar=next_bar
+        )
     info["reward"] = reward
     info["base_reward"] = base_reward
     info["force_close_reward_penalty"] = penalty
@@ -397,11 +401,12 @@ def _event_overlay(state, a, data: MarketData, cfg: EnvConfig, params: EnvParams
     Reads engineered no-trade columns at the upcoming row and blocks new
     entries / force-flattens open positions during event windows."""
     n = cfg.n_bars
-    row = jnp.minimum(jnp.minimum(state.t + 1, n), n - 1) - data.row0
+    row = jnp.minimum(jnp.minimum(state.t + 1, n), n - 1)
     with jax.named_scope(scopes.TAPE_READ):
-        no_trade_value = data.ev_no_trade[row]
-        spread_mult = data.ev_spread_mult[row]
-        slip_mult = data.ev_slip_mult[row]
+        upcoming = read_bar(data, row)
+    no_trade_value = upcoming["ev_no_trade"]
+    spread_mult = upcoming["ev_spread_mult"]
+    slip_mult = upcoming["ev_slip_mult"]
     with jax.named_scope(scopes.DYNAMICS):
         active = no_trade_value >= params.event_no_trade_threshold
         pos_sign = jnp.sign(state.pos).astype(jnp.int32)

@@ -26,7 +26,7 @@ from typing import Any, Dict
 import jax.numpy as jnp
 
 from gymfx_tpu.data.calendar import CALENDAR_FEATURE_KEYS, FORCE_CLOSE_FEATURE_KEYS
-from gymfx_tpu.data.feed import MarketData
+from gymfx_tpu.data.feed import MarketData, read_bar
 from gymfx_tpu.core.types import (
     ACTION_DIAG_KEYS,
     EXEC_DIAG_KEYS,
@@ -110,13 +110,25 @@ def _scaled_features(win, mean, std, neutral, cfg: "EnvConfig"):
     )
 
 
+def _rows(state, data, n, bar, next_bar):
+    """The two packed tape rows obs and info are built from: the current
+    bar ``state.t`` and the bar one ahead, ``min(state.t + 1, n - 1)``.
+    The env step hands in the rows it already fetched; a reset reads."""
+    if bar is None:
+        bar = read_bar(data, state.t)
+    if next_bar is None:
+        next_bar = read_bar(data, jnp.minimum(state.t + 1, n - 1))
+    return bar, next_bar
+
+
 def build_obs(
-    state: EnvState, data: MarketData, cfg: EnvConfig, params: EnvParams
+    state: EnvState, data: MarketData, cfg: EnvConfig, params: EnvParams,
+    *, bar=None, next_bar=None,
 ) -> Dict[str, Any]:
-    w = cfg.window_size
     n = cfg.n_bars
     step = jnp.minimum(state.t + 1, n)  # == bar_index, clamped
     r0 = data.row0  # shard-local rebase for streamed data (0 resident)
+    bar, next_bar = _rows(state, data, n, bar, next_bar)
     obs: Dict[str, Any] = {}
 
     if cfg.n_features > 0:
@@ -126,7 +138,7 @@ def build_obs(
         neutral = data.feat_neutral[step - r0]
         obs["features"] = _scaled_features(win, mean, std, neutral, cfg)
 
-    price = data.close[state.t - r0]
+    price = bar["close"]
     prices = None
     if cfg.include_prices:
         prices = state.price_window  # streaming carry
@@ -159,14 +171,13 @@ def build_obs(
         )
         obs["steps_remaining_norm"] = jnp.asarray([remaining], dtype=jnp.float32)
 
-    row = jnp.minimum(step, n - 1) - r0
     if cfg.stage_b_force_close_obs:
-        fc = data.force_close[row]
+        fc = next_bar["force_close"]
         for i, key in enumerate(FORCE_CLOSE_FEATURE_KEYS):
             obs[key] = fc[i][None]
 
     if cfg.oanda_fx_calendar_obs:
-        cal = data.calendar[row]
+        cal = next_bar["calendar"]
         cal_map = dict(zip(CALENDAR_FEATURE_KEYS, cal))
         for key in CALENDAR_OBS_KEYS:
             obs[key] = cal_map[key][None]
@@ -200,13 +211,14 @@ def build_info(
     cfg: EnvConfig,
     params: EnvParams,
     event_info: Dict[str, Any] | None = None,
+    *, bar=None, next_bar=None,
 ) -> Dict[str, Any]:
     n = cfg.n_bars
-    r0 = data.row0  # shard-local rebase for streamed data (0 resident)
+    bar, next_bar = _rows(state, data, n, bar, next_bar)
     info: Dict[str, Any] = {
         "equity": params.initial_cash + state.equity_delta,
         "position": jnp.sign(state.pos).astype(jnp.int32),
-        "price": data.close[state.t - r0],
+        "price": bar["close"],
         "bar_index": state.t + 1,
         "total_bars": jnp.asarray(n, dtype=jnp.int32),
         "trades": state.trade_count,
@@ -224,20 +236,19 @@ def build_info(
     if event_info:
         info.update(event_info)
 
-    row = jnp.minimum(jnp.minimum(state.t + 1, n), n - 1) - r0
     if cfg.stage_b_force_close_obs:
-        fc = data.force_close[row]
+        fc = next_bar["force_close"]
         for i, key in enumerate(FORCE_CLOSE_FEATURE_KEYS):
             info[key] = fc[i]
     if cfg.oanda_fx_calendar_obs:
-        cal = data.calendar[row]
+        cal = next_bar["calendar"]
         for i, key in enumerate(CALENDAR_FEATURE_KEYS):
             info[key] = cal[i]
         initial = jnp.where(params.initial_cash == 0, 1.0, params.initial_cash)
         from gymfx_tpu.core import broker as _broker
 
         info["margin_closeout_percent"] = _broker.margin_closeout_percent(
-            state, data.close[state.t - r0], params, cfg.margin_model
+            state, bar["close"], params, cfg.margin_model
         ).astype(jnp.float32)
         info["margin_available_norm"] = (
             params.initial_cash + state.equity_delta

@@ -671,7 +671,7 @@ class PortfolioEnvironment:
             )
             for p, ds in zip(pairs, datasets)
         ]
-        stacked = MarketData(*(jnp.stack(leaves) for leaves in zip(*mds)))
+        stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves), *mds)
         closes = np.stack(
             [aligned[p]["CLOSE"].to_numpy(np.float64) for p in pairs], 1
         )

@@ -453,7 +453,9 @@ def encode_market_data(
     period = int(np.unique(minutes).size)
 
     for field in type(host)._fields:
-        if field == "row0":
+        # row0 is the shard's own; `bars` is the packed copy of columns
+        # encoded here one by one, packed again after the decode
+        if field in ("row0", "bars"):
             continue
         target = np.stack([np.asarray(getattr(sh, field)) for sh in shards])
         if field in RAW_FIELDS:
@@ -617,7 +619,7 @@ def _decode_shard_impl(columns: Tuple[ColumnSpec, ...], shard_bars: int,
     """
     import jax.numpy as jnp
 
-    from gymfx_tpu.data.feed import MarketData
+    from gymfx_tpu.data.feed import MarketData, pack_bars
     from gymfx_tpu.ops.dispatch import kernel_interpret
 
     slabs, bases, raws = slab["slabs"], slab["bases"], slab["raws"]
@@ -696,7 +698,7 @@ def _decode_shard_impl(columns: Tuple[ColumnSpec, ...], shard_bars: int,
                 for c in sorted(specs, key=lambda c: c.col)
             ]
             fields[field] = jnp.stack(cols, axis=1)
-    return MarketData(**fields)
+    return pack_bars(MarketData(**fields))
 
 
 def make_shard_decoder(tape: CompressedTape, mode: str):

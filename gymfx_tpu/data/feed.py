@@ -11,6 +11,16 @@ padded window sources, per-bar NY-calendar/force-close feature columns
 and leakage-safe scaling moments.  Every per-step computation inside
 ``jit`` is then a ``dynamic_slice`` + fused elementwise math — no pandas,
 no Python objects, no data-dependent shapes.
+
+The env step reads the tape ONE PACKED ROW PER BAR INDEX: the per-bar
+columns it reads (``BAR_COLUMNS``, in that order) ride side by side in
+``MarketData.bars``, a table whose row is a bar, and ``read_bar`` fetches
+the row once and takes it apart with static slices.  On the chip a
+gather costs per index, not per byte: four columns read one by one at
+the same index paid four times what the row of all twenty-four words
+pays once (PERF.md, PR 27).  ``pack_bars`` is the one packer; every
+producer of a ``MarketData`` ends in it, and whoever replaces a packed
+column packs again.
 """
 from __future__ import annotations
 
@@ -23,6 +33,19 @@ from gymfx_tpu.data import calendar as fxcal
 
 OHLC_COLUMNS = ("OPEN", "HIGH", "LOW", "CLOSE")
 
+# The per-bar columns the env step reads by bar index, in the order they
+# ride in a packed row: the new bar's prices, financing rate, session
+# minute and scenario bitmask (read at the new bar), the event overlay's
+# three (read at the upcoming bar) and the force-close and calendar
+# blocks (read one bar ahead).  ``volume`` and the feature tables are not
+# read by bar index in the step and stay out.
+BAR_COLUMNS = (
+    "open", "high", "low", "close", "rollover_accrual",
+    "minute_of_week", "scen_flags",
+    "ev_no_trade", "ev_spread_mult", "ev_slip_mult",
+    "force_close", "calendar",
+)
+
 
 class MarketData(NamedTuple):
     """Static-shaped per-dataset device arrays consumed by the env kernel.
@@ -32,6 +55,17 @@ class MarketData(NamedTuple):
     window at step ``t`` is a pure ``dynamic_slice`` at offset ``t``
     (reference front-pad semantics:
     preprocessor_plugins/default_preprocessor.py:47-52).
+
+    ``bars`` holds the ``BAR_COLUMNS`` a second time, packed by
+    :func:`pack_bars` into one ``(n, words)`` table per word width — one
+    table of 24 words (open, high, low, close, rollover_accrual,
+    minute_of_week, scen_flags, ev_no_trade, ev_spread_mult, ev_slip_mult,
+    force_close x4, calendar x10) when the compute dtype is 32 bits wide,
+    the prices apart from the 32-bit columns when it is not.  The env step
+    reads ONLY the table (:func:`read_bar`); the columns stay for their
+    other readers (crosscheck, the portfolio's account marks, the padded
+    windows, host-side tools).  A ``_replace`` of a packed column leaves
+    the table stale: pack again.
     """
 
     open: Any          # (n,) compute dtype
@@ -60,14 +94,108 @@ class MarketData(NamedTuple):
     row0: Any = 0
     # (n,) int32 per-bar scenario bitmask (scengen/params.py FLAG_*):
     # zeros on every replayed feed; generated feeds carry the active
-    # regime/overlay so venue=lob can thin its flow with the tape.
-    # Reads are gated behind the static lob_flow_from_scengen config
-    # flag, so replay-path programs never trace this leaf.
+    # regime/overlay so venue=lob can thin its flow with the tape (the
+    # step uses it only under the static lob_flow_from_scengen flag).
     scen_flags: Any = 0
+    # tuple of (n, words) tables, one per word width: BAR_COLUMNS packed
+    # row by row (pack_bars); what the env step reads (read_bar)
+    bars: Any = ()
 
     @property
     def n_bars(self) -> int:
         return int(self.close.shape[0])
+
+
+def _bar_layout(data: MarketData):
+    """How ``BAR_COLUMNS`` ride in ``data.bars``, from the columns' own
+    dtypes and shapes (static under ``jit``): one table per word width, in
+    order of first appearance, each a list of ``(field, first word, words,
+    dtype, trailing shape)``.  A table's dtype is that of its first field;
+    a field of another dtype of the same width rides as its bits."""
+    tables: Dict[int, list] = {}
+    for name in BAR_COLUMNS:
+        col = getattr(data, name)
+        # scen_flags left at its scalar default is a column of that value
+        dtype = np.dtype(getattr(col, "dtype", np.int32))
+        tail = tuple(np.shape(col)[1:])
+        fields = tables.setdefault(dtype.itemsize, [])
+        first = sum(f[2] for f in fields)
+        fields.append((name, first, int(np.prod(tail, dtype=int)), dtype, tail))
+    return list(tables.values())
+
+
+def _as_bits(x, dtype):
+    """``x`` as ``dtype`` of the same width, bit for bit (never a value
+    conversion: the int32 minute_of_week rides in a float32 table)."""
+    if x.dtype == dtype:
+        return x
+    if isinstance(x, np.ndarray):
+        return x.view(dtype)
+    import jax
+
+    return jax.lax.bitcast_convert_type(x, dtype)
+
+
+def pack_bars(data: MarketData) -> MarketData:
+    """``data`` with ``bars`` (re)built from its per-bar columns — THE
+    packer, for every producer (host numpy or device arrays, traced or
+    not) and after every replacement of a packed column."""
+    if isinstance(data.close, np.ndarray):
+        xp = np
+    else:
+        import jax.numpy as xp
+    n = data.close.shape[0]
+    tables = []
+    for fields in _bar_layout(data):
+        carrier = fields[0][3]
+        cols = []
+        for name, _first, words, dtype, _tail in fields:
+            col = getattr(data, name)
+            if np.ndim(col) == 0:
+                col = xp.full((n,), col, dtype)
+            cols.append(_as_bits(col.reshape(n, words), carrier))
+        tables.append(xp.concatenate(cols, axis=1))
+    return data._replace(bars=tuple(tables))
+
+
+def read_bar(data: MarketData, index) -> Dict[str, Any]:
+    """The packed row of global bar ``index``, taken apart: ``{field:
+    value}`` for every field of ``BAR_COLUMNS``, each with the dtype, the
+    trailing shape and the bits ``data.<field>[index - data.row0]`` has.
+
+    One gather per table and index — a field nobody uses is dead code, a
+    table nobody uses is no gather at all.  Out of range the index is
+    wrapped (negative) and clamped exactly like a column's ``[index]``.
+
+    Two things here are what the chip's compiler made of the alternatives
+    (PERF.md, PR 27; both are the same values either way):
+
+    * ``.at[i].get`` and not ``table[i]``: scalar indexing of a 2-D table
+      lowers to a dynamic slice with a start index PER DIMENSION, and the
+      gather pays for each (1.31 against 0.61 ms for 131,072 rows);
+    * the fields leave through ``optimization_barrier``, as arrays of their
+      own.  Under the trainers' ``vmap`` the row is an ``(envs, words)``
+      array, env-major with the words padded to 128 lanes; fields sliced
+      straight out of it hand that layout on to whatever they are
+      concatenated with, and the step's carried windows turned env-major
+      with it (flagship: obs 8 -> 114 ms, dynamics 20 -> 89 ms a train
+      step).  Behind the barrier a field is a plain vector over the envs,
+      as a column's gather was.
+    """
+    import jax
+
+    if not data.bars:
+        raise ValueError(
+            "MarketData.bars is empty: build the tape through pack_bars "
+            "(every producer in gymfx_tpu does)"
+        )
+    i = index - data.row0  # shard-local rebase (0 when fully resident)
+    out: Dict[str, Any] = {}
+    for table, fields in zip(data.bars, _bar_layout(data)):
+        row = table.at[i].get(mode="clip")
+        for name, first, words, dtype, tail in fields:
+            out[name] = _as_bits(row[first:first + words].reshape(tail), dtype)
+    return jax.lax.optimization_barrier(out)
 
 
 def _infer_timeframe_hours(config: Dict[str, Any]) -> float:
@@ -244,7 +372,7 @@ class MarketDataset:
                 return np.asarray(x, dtype=dt)
 
         f32 = np.float32
-        return MarketData(
+        return pack_bars(MarketData(
             open=A(o, dtype),
             high=A(h, dtype),
             low=A(l, dtype),
@@ -264,7 +392,7 @@ class MarketDataset:
             feat_neutral=A(feat_neutral, bool),
             row0=np.int32(0),
             scen_flags=A(np.zeros(n, np.int32), np.int32),
-        )
+        ))
 
 
 def _build_feature_tensors(
@@ -342,9 +470,11 @@ def market_data_nbytes(data: MarketData) -> int:
     """Total array bytes of a MarketData pytree (host or device)."""
     total = 0
     for leaf in data:
-        nbytes = getattr(leaf, "nbytes", None)
-        if nbytes is not None:
-            total += int(nbytes)
+        # `bars` is a tuple of tables; every other field one array or scalar
+        for arr in leaf if isinstance(leaf, tuple) else (leaf,):
+            nbytes = getattr(arr, "nbytes", None)
+            if nbytes is not None:
+                total += int(nbytes)
     return total
 
 
@@ -411,6 +541,7 @@ def shard_market_data(data: MarketData, start: int, shard_bars: int,
         feat_neutral=data.feat_neutral[feat],
         row0=np.int32(start),
         scen_flags=data.scen_flags[bar],
+        bars=tuple(table[bar] for table in data.bars),
     )
 
 

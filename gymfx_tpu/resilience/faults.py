@@ -417,6 +417,8 @@ def contaminate_market_data(
     """
     import jax.numpy as jnp
 
+    from gymfx_tpu.data.feed import pack_bars
+
     bar_idx = np.asarray(sorted(set(int(b) for b in bars)), dtype=np.int64)
     if bar_idx.size == 0:
         return data
@@ -438,7 +440,7 @@ def contaminate_market_data(
             replace["padded_close"] = jnp.asarray(
                 padded, dtype=data.padded_close.dtype
             )
-    return data._replace(**replace)
+    return pack_bars(data._replace(**replace))
 
 
 def nonfinite_report(data: Any) -> Dict[str, int]:

@@ -22,7 +22,11 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import pandas as pd
 
-from gymfx_tpu.data.feed import MarketDataset, _infer_timeframe_hours
+from gymfx_tpu.data.feed import (
+    MarketDataset,
+    _infer_timeframe_hours,
+    pack_bars,
+)
 
 from .params import ScenarioParams, scenario_params
 
@@ -248,7 +252,7 @@ class ScenGenDataset(MarketDataset):
             flags = jnp.asarray(self.scen_flags, jnp.int32)
         else:
             flags = np.asarray(self.scen_flags, np.int32)
-        return md._replace(scen_flags=flags)
+        return pack_bars(md._replace(scen_flags=flags))
 
     def sliced(self, sl: slice) -> "ScenGenDataset":
         """Row-slice (chronological eval_split support) keeping frame

@@ -19,6 +19,8 @@ from typing import Any, Dict
 
 import numpy as np
 
+from gymfx_tpu.data.feed import pack_bars
+
 from .params import (
     FLAG_CRASH,
     FLAG_DROUGHT,
@@ -117,4 +119,4 @@ def apply_scengen_stress(
     if prev.shape != flags.shape:  # replay feeds carry the scalar 0
         prev = np.zeros(n, np.int32)
     replace["scen_flags"] = jnp.asarray(prev | flags, jnp.int32)
-    return data._replace(**replace)
+    return pack_bars(data._replace(**replace))
