@@ -84,8 +84,10 @@ def parse_args(argv=None):
     parser.add_argument(
         "--policy",
         choices=["mlp", "lstm", "transformer", "transformer_ring",
-                 "transformer_ulysses"],
+                 "transformer_ulysses", "mla_moe_decoder"],
     )
+    # a policy's widths as JSON text (a decoder trunk's are a nested object)
+    parser.add_argument("--policy_kwargs", type=str)
     parser.add_argument("--checkpoint_dir", type=str)
     parser.add_argument("--train_total_steps", type=int)
 

@@ -102,7 +102,7 @@ DEFAULT_VALUES = {
     "eval_data_file": None,
     # policy: unset by default — PPO defaults to "mlp", IMPALA to "lstm";
     # pass --policy mlp|lstm|transformer|transformer_ring|
-    # transformer_ulysses to override.
+    # transformer_ulysses|mla_moe_decoder to override.
     "policy": None,
 
     # ---- resilience (docs/resilience.md) ----

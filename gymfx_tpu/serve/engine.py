@@ -250,6 +250,10 @@ class InferenceEngine:
                     (obs_b, carry_b),
                 )
 
+        elif getattr(policy, "takes_batch", False):
+            # a policy that takes the batch itself (an expert layer sorts
+            # the tokens of the whole bucket) is called on it as it is
+            batched = single
         else:
 
             def batched(params, obs_b, carry_b):
@@ -821,13 +825,14 @@ def engine_from_config(
         make_obs_encoder,
         make_obs_spec,
         make_trainer_policy,
+        policy_kwargs_from,
     )
 
     scfg = serve_config_from(config)
     if env is None:
         env = Environment(config)
     policy_name = str(config.get("policy") or "mlp")
-    policy_kwargs = dict(config.get("policy_kwargs") or {})
+    policy_kwargs = policy_kwargs_from(config)
     ckpt_dir = config.get("checkpoint_dir")
     if ckpt_dir:
         from gymfx_tpu.train.checkpoint import read_metadata

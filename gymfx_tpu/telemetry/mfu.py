@@ -14,7 +14,13 @@ Model (dense-matmul accounting, the standard MFU convention):
     per sample (per token for token policies) — biases/norms are
     rounding errors against the GEMMs and are ignored;
   * self-attention adds ``4·W²·d_model`` per layer per sample
-    (``QKᵀ`` and ``A·V``, ``2·W²·d`` each) for window length ``W``;
+    (``QKᵀ`` and ``A·V``, ``2·W²·d`` each) for window length ``W``
+    (``causal``: a token meets the ``(W + 1) / 2`` keys up to itself);
+  * with ``expert_share`` (an expert layer: experts per token ÷ routed
+    experts) parameters of more than two dims are stacks of GEMMs (layers
+    under a scan, the experts a chip holds) and a stack of experts
+    (``experts_*``) costs its ACTIVE share: per layer experts held ×
+    ``expert_share`` × ``2·m·n``, not every expert held for every token;
   * one train step = rollout forwards over ``num_envs · horizon``
     samples + update passes at the standard ``3×`` forward cost
     (forward + backward) over the same samples, ``update_epochs``
@@ -25,25 +31,36 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 
-def param_flops_per_sample(params: Any, *, tokens: int = 1) -> float:
+def param_flops_per_sample(params: Any, *, tokens: int = 1,
+                           expert_share: Optional[float] = None) -> float:
     """``2·m·n`` summed over every 2-D leaf of ``params``, times the
     ``tokens`` each sample pushes through the trunk (1 for flat-obs
-    policies, the window length for token policies)."""
+    policies, the window length for token policies).  With
+    ``expert_share`` also the stacked leaves: ``2·m·n`` of the last two
+    dims times the stack, an ``experts_*`` stack times ``expert_share``."""
+    import math
+
     import jax
 
     total = 0.0
-    for leaf in jax.tree_util.tree_leaves(params):
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
         shape = getattr(leaf, "shape", ())
         if len(shape) == 2:
             total += 2.0 * float(shape[0]) * float(shape[1])
+        elif len(shape) > 2 and expert_share is not None:
+            name = str(getattr(path[-1], "key", path[-1]))
+            share = float(expert_share) if name.startswith("experts_") else 1.0
+            total += 2.0 * share * float(math.prod(shape))
     return total * float(tokens)
 
 
 def attention_flops_per_sample(window: int, d_model: int,
-                               n_layers: int) -> float:
+                               n_layers: int, causal: bool = False) -> float:
     """The activation-activation matmuls parameter counting misses:
-    ``QKᵀ`` + ``A·V`` = ``4·W²·d`` per layer."""
-    return 4.0 * float(n_layers) * float(window) ** 2 * float(d_model)
+    ``QKᵀ`` + ``A·V`` = ``4·W²·d`` per layer (``d``: heads × head width);
+    causal, ``4·W·(W + 1)/2·d``."""
+    keys = (float(window) + 1.0) / 2.0 if causal else float(window)
+    return 4.0 * float(n_layers) * float(window) * keys * float(d_model)
 
 
 def analytic_train_step_flops(
@@ -56,11 +73,13 @@ def analytic_train_step_flops(
     window: int = 0,
     d_model: int = 0,
     n_layers: int = 0,
+    causal: bool = False,
+    expert_share: Optional[float] = None,
 ) -> float:
     """Closed-form FLOPs of ONE fused rollout+update train step."""
-    fwd = param_flops_per_sample(params, tokens=tokens)
+    fwd = param_flops_per_sample(params, tokens=tokens, expert_share=expert_share)
     if n_layers and window and d_model:
-        fwd += attention_flops_per_sample(window, d_model, n_layers)
+        fwd += attention_flops_per_sample(window, d_model, n_layers, causal)
     samples = float(num_envs) * float(horizon)
     rollout = samples * fwd
     update = 3.0 * samples * fwd * float(max(1, update_epochs))

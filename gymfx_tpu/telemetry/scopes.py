@@ -32,7 +32,15 @@ The scope paths, under ``jit(...)``:
   update/optimizer              optimizer update, apply, the guard's select
   update/guard                  metrics, quarantine, masked resets
   .../attention, .../ffn        the two halves of a transformer block, under
-                                ``policy_act`` and ``policy_forward``
+                                ``policy_act`` and ``policy_forward`` (the
+                                decoder trunk: latent attention; the DENSE
+                                layer's gated feed-forward)
+  .../moe_router                an expert layer, flat under the same two:
+                                its norm, the scores, top-k and weights
+  .../moe_dispatch              sort by expert, rows to the buffer and back,
+                                the weighted sum
+  .../moe_experts               grouped products over the experts held
+  .../moe_shared                the shared expert
 """
 from __future__ import annotations
 
@@ -55,13 +63,19 @@ OPTIMIZER = "optimizer"
 GUARD = "guard"
 ATTENTION = "attention"
 FFN = "ffn"
+MOE_ROUTER = "moe_router"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_SHARED = "moe_shared"
+# the parts of an expert layer (train/mla_moe_decoder.py)
+MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED)
 
 # the two phases every trainer's fused step plants (PR 6)
 PHASE_SCOPES = (ROLLOUT, UPDATE)
 SCOPE_NAMES = PHASE_SCOPES + (
     POLICY_ACT, ENV_STEP, TAPE_READ, DYNAMICS, OBS, AUTO_RESET, GAE,
     MINIBATCH_TAKE, LOSS, POLICY_FORWARD, OPTIMIZER, GUARD, ATTENTION, FFN,
-)
+) + MOE_SCOPES
 
 
 def join(*names: str) -> str:
@@ -73,6 +87,7 @@ LAYERS = (
     join(ROLLOUT, POLICY_ACT),
     join(ROLLOUT, POLICY_ACT, ATTENTION),
     join(ROLLOUT, POLICY_ACT, FFN),
+    *(join(ROLLOUT, POLICY_ACT, part) for part in MOE_SCOPES),
     join(ROLLOUT, ENV_STEP, TAPE_READ),
     join(ROLLOUT, ENV_STEP, DYNAMICS),
     join(ROLLOUT, ENV_STEP, OBS),
@@ -83,6 +98,7 @@ LAYERS = (
     join(UPDATE, LOSS, POLICY_FORWARD),
     join(UPDATE, LOSS, POLICY_FORWARD, ATTENTION),
     join(UPDATE, LOSS, POLICY_FORWARD, FFN),
+    *(join(UPDATE, LOSS, POLICY_FORWARD, part) for part in MOE_SCOPES),
     join(UPDATE, OPTIMIZER),
     join(UPDATE, GUARD),
 )
@@ -97,8 +113,11 @@ KERNEL_FILL_BRACKETS = "env_dynamics_fill_brackets"
 KERNEL_MARK_REWARD = "env_dynamics_mark_reward"
 KERNEL_ATTENTION_FWD = "fused_attention_fwd"
 KERNEL_ATTENTION_BWD = "fused_attention_bwd"
+KERNEL_GROUPED_MATMUL = "grouped_matmul"
+KERNEL_GROUPED_MATMUL_DW = "grouped_matmul_dw"
 KERNEL_NAMES = (KERNEL_FILL_BRACKETS, KERNEL_MARK_REWARD,
-                KERNEL_ATTENTION_FWD, KERNEL_ATTENTION_BWD)
+                KERNEL_ATTENTION_FWD, KERNEL_ATTENTION_BWD,
+                KERNEL_GROUPED_MATMUL, KERNEL_GROUPED_MATMUL_DW)
 
 FWD, BWD = "fwd", "bwd"
 
@@ -132,16 +151,25 @@ def _op_scope(op_name: str, scopes: Optional[Sequence[str]]) -> OpScope:
     unwrapped (a jitted helper, ``jit(name)``, is no scope).  The backward
     of a custom VJP carries the scope it is called under in front of the
     scopes it was defined under, ``loss/transpose(loss)/jvp(policy_forward)/
-    .../fused_attention_bwd``: a name that repeats the one before it is
-    dropped."""
+    .../fused_attention_bwd``, and a block rematerialised in the backward
+    pass carries its whole stack again, ``loss/transpose(jvp(policy_forward))/
+    .../loss/jvp(policy_forward)/.../rematted_computation/attention``: a
+    name already on the path takes the path back to where it stood."""
     direction = BWD if "transpose(" in op_name else (
         FWD if "jvp(" in op_name else None)
     if scopes is None:
         return OpScope(op_name, direction)
     found: List[str] = []
     for part in op_name.split("/"):
-        wrappers, name = _COMPONENT_RE.match(part).groups()
-        if name in scopes and "jit(" not in wrappers and found[-1:] != [name]:
+        match = _COMPONENT_RE.match(part)
+        if match is None:
+            # an op XLA merged carries every source's path, `a/b/add;jit(f)/a/c/add`:
+            # the seam is no component, and the later path takes the earlier one back
+            continue
+        wrappers, name = match.groups()
+        if name in scopes and "jit(" not in wrappers:
+            if name in found:
+                del found[found.index(name):]
             found.append(name)
     return OpScope(_rooted(join(*found)), direction)
 
@@ -150,9 +178,17 @@ def _rooted(path: str) -> str:
     """Ops traced inside a ``custom_vmap`` rule (the packing round the
     env-dynamics kernels) lose the outer part of their name stack,
     ``vmap(env_step)/dynamics/add``: a path that starts below a phase gets
-    its root from the tree of layers, where only one root fits."""
-    if not path or path.split("/")[0] in PHASE_SCOPES:
+    its root from the tree of layers, where only one root fits.  Constants
+    hoisted out of a transformed scan body (an expert layer's ``iota``) keep
+    the phase and the layer and lose what lies between, ``update/
+    moe_dispatch``: such a path is the one layer of that phase it ends as."""
+    if not path or path in LAYERS or path in GROUP_SCOPES:
         return path
+    phase, _, tail = path.partition("/")
+    if phase in PHASE_SCOPES:
+        fits = [layer for layer in LAYERS
+                if layer.startswith(f"{phase}/") and layer.endswith(f"/{tail}")]
+        return fits[0] if len(fits) == 1 else path
     roots = set()
     for layer in LAYERS:
         at = f"/{layer}/".find(f"/{path}/")

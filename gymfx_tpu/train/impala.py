@@ -38,6 +38,7 @@ from gymfx_tpu.train.policies import (
     flatten_obs,
     gaussian_entropy,
     is_token_policy,
+    policy_kwargs_from,
     make_obs_spec,
     make_trainer_policy,
     normal_logp,
@@ -109,7 +110,7 @@ def impala_config_from(config: Dict[str, Any]) -> ImpalaConfig:
         policy_dtype=dt,
         policy_kwargs=tuple(
             (k, tuple(v) if isinstance(v, list) else v)
-            for k, v in (config.get("policy_kwargs") or {}).items()
+            for k, v in policy_kwargs_from(config).items()
         ),
         collect_dtype=_resolve_collect_dtype(config, dt),
         nonfinite_guard=bool(config.get("nonfinite_guard", True)),

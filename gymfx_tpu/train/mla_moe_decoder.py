@@ -1,0 +1,533 @@
+"""A decoder trunk with latent attention and sparse experts, as a policy.
+
+``mla_moe_decoder``: pre-norm residual blocks over the bar window, one
+bar one token.  Each block is multi-head latent attention (low-rank query
+and key-value paths, rotary positions on a part of each head, one rotary
+key shared by all heads, causal) and a gated feed-forward: dense in the
+leading ``first_k_dense_replace`` layers, an expert layer after them.  The
+last position's state feeds the actor and the critic head.
+
+The expert layer is TOLD which experts it holds (``experts_held`` of
+``n_routed_experts`` from ``expert_offset``): the router scores all
+experts and keeps its top-k, the layer computes the terms of the sum
+whose expert it holds, for the tokens routed there, plus the shared
+expert every token passes through, and hands the partial result on.
+Nothing stands in for the chips that hold the other experts.  Dispatch is
+dropless: token choices are placed by expert in a buffer (twice the expected
+rows where the batch fits that, the worst case's rows else), a grouped matrix
+product runs over the tiles in use (the Mosaic kernel of
+``ops/grouped_matmul.py``, interpreted on a CPU), and each token sums the rows
+of its choices.
+
+The router's choice bias takes no gradient.  It is drawn from the key and
+then balanced, once, on the batch the module is initialised on
+(``balanced_choice_bias``: the rule that trains the published bias), which
+a trainer makes a batch like the ones it will meet (``PPOTrainer._first_batch``):
+a trained router's bias carries the balance of the experts' loads, and random
+weights have none.
+
+Parameters are float32, compute is ``dtype``; RMSNorm, rotary angles,
+the router's scores and the softmax of attention run in float32.  The
+module takes any leading batch dims itself (``takes_batch``): the expert
+layer sorts the tokens of the WHOLE batch, so a trainer calls it on the
+batch instead of vmapping it over envs.  The four expert layers of a
+configuration are one ``nn.scan`` over stacked parameters, each block
+rematerialised in the backward pass (``remat``).
+
+The plain reference of the same equations is
+``gymfx_tpu/reference/mla_moe_decoder.py``.
+"""
+from __future__ import annotations
+
+from typing import Any, NamedTuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from gymfx_tpu.telemetry import scopes
+
+class Dims(NamedTuple):
+    """The widths of the block, under the names of the published config."""
+
+    hidden_size: int = 2048
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    num_attention_heads: int = 20
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    intermediate_size: int = 10240
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 64
+    num_experts_per_tok: int = 4
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.8
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    experts_held: int = 64
+    expert_offset: int = 0
+
+
+_matrix = nn.initializers.variance_scaling(
+    1.0, "fan_in", "normal", in_axis=-2, out_axis=-1)
+_expert_matrix = nn.initializers.variance_scaling(
+    1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,))
+
+
+def rms_norm(x, weight, eps):
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale * weight).astype(x.dtype)
+
+
+def rope_interleaved(x, theta):
+    """Rotary positions on the last dim of ``x`` (..., W, heads, d), the
+    position the index in the window; pairs are ADJACENT dims (2i, 2i+1)."""
+    window, d = x.shape[-3], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(window, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    even, odd = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    return (nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def route(scores, bias, dims: Dims):
+    """Top-k of ``scores + bias`` over ALL experts; the weights are the
+    chosen SCORES (the bias steers the choice only), renormalised and
+    scaled.  ``scores`` (T, n_routed) float32 -> (idx, weights) (T, k)."""
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), dims.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if dims.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return idx, weights * dims.routed_scaling_factor
+
+
+def balanced_choice_bias(scores, bias, dims: Dims):
+    """The choice bias a trained router carries, for weights that were not
+    trained: from ``bias``, the published balancing rule (lower the bias of
+    an expert over the even load, raise one under it) on the ``scores``
+    (T, n_routed) of the batch the layer is initialised on, 400 rounds with a
+    step that shrinks from 0.05 to 5e-5.  It moves the choice only; where the tokens
+    are all alike (a window of padding) no bias can part them and it settles
+    within the steps' sum of the start."""
+    tokens, n = scores.shape
+    even = tokens * dims.num_experts_per_tok / n
+    scores = jax.lax.stop_gradient(scores)
+    rounds = 400
+
+    def step(i, b):
+        _, idx = jax.lax.top_k(scores + b, dims.num_experts_per_tok)
+        load = jnp.sum(idx[..., None] == jnp.arange(n, dtype=idx.dtype), axis=(0, 1))
+        rate = 0.05 * 1e-3 ** (i / (rounds - 1))
+        return b - rate * jnp.clip(load / even - 1.0, -1.0, 1.0)
+
+    return jax.lax.fori_loop(0, rounds, step, bias.astype(jnp.float32))
+
+
+class Plan(NamedTuple):
+    """Where each token choice goes in the sorted buffer, from both ends.
+    Choices are numbered choice-major, ``c = j * T + t`` for token t's j-th
+    choice: a (k, T) array is then k runs of T, and nothing is re-laid-out."""
+
+    token: Any        # (rows,) the token whose choice sits in row r (0 where padding)
+    choice: Any       # (rows,) that choice's number c; k * T where the row is padding
+    valid: Any        # (rows,) bool
+    dest: Any         # (k, T) the row of choice (j, t); rows where its expert is not held
+    held: Any         # (k, T) bool
+    group_sizes: Any  # (experts_held,) rows of each expert, whole tiles
+    loads: Any        # (experts_held,) token choices of each expert
+
+
+def buffer_rows(tokens: int, dims: Dims, align: int, worst: bool = True) -> int:
+    """Rows of the sorted buffer, in whole tiles of ``align`` rows.  ``worst``:
+    every choice of every token may fall on an expert held here (dropless).
+    Else TWICE what the experts held here expect of ``tokens`` tokens.  Either
+    way one tile more per expert, for the padding of its last one."""
+    k, held = dims.num_experts_per_tok, dims.experts_held
+    rows = tokens * min(k, held)
+    if not worst:
+        rows = min(rows, 2 * -(-tokens * k * held // dims.n_routed_experts))
+    return -(-rows // align) * align + held * align
+
+
+def expert_loads(idx, dims: Dims):
+    """(key of each choice, choice-major: its expert's place here,
+    ``experts_held`` where the expert is not held; the one-hot of the keys;
+    choices of each expert held) -- the part of the plan that does not depend
+    on the buffer."""
+    local = idx.T.reshape(-1) - dims.expert_offset
+    held = (local >= 0) & (local < dims.experts_held)
+    key = jnp.where(held, local, dims.experts_held).astype(jnp.int32)
+    onehot = key[:, None] == jnp.arange(dims.experts_held, dtype=jnp.int32)[None, :]
+    return key, onehot, jnp.sum(onehot, axis=0, dtype=jnp.int32)
+
+
+def padded_sizes(loads, align: int):
+    """Every expert's rows as whole tiles, at least one."""
+    return jnp.maximum(align, -(-loads // align) * align)
+
+
+def routing_plan(idx, dims: Dims, align: int, rows: int = 0) -> Plan:
+    """Place the (T, k) expert choices in a buffer of ``rows`` rows (the
+    worst case by default) sorted by expert: the experts held here in order,
+    every choice behind its expert's earlier ones; the others out of the
+    buffer.  Every expert's rows start at a multiple of ``align`` and every
+    expert has at least one tile.  No sort: a choice's rank among its
+    expert's is a running count."""
+    tokens, k = idx.shape
+    rows = rows or buffer_rows(tokens, dims, align)
+    key, onehot, loads = expert_loads(idx, dims)
+    sizes = padded_sizes(loads, align)
+    rank = jnp.sum(jnp.where(onehot, jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1, 0),
+                   axis=1)
+    held = key < dims.experts_held
+    starts = jnp.cumsum(sizes) - sizes
+    dest = jnp.where(held, starts[jnp.minimum(key, dims.experts_held - 1)] + rank, rows)
+    choice = jnp.full(rows, k * tokens, jnp.int32).at[dest].set(
+        jnp.arange(k * tokens, dtype=jnp.int32), mode="drop")
+    valid = choice < k * tokens
+    return Plan(jnp.where(valid, choice % tokens, 0), choice, valid,
+                dest.reshape(k, tokens), held.reshape(k, tokens), sizes, loads)
+
+
+def _rows_of(x, index, mask):
+    """``x[index]`` where ``mask``, else 0: one gather of whole rows."""
+    return jnp.where(mask[:, None], jnp.take(x, index, axis=0, mode="clip"), 0)
+
+
+def _sum_of_choices(rows, plan: Plan, weights=None):
+    """(T, hidden): over a token's k choices, the buffer row of each one held
+    here (times its weight).  k gathers of T rows summed as they are read:
+    no (k, T, hidden) array is written."""
+    out = 0.0
+    for j in range(plan.dest.shape[0]):
+        part = _rows_of(rows, plan.dest[j], plan.held[j])
+        out = out + (part if weights is None else part * weights[j][:, None])
+    return out
+
+
+@jax.custom_vjp
+def dispatch_rows(y, plan: Plan):
+    """Tokens (T, hidden) -> the sorted buffer (rows, hidden): row r holds the
+    token whose choice was placed there, padding rows zeros.  The plan is a
+    partial one-to-one map of choices and rows known from both ends, so the
+    backward pass is gathers too (no scatter-add)."""
+    return _rows_of(y, plan.token, plan.valid)
+
+
+def _dispatch_fwd(y, plan):
+    return dispatch_rows(y, plan), plan
+
+
+def _dispatch_bwd(plan, g):
+    return _sum_of_choices(g, plan).astype(g.dtype), None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(ys, weights, plan: Plan):
+    """The sorted buffer's outputs (rows, hidden) and the (k, T) weights ->
+    (T, hidden): each token's weighted sum over its choices held here."""
+    return _sum_of_choices(ys, plan, weights.astype(ys.dtype)).astype(ys.dtype)
+
+
+def _combine_fwd(ys, weights, plan):
+    return combine_rows(ys, weights, plan), (ys, weights, plan)
+
+
+def _combine_bwd(res, g):
+    ys, weights, plan = res
+    k, tokens = plan.dest.shape
+    row_weight = jnp.take(weights.reshape(-1), plan.choice, mode="clip").astype(g.dtype)
+    g_ys = _rows_of(g, plan.token, plan.valid) * row_weight[:, None]
+    g_weights = jnp.stack([
+        jnp.sum(_rows_of(ys, plan.dest[j], plan.held[j]).astype(jnp.float32)
+                * g.astype(jnp.float32), axis=-1) for j in range(k)])
+    return g_ys, g_weights.astype(weights.dtype), None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+def experts_ffn(xs, plan: Plan, w_gate, w_up, w_down, align: int):
+    """SwiGLU of every row of the sorted buffer through its own expert; the
+    tiles behind the last expert's are skipped."""
+    from gymfx_tpu.ops.grouped_matmul import grouped_matmul, tile_groups
+
+    tiles = tile_groups(plan.group_sizes, xs.shape[0], align)
+    width = w_gate.shape[-1]
+    both = grouped_matmul(xs, jnp.concatenate([w_gate, w_up], axis=-1), *tiles,
+                          tile_rows=align)
+    hidden = nn.silu(both[:, :width]) * both[:, width:]
+    return grouped_matmul(hidden, w_down, *tiles, tile_rows=align)
+
+
+def routed_experts(dims: Dims, tokens: int, align: int):
+    """``(y, idx, weights, w_gate, w_up, w_down) -> (T, hidden)``: the weighted
+    sum, over each token's choices, of the experts held here.  Dropless: the
+    rows are moved through the short buffer where the choices on the experts
+    held here fit it, through the worst case's else (``lax.cond``; what a
+    buffer costs is its gathers, a row each way whether used or not).
+    Forward and backward each pick their branch and keep what they compute
+    inside it (a ``custom_vjp`` whose residuals are its operands):
+    differentiated as it stands, a ``cond`` hands the backward pass BOTH
+    branches' buffers, the one not taken filled with zeros."""
+    def through(rows):
+        def run(y, idx, weights, w_gate, w_up, w_down):
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                plan = routing_plan(idx, dims, align, rows)
+                xs = dispatch_rows(y, plan)
+            with jax.named_scope(scopes.MOE_EXPERTS):
+                ys = experts_ffn(xs, plan, w_gate, w_up, w_down, align)
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                return combine_rows(ys, weights.T, plan)
+        return run
+
+    short, worst = (buffer_rows(tokens, dims, align, worst=w) for w in (False, True))
+    if short >= worst:
+        return through(worst)
+
+    def fits(idx):
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            return jnp.sum(padded_sizes(expert_loads(idx, dims)[2], align)) <= short
+
+    def backward(rows):
+        def run(g, y, idx, weights, *w):
+            _, pull = jax.vjp(
+                lambda y, weights, *w: through(rows)(y, idx, weights, *w),
+                y, weights, *w)
+            return pull(g)
+        return run
+
+    @jax.custom_vjp
+    def routed(y, idx, weights, w_gate, w_up, w_down):
+        return jax.lax.cond(fits(idx), through(short), through(worst),
+                            y, idx, weights, w_gate, w_up, w_down)
+
+    def routed_fwd(*operands):
+        return routed(*operands), operands
+
+    def routed_bwd(operands, g):
+        gy, gweights, *gw = jax.lax.cond(
+            fits(operands[1]), backward(short), backward(worst), g, *operands)
+        return (gy, None, gweights, *gw)
+
+    routed.defvjp(routed_fwd, routed_bwd)
+    return routed
+
+
+def tile_rows_for(tokens: int, dims: Dims) -> int:
+    """Rows of a tile of the grouped product: near what one expert held here
+    expects, between 128 and 512."""
+    expected = tokens * dims.num_experts_per_tok // dims.n_routed_experts
+    return 512 if expected >= 512 else (256 if expected >= 256 else 128)
+
+
+class _Layer(nn.Module):
+    """What the three parts of a block share: float32 parameters handed out
+    in the compute dtype, and RMSNorm with a learned weight."""
+
+    dims: Dims
+    dtype: Any
+
+    def weight(self, name, shape, init=_matrix):
+        return self.param(name, init, shape, jnp.float32).astype(self.dtype)
+
+    def norm(self, name, x):
+        weight = self.param(name, nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        return rms_norm(x, weight, self.dims.rms_norm_eps)
+
+
+class LatentAttention(_Layer):
+    """Pre-norm multi-head latent attention: (B, W, hidden) -> the same."""
+
+    @nn.compact
+    def __call__(self, x):
+        from gymfx_tpu.train.policies import dense_window_attention
+
+        d = self.dims
+        heads, nope, rope, vdim = (d.num_attention_heads, d.qk_nope_head_dim,
+                                   d.qk_rope_head_dim, d.v_head_dim)
+        y = self.norm("attn_norm", x)
+        c_q = self.norm("q_a_norm", y @ self.weight("q_a", (d.hidden_size, d.q_lora_rank)))
+        q = c_q @ self.weight("q_b", (d.q_lora_rank, heads * (nope + rope)))
+        q = q.reshape(*q.shape[:-1], heads, nope + rope)
+        kv_a = y @ self.weight("kv_a", (d.hidden_size, d.kv_lora_rank + rope))
+        c_kv = self.norm("kv_a_norm", kv_a[..., :d.kv_lora_rank])
+        k_rope = rope_interleaved(kv_a[..., None, d.kv_lora_rank:], d.rope_theta)
+        kv = c_kv @ self.weight("kv_b", (d.kv_lora_rank, heads * (nope + vdim)))
+        kv = kv.reshape(*kv.shape[:-1], heads, nope + vdim)
+        q = jnp.concatenate(
+            [q[..., :nope], rope_interleaved(q[..., nope:], d.rope_theta)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (*kv.shape[:-1], rope))], axis=-1)
+        a = dense_window_attention(q, k, kv[..., nope:], causal=True)
+        return a.reshape(*a.shape[:-2], heads * vdim) @ self.weight(
+            "o", (heads * vdim, d.hidden_size))
+
+
+class DenseFfn(_Layer):
+    """Pre-norm gated (SwiGLU) feed-forward of a leading dense layer."""
+
+    @nn.compact
+    def __call__(self, x):
+        d = self.dims
+        return swiglu(self.norm("ffn_norm", x),
+                      self.weight("gate", (d.hidden_size, d.intermediate_size)),
+                      self.weight("up", (d.hidden_size, d.intermediate_size)),
+                      self.weight("down", (d.intermediate_size, d.hidden_size)))
+
+
+class ExpertLayer(_Layer):
+    """Pre-norm expert layer of the chip's share: (T, hidden) -> the partial
+    sum of the experts held here plus the shared expert, the counters
+    (choices on the experts held, the largest expert's load, float32) and the
+    (T, k) expert choices."""
+
+    @nn.compact
+    def __call__(self, x):
+        d = self.dims
+        tokens, k = x.shape[0], d.num_experts_per_tok
+        with jax.named_scope(scopes.MOE_ROUTER):
+            y = self.norm("ffn_norm", x)
+            w_router = self.param(
+                "router", _matrix, (d.hidden_size, d.n_routed_experts), jnp.float32)
+            scores = jax.nn.sigmoid(jnp.dot(
+                y.astype(jnp.float32), w_router, precision=jax.lax.Precision.HIGHEST))
+            # drawn from the seed, then balanced on the batch of the init call
+            bias = self.param(
+                "e_score_correction_bias",
+                lambda key: balanced_choice_bias(
+                    scores, 0.02 * jax.random.normal(key, (d.n_routed_experts,)), d))
+            idx, weights = route(scores, bias, d)
+        align = tile_rows_for(tokens, d)
+        shape = (d.experts_held, d.hidden_size, d.moe_intermediate_size)
+        w_gate = self.weight("experts_gate", shape, _expert_matrix)
+        w_up = self.weight("experts_up", shape, _expert_matrix)
+        w_down = self.weight("experts_down", (shape[0], shape[2], shape[1]), _expert_matrix)
+
+        routed = routed_experts(d, tokens, align)(y, idx, weights, w_gate, w_up, w_down)
+        with jax.named_scope(scopes.MOE_SHARED):
+            width = d.moe_intermediate_size * d.n_shared_experts
+            shared = swiglu(y, self.weight("shared_gate", (d.hidden_size, width)),
+                            self.weight("shared_up", (d.hidden_size, width)),
+                            self.weight("shared_down", (width, d.hidden_size)))
+        loads = expert_loads(idx, d)[2].astype(jnp.float32)
+        return routed + shared, jnp.stack([jnp.sum(loads), jnp.max(loads)]), idx
+
+
+class _Block(nn.Module):
+    """One pre-norm residual block: latent attention, then a gated
+    feed-forward that is dense (``sparse=False``) or the expert layer.
+    Called on (B, W, hidden); returns it with the expert layer's counters
+    and choices (``None`` for a dense block), in the shape ``nn.scan`` wants."""
+
+    dims: Dims
+    sparse: bool
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x, _=None):
+        with jax.named_scope(scopes.ATTENTION):
+            x = x + LatentAttention(self.dims, self.dtype, name="attn")(x)
+        if not self.sparse:
+            with jax.named_scope(scopes.FFN):
+                return x + DenseFfn(self.dims, self.dtype, name="ffn")(x), None
+        out, counters, idx = ExpertLayer(self.dims, self.dtype, name="experts")(
+            x.reshape(-1, self.dims.hidden_size))
+        return x + out.reshape(x.shape), (counters, idx)
+
+
+class MlaMoeDecoderPolicy(nn.Module):
+    """Actor-critic over the decoder trunk; tokens (..., W, token_dim)."""
+
+    n_actions: int = 3
+    dtype: Any = jnp.float32
+    n_layers: int = 5
+    first_k_dense_replace: int = 1
+    remat: bool = True
+    hidden_size: int = Dims._field_defaults["hidden_size"]
+    q_lora_rank: int = Dims._field_defaults["q_lora_rank"]
+    kv_lora_rank: int = Dims._field_defaults["kv_lora_rank"]
+    num_attention_heads: int = Dims._field_defaults["num_attention_heads"]
+    qk_nope_head_dim: int = Dims._field_defaults["qk_nope_head_dim"]
+    qk_rope_head_dim: int = Dims._field_defaults["qk_rope_head_dim"]
+    v_head_dim: int = Dims._field_defaults["v_head_dim"]
+    intermediate_size: int = Dims._field_defaults["intermediate_size"]
+    moe_intermediate_size: int = Dims._field_defaults["moe_intermediate_size"]
+    n_routed_experts: int = Dims._field_defaults["n_routed_experts"]
+    num_experts_per_tok: int = Dims._field_defaults["num_experts_per_tok"]
+    n_shared_experts: int = Dims._field_defaults["n_shared_experts"]
+    routed_scaling_factor: float = Dims._field_defaults["routed_scaling_factor"]
+    norm_topk_prob: bool = Dims._field_defaults["norm_topk_prob"]
+    rms_norm_eps: float = Dims._field_defaults["rms_norm_eps"]
+    rope_theta: float = Dims._field_defaults["rope_theta"]
+    experts_held: int = 0          # 0: all of n_routed_experts
+    expert_offset: int = 0
+
+    # the trainers call this module on the whole env batch (no vmap), and
+    # ask it for the expert layers' counters inside the loss
+    takes_batch = True
+    COUNTERS = ("moe_held_share", "moe_load_max_over_mean")
+
+    def dims(self) -> Dims:
+        held = self.experts_held or self.n_routed_experts
+        if not 0 <= self.expert_offset <= self.n_routed_experts - held:
+            raise ValueError(
+                f"experts {self.expert_offset}..{self.expert_offset + held} held of "
+                f"{self.n_routed_experts}")
+        values = {name: getattr(self, name) for name in Dims._fields}
+        return Dims(**{**values, "experts_held": held})
+
+    @nn.compact
+    def __call__(self, tokens, counters: bool = False, routing: bool = False):
+        """(logits, value); with ``counters`` also the expert layers' two
+        counters, with ``routing`` also their (layers, tokens, k) choices."""
+        dims = self.dims()
+        batch = tokens.shape[:-2]
+        x = tokens.reshape(-1, *tokens.shape[-2:]).astype(self.dtype)
+        w_in = self.param("in_proj", _matrix, (x.shape[-1], dims.hidden_size), jnp.float32)
+        x = x @ w_in.astype(self.dtype)
+        block = nn.remat(_Block) if self.remat else _Block
+        dense = min(self.first_k_dense_replace, self.n_layers)
+        for i in range(dense):
+            x, _ = block(dims, False, self.dtype, name=f"dense_{i}")(x)
+        sparse = self.n_layers - dense
+        stats, chosen = jnp.zeros((1, 2), jnp.float32), None
+        if sparse:
+            x, (stats, chosen) = nn.scan(
+                block, variable_axes={"params": 0}, split_rngs={"params": True},
+                length=sparse,
+            )(dims, True, self.dtype, name="moe")(x, None)
+        weight = self.param("final_norm", nn.initializers.ones, (dims.hidden_size,),
+                            jnp.float32)
+        last = rms_norm(x[:, -1, :], weight, dims.rms_norm_eps)
+        logits = nn.Dense(self.n_actions, dtype=jnp.float32)(last).reshape(*batch, -1)
+        value = nn.Dense(1, dtype=jnp.float32)(last).reshape(batch)
+        if routing:
+            return logits, value, chosen
+        if not counters:
+            return logits, value
+        choices = x.shape[0] * x.shape[1] * dims.num_experts_per_tok
+        held, largest = jnp.mean(stats[:, 0]), jnp.mean(stats[:, 1])
+        return logits, value, {
+            "moe_held_share": held / choices,
+            "moe_load_max_over_mean": largest * dims.experts_held / jnp.maximum(held, 1.0),
+        }
+
+    def initial_carry(self, batch_shape=()):
+        return ()
+
+    def apply_seq(self, params, tokens, carry):
+        logits, value = self.apply(params, tokens)
+        return logits, value, carry

@@ -58,7 +58,7 @@ def flatten_obs(obs: Dict[str, Any], spec: Optional[ObsSpec] = None) -> Any:
     return jnp.concatenate(parts, axis=0)
 
 
-def dense_window_attention(q, k, v):
+def dense_window_attention(q, k, v, causal: bool = False):
     """Single-device attention for the token policies: the fused
     VMEM-resident pallas kernel on TPU for LONG windows
     (ops/fused_attention.py — zero HBM score traffic, VERDICT r4 weak
@@ -74,8 +74,8 @@ def dense_window_attention(q, k, v):
     from gymfx_tpu.parallel.ring_attention import full_attention
 
     if MIN_FUSED_WINDOW <= q.shape[-3] <= MAX_FUSED_WINDOW and on_tpu():
-        return fused_window_attention(q, k, v, interpret=False)
-    return full_attention(q, k, v)
+        return fused_window_attention(q, k, v, causal=causal, interpret=False)
+    return full_attention(q, k, v, causal=causal)
 
 
 def obs_size(obs: Dict[str, Any]) -> int:
@@ -547,11 +547,24 @@ class ContinuousRingTransformerPolicy(nn.Module):
 
 # policies whose inputs are (window, token_dim) token sequences rather
 # than flat vectors — shared by every trainer's encode/init paths
-TOKEN_POLICIES = ("transformer", "transformer_ring", "transformer_ulysses")
+TOKEN_POLICIES = ("transformer", "transformer_ring", "transformer_ulysses",
+                  "mla_moe_decoder")
 
 
 def is_token_policy(name: str) -> bool:
     return name in TOKEN_POLICIES
+
+
+def policy_kwargs_from(config: Dict[str, Any]) -> Dict[str, Any]:
+    """``policy_kwargs`` of a merged config as a dict: a config file gives
+    one, the command line gives its JSON text (``--policy_kwargs '{...}'``:
+    the widths of a decoder trunk are a nested object)."""
+    kwargs = config.get("policy_kwargs") or {}
+    if isinstance(kwargs, str):
+        import json
+
+        kwargs = json.loads(kwargs)
+    return dict(kwargs)
 
 
 def policy_kwargs_for(name: str, kwargs: Dict[str, Any], window: int) -> Dict[str, Any]:
@@ -574,6 +587,10 @@ def make_policy(name: str, n_actions: int = 3, dtype: Any = jnp.float32, **kw):
         return ContinuousRingTransformerPolicy(
             dtype=dtype, sp_backend="ulysses", **kw
         )
+    if name == "mla_moe_decoder":
+        from gymfx_tpu.train.mla_moe_decoder import MlaMoeDecoderPolicy
+
+        return MlaMoeDecoderPolicy(n_actions=n_actions, dtype=dtype, **kw)
     if name == "mlp":
         return MLPPolicy(n_actions=n_actions, dtype=dtype, **kw)
     if name == "lstm":

@@ -37,6 +37,7 @@ from gymfx_tpu.train.policies import (
     is_token_policy,
     make_obs_spec,
     make_trainer_policy,
+    policy_kwargs_from,
     normal_logp,
     sample_normal,
     tokens_from_obs,
@@ -151,7 +152,7 @@ def ppo_config_from(config: Dict[str, Any]) -> PPOConfig:
         policy_dtype=dt,
         policy_kwargs=tuple(
             (k, tuple(v) if isinstance(v, list) else v)
-            for k, v in (config.get("policy_kwargs") or {}).items()
+            for k, v in policy_kwargs_from(config).items()
         ),
         minibatch_scheme=str(
             config.get("ppo_minibatch_scheme", "env_permute")
@@ -280,30 +281,82 @@ class PPOTrainer:
         """Key-based init (traceable — PBT vmaps this over a population)."""
         rng, k_init = jax.random.split(rng)
         carry0 = self.policy.initial_carry(())
-        if self._is_transformer:
-            p = self.policy.init(k_init, self._reset_vec)
-        elif self.pcfg.policy == "lstm":
-            p = self.policy.init(k_init, self._reset_vec, carry0)
+        n = self.pcfg.n_envs
+        if self._random_start:
+            # the first episodes start at random offsets too, as every
+            # later one does (_rollout): the batch covers the tape, and its
+            # windows hold bars and not padding, from the first step on
+            rng, k0 = jax.random.split(rng)
+            t0s = jax.random.randint(k0, (n,), 0, max(1, self.env.cfg.n_bars - 2))
+            env_states, first_obs = jax.vmap(
+                env_core.reset_at, in_axes=(None, None, None, 0)
+            )(self.env.cfg, self.env.params, self.env.data, t0s)
+            obs_vec = jax.vmap(self._encode)(first_obs)
+            # every leaf a buffer of its own (reset hands one array out
+            # under two fields; the donated step takes each once)
+            env_states = jax.tree.map(jnp.array, env_states)
         else:
-            p = self.policy.init(k_init, self._reset_vec)
+            env_states = jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (n, *x.shape)), self._reset_state
+            )
+            obs_vec = jnp.broadcast_to(self._reset_vec, (n, *self._reset_vec.shape))
+        sample = self._reset_vec
+        if getattr(self.policy, "takes_batch", False):
+            # a policy that takes the batch is initialised on a batch like
+            # the ones it will meet (an expert layer balances its choice
+            # bias on it)
+            rng, k_walk = jax.random.split(rng)
+            sample = self._first_batch(k_walk, env_states)
+        if self.pcfg.policy == "lstm":
+            p = self.policy.init(k_init, sample, carry0)
+        else:
+            p = self.policy.init(k_init, sample)
         opt_state = self.optimizer.init(p)
 
-        n = self.pcfg.n_envs
-        env_states = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (n, *x.shape)), self._reset_state
-        )
-        obs_vec = jnp.broadcast_to(self._reset_vec, (n, *self._reset_vec.shape))
         pcarry = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (n, *x.shape)), carry0
         )
         return TrainState(p, opt_state, env_states, obs_vec, pcarry, rng)
 
+    def _first_batch(self, rng, env_states):
+        """One horizon of uniformly random actions from ``env_states``,
+        every ``minibatches``-th step's observations: a minibatch's worth
+        of windows with the agent states a rollout meets (a position held
+        is a feature of every token of its window; the reset's are all
+        flat).  The states walked through are dropped."""
+        cfg, eparams, data = self.env.cfg, self.env.params, self.env.data
+        vstep = jax.vmap(env_core.step, in_axes=(None, None, None, 0, 0))
+
+        def body(states, key):
+            action = jax.random.randint(
+                key, (self.pcfg.n_envs,), 0, self.policy.n_actions)
+            states, obs, *_ = vstep(cfg, eparams, data, states, action)
+            return states, jax.vmap(self._encode)(obs)
+
+        _, walked = jax.lax.scan(
+            body, env_states, jax.random.split(rng, self.pcfg.horizon))
+        kept = walked[self.pcfg.minibatches - 1::self.pcfg.minibatches]
+        return kept.reshape(-1, *kept.shape[2:])
+
     # ------------------------------------------------------------------
-    def _policy_forward(self, params, obs_vec, pcarry):
+    def _policy_forward(self, params, obs_vec, pcarry, counters=False):
+        """(dist, value, carry); with ``counters`` also the counters a policy
+        keeps of its own layers (``policy.COUNTERS``: an expert layer's load)."""
         if self.pcfg.policy == "lstm":
             return self.policy.apply(params, obs_vec, pcarry)
+        if counters:
+            logits, value, counted = self.policy.apply(params, obs_vec, counters=True)
+            return logits, value, pcarry, jax.lax.stop_gradient(counted)
         logits, value = self.policy.apply(params, obs_vec)
         return logits, value, pcarry
+
+    def _batch_forward(self):
+        """The policy over the env batch: vmapped per env, or called on the
+        batch as it is where the module takes one itself (an expert layer
+        sorts the tokens of the whole batch)."""
+        if getattr(self.policy, "takes_batch", False):
+            return self._policy_forward
+        return jax.vmap(self._policy_forward, in_axes=(None, 0, 0))
 
     def _rollout(self, params, env_states, obs_vec, pcarry, rng, data=None):
         cfg, eparams = self.env.cfg, self.env.params
@@ -316,7 +369,7 @@ class PPOTrainer:
             data = self.env.data
         vstep = jax.vmap(env_core.step, in_axes=(None, None, None, 0, 0))
         vencode = jax.vmap(self._encode)
-        fwd = jax.vmap(self._policy_forward, in_axes=(None, 0, 0))
+        fwd = self._batch_forward()
         carry0 = self.policy.initial_carry(())
         if self._random_start:
             # a per-env bank of fresh episodes at random offsets, drawn
@@ -413,7 +466,12 @@ class PPOTrainer:
         return advs, returns
 
     def _loss(self, params, batch):
-        fwd = jax.vmap(self._policy_forward, in_axes=(None, 0, 0))
+        fwd = self._batch_forward()
+        # counters a policy keeps of its own layers ride out of the loss
+        # beside its three parts
+        counted = bool(getattr(self.policy, "COUNTERS", ()))
+        if counted:
+            fwd = partial(self._policy_forward, counters=True)
         if self.pcfg.update_remat:
             # recompute the forward activations inside the backward pass
             # (same ops, same order — no numeric change) instead of
@@ -423,7 +481,8 @@ class PPOTrainer:
         # inside value_and_grad the scope reads jvp(policy_forward) on the
         # forward pass and transpose(jvp(policy_forward)) on the backward
         with jax.named_scope(scopes.POLICY_FORWARD):
-            dist, value, _ = fwd(params, batch["obs"], batch["pcarry"])
+            dist, value, _, *rest = fwd(params, batch["obs"], batch["pcarry"])
+        counters = rest[0] if counted else {}
         if self._continuous:
             mu, log_std = dist
             logp = _normal_logp(batch["action"], mu, log_std)
@@ -449,7 +508,8 @@ class PPOTrainer:
             - ent_coef * entropy
         )
         return total, dict(
-            policy_loss=policy_loss, value_loss=value_loss, entropy=entropy
+            policy_loss=policy_loss, value_loss=value_loss, entropy=entropy,
+            **counters,
         )
 
     def _loss_hyper(self):
@@ -587,6 +647,7 @@ class PPOTrainer:
                     policy_loss=mmean(auxes["policy_loss"]),
                     value_loss=mmean(auxes["value_loss"]),
                     entropy=mmean(auxes["entropy"]),
+                    **{k: mmean(auxes[k]) for k in getattr(self.policy, "COUNTERS", ())},
                     mean_reward=traj["reward"].mean(),
                     mean_episode_done=traj["done"].mean(),
                     nonfinite_skips=(1.0 - okf).sum(),
@@ -622,6 +683,7 @@ class PPOTrainer:
                     policy_loss=auxes["policy_loss"].mean(),
                     value_loss=auxes["value_loss"].mean(),
                     entropy=auxes["entropy"].mean(),
+                    **{k: auxes[k].mean() for k in getattr(self.policy, "COUNTERS", ())},
                     mean_reward=traj["reward"].mean(),
                     mean_episode_done=traj["done"].mean(),
                 )

@@ -2,14 +2,15 @@
 
   * every layer of the vocabulary that applies occurs in the scope map of
     the step ``bench_util.compile_train_step`` hands out, for an MLP and a
-    ``transformer_ring`` trainer; ``update/loss`` has both directions; an
+    ``transformer_ring`` and an ``mla_moe_decoder`` trainer; ``update/loss``
+    has both directions; an
     unscoped ``while`` or fusion inherits from the computation it calls;
   * scopes are metadata only: the step's metrics and final params are
     bitwise what a trainer traced with ``jax.named_scope`` patched to a
     no-op gives;
   * the registry of the newest step program does no work until it is asked,
     and keeps no executable it no longer needs;
-  * the four Pallas kernels carry their names into the lowered text.
+  * the Pallas kernels carry their names into the lowered text.
 """
 import contextlib
 import gc
@@ -33,13 +34,24 @@ POLICIES = {
     "transformer_ring": dict(
         policy="transformer_ring",
         policy_kwargs={"d_model": 16, "n_heads": 2, "n_layers": 1}),
+    "mla_moe_decoder": dict(
+        policy="mla_moe_decoder",
+        policy_kwargs=dict(
+            hidden_size=32, q_lora_rank=16, kv_lora_rank=8, num_attention_heads=2,
+            qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16, intermediate_size=64,
+            moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
+            n_layers=2, experts_held=4)),
 }
-BLOCKS = (scopes.ATTENTION, scopes.FFN)
+# the parts of a policy's blocks, by policy: the layers that apply to it
+BLOCKS = {"mlp": (), "transformer_ring": (scopes.ATTENTION, scopes.FFN),
+          "mla_moe_decoder": (scopes.ATTENTION, scopes.FFN) + scopes.MOE_SCOPES}
+ALL_BLOCKS = BLOCKS["mla_moe_decoder"]
 
 
 def layers_of(policy):
     return [layer for layer in scopes.LAYERS
-            if policy == "transformer_ring" or layer.split("/")[-1] not in BLOCKS]
+            if layer.split("/")[-1] in BLOCKS[policy]
+            or layer.split("/")[-1] not in ALL_BLOCKS]
 
 
 def make_trainer(policy):
@@ -108,7 +120,11 @@ def test_every_scan_of_the_step_is_charged_to_its_phase_or_layer(handed_out, pol
     paths = {scope_map[name].path for name in whiles}
     assert scopes.ROLLOUT in paths and scopes.UPDATE in paths
     assert scopes.join(scopes.UPDATE, scopes.GAE) in paths
-    assert all(scope_map[name].direction is None for name in whiles)
+    # a loop INSIDE the loss (the decoder's scan over its expert layers, the
+    # grid of a Pallas kernel interpreted on the CPU) has the loss's direction
+    loss = scopes.join(scopes.UPDATE, scopes.LOSS)
+    assert all(scope_map[name].direction is None for name in whiles
+               if not scope_map[name].path.startswith(loss))
 
 
 HLO = """\
@@ -315,5 +331,36 @@ def test_the_env_dynamics_kernels_carry_their_names(handed_out):
     assert dynamics in {scope.path for scope in scope_map.values()}
 
 
-def test_the_kernel_names_are_four_and_distinct():
-    assert len(set(scopes.KERNEL_NAMES)) == 4
+def test_the_kernel_names_are_six_and_distinct():
+    assert len(set(scopes.KERNEL_NAMES)) == len(scopes.KERNEL_NAMES) == 6
+
+
+def test_a_rematerialised_blocks_path_is_taken_back_and_a_hoisted_constant_rooted():
+    """What PR 29's policy brought: a block recomputed in the backward pass
+    carries its whole name stack a second time, and constants hoisted out of
+    the scanned blocks keep only the phase and the layer."""
+    remat = ("jit(_train_step_impl)/update/while/body/closed_call/loss/"
+             "transpose(jvp(policy_forward))/MlaMoeDecoderPolicy/loss/jvp(policy_forward)/"
+             "MlaMoeDecoderPolicy/checkpoint/rematted_computation/moe/attention/dot_general")
+    assert scopes._op_scope(remat, scopes.SCOPE_NAMES) == scopes.OpScope(
+        "update/loss/policy_forward/attention", scopes.BWD)
+    hoisted = "jit(_train_step_impl)/update/while/body/closed_call/moe/experts/moe_dispatch/iota"
+    assert scopes._op_scope(hoisted, scopes.SCOPE_NAMES).path == \
+        "update/loss/policy_forward/moe_dispatch"
+    assert scopes._rooted("update/loss") == "update/loss"
+    assert scopes._rooted("rollout/nowhere") == "rollout/nowhere"
+
+
+def test_an_op_merged_from_several_sources_takes_the_last_ones_path():
+    """With ``random_episode_start`` the rollout's vmapped reset leaves ops whose
+    ``op_name`` lists every source, ``a/x;a/y``: one such name emptied the whole
+    scope map (PR 29, the traced rehearsal of ``glm47flash_w256_train``)."""
+    merged = ("jit(_train_step_impl)/rollout/vmap()/broadcast_in_dim;"
+              "jit(_train_step_impl)/rollout/policy_act/broadcast_in_dim")
+    assert scopes._op_scope(merged, scopes.SCOPE_NAMES) == scopes.OpScope(
+        "rollout/policy_act", None)
+    text = ('ENTRY %main (p: f32[4]) -> f32[4] {\n'
+            '  %p = f32[4]{0} parameter(0)\n'
+            f'  ROOT %b = f32[4]{{0}} add(%p, %p), metadata={{op_name="{merged}"}}\n'
+            '}\n')
+    assert scopes.scope_map_from_hlo(text) == {"b": scopes.OpScope("rollout/policy_act", None)}

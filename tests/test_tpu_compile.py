@@ -110,6 +110,52 @@ def test_fused_attention_compiles(one_chip, window, dtype, direction):
                                 "bwd": scopes.KERNEL_ATTENTION_BWD}[direction])
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_fused_attention_compiles_causal_at_the_decoder_trunks_heads(one_chip, direction):
+    """``mla_moe_decoder`` at published widths: 20 heads of 256 (192 nope + 64
+    rope for q.k, 256 for v), causal, window 256, a minibatch's 64 windows."""
+    from gymfx_tpu.ops.fused_attention import fused_window_attention
+
+    x = _sds((64, 256, 20, 256), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return fused_window_attention(q, k, v, causal=True, interpret=False)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    hlo = _compile(fwd if direction == "fwd" else bwd, x, x, x, sharding=one_chip)
+    _assert_kernels_named(hlo, {"fwd": scopes.KERNEL_ATTENTION_FWD,
+                                "bwd": scopes.KERNEL_ATTENTION_BWD}[direction])
+
+
+# ---------------------------------------------------------------------------
+# grouped products of an expert layer (ops/grouped_matmul.py) at the
+# benchmark cell's shapes: 8 experts of 2048 x 3072 (gate|up) and 1536 x 2048
+# (down), the short and the worst-case buffer of a 16,384-token minibatch
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("rows, k, n", [(20480, 2048, 3072), (69632, 1536, 2048)])
+def test_grouped_matmul_compiles(one_chip, rows, k, n, direction):
+    from gymfx_tpu.ops.grouped_matmul import grouped_matmul
+
+    def fwd(lhs, rhs, group, used):
+        return grouped_matmul(lhs, rhs, group, used, tile_rows=512, interpret=False)
+
+    def bwd(lhs, rhs, group, used):
+        return jax.grad(lambda lhs, rhs: fwd(lhs, rhs, group, used).astype(
+            jnp.float32).sum(), argnums=(0, 1))(lhs, rhs)
+
+    hlo = _compile(
+        fwd if direction == "fwd" else bwd,
+        _sds((rows, k), jnp.bfloat16), _sds((8, k, n), jnp.bfloat16),
+        _sds((rows // 512,), jnp.int32), _sds((1,), jnp.int32), sharding=one_chip)
+    _assert_kernels_named(hlo, scopes.KERNEL_GROUPED_MATMUL)
+    assert (scopes.KERNEL_GROUPED_MATMUL_DW in hlo) == (direction == "bwd")
+
+
 # ---------------------------------------------------------------------------
 # per-step obs kernel, the trainers' per-env vmap folded into the grid
 # ---------------------------------------------------------------------------
