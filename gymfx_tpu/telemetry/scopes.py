@@ -17,7 +17,9 @@ its numerics do not change (tests/test_scopes.py pins both).
 
 The scope paths, under ``jit(...)``:
 
-  rollout/policy_act            policy forward, sampling, log-prob pick
+  rollout/policy_act            policy forward, sampling, the chosen action's
+                                log-probability (a select over the actions:
+                                train/common.picked_logp, no gather)
   rollout/env_step/tape_read    the tape's columns read by bar index
   rollout/env_step/dynamics     action coercion, event overlay, fills and
                                 brackets, financing, margin, mark, reward,

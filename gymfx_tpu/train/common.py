@@ -417,6 +417,25 @@ def minibatch_plan(fields, *, scheme: str, n_envs: int, horizon: int,
     return n_total, n_total // minibatches, take
 
 
+def picked_logp(logp_all, action):
+    """Log-probability of the discrete ``action`` along the last axis of
+    ``logp_all``, as a compare and a sum over the few actions there are:
+    no gather (a TPU gather costs per index: PERF.md section 6), and a
+    ``where`` rather than a product with a one-hot, so that a ``-inf``
+    on an action NOT chosen leaves value and gradient finite.  The one
+    pick of the trainers (ppo, impala, portfolio_ppo).
+
+    ``action`` is an integer array of ``logp_all``'s leading shape with
+    values in ``[0, n_actions)``: it comes from
+    ``jax.random.categorical`` or from the stored trajectory.  Outside
+    that range the result is 0, where ``take_along_axis`` gives NaN.
+    """
+    hit = action[..., None] == jnp.arange(
+        logp_all.shape[-1], dtype=action.dtype
+    )
+    return jnp.sum(jnp.where(hit, logp_all, 0.0), axis=-1)
+
+
 def masked_reset(done, fresh_tree, cur_tree):
     """Where ``done`` (batch bool), replace each leaf of ``cur_tree``
     with the (broadcast) corresponding leaf of ``fresh_tree``.  Used for

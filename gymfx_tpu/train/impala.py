@@ -33,7 +33,7 @@ from gymfx_tpu.core import env as env_core
 from gymfx_tpu.core.runtime import Environment
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
 from gymfx_tpu.telemetry import scopes
-from gymfx_tpu.train.common import masked_reset
+from gymfx_tpu.train.common import masked_reset, picked_logp
 from gymfx_tpu.train.policies import (
     flatten_obs,
     gaussian_entropy,
@@ -287,9 +287,7 @@ class ImpalaTrainer:
                 logits = dist
                 keys = jax.random.split(k, logits.shape[0])
                 action = jax.vmap(jax.random.categorical)(keys, logits)
-                logp = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits), action[:, None], axis=1
-                )[:, 0]
+                logp = picked_logp(jax.nn.log_softmax(logits), action)
             env_states2, obs2, reward, done, _ = vstep(
                 cfg, eparams, data, env_states, action
             )
@@ -366,9 +364,7 @@ class ImpalaTrainer:
         else:
             logits = dist
             logp_all = jax.nn.log_softmax(logits)
-            pi_logp = jnp.take_along_axis(
-                logp_all, traj["action"][..., None], axis=-1
-            )[..., 0]
+            pi_logp = picked_logp(logp_all, traj["action"])
             entropy = -jnp.mean(jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1))
         rhos = jnp.exp(pi_logp - traj["mu_logp"])
         vs, pg_adv = self._vtrace(

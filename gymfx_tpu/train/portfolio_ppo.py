@@ -22,7 +22,7 @@ import optax
 
 from gymfx_tpu.core import portfolio as P
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
-from gymfx_tpu.train.common import masked_reset
+from gymfx_tpu.train.common import masked_reset, picked_logp
 from gymfx_tpu.train.policies import RingTransformerEncoder, is_token_policy
 
 
@@ -276,9 +276,9 @@ class PortfolioPPOTrainer:
             rng, k = jax.random.split(rng)
             logits, value = fwd(params, obs_vec)          # (B, I, 3), (B,)
             actions = jax.random.categorical(k, logits)   # (B, I)
-            logp = jnp.take_along_axis(
-                jax.nn.log_softmax(logits), actions[..., None], axis=-1
-            )[..., 0].sum(axis=-1)                        # joint logp
+            logp = picked_logp(
+                jax.nn.log_softmax(logits), actions
+            ).sum(axis=-1)                                # joint logp
             env_states2, obs2, reward, done, _info = vstep(
                 cfg, eparams, data, env_states, actions
             )
@@ -324,9 +324,7 @@ class PortfolioPPOTrainer:
             params, batch["obs"]
         )
         logp_all = jax.nn.log_softmax(logits)
-        logp = jnp.take_along_axis(
-            logp_all, batch["action"][..., None], axis=-1
-        )[..., 0].sum(axis=-1)
+        logp = picked_logp(logp_all, batch["action"]).sum(axis=-1)
         ratio = jnp.exp(logp - batch["logp"])
         adv = batch["adv"]
         adv = (adv - adv.mean()) / (adv.std() + 1e-8)

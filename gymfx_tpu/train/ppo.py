@@ -30,7 +30,7 @@ from gymfx_tpu.core import env as env_core
 from gymfx_tpu.core.runtime import Environment
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
 from gymfx_tpu.telemetry import scopes
-from gymfx_tpu.train.common import masked_reset
+from gymfx_tpu.train.common import masked_reset, picked_logp
 from gymfx_tpu.train.policies import (
     flatten_obs,
     gaussian_entropy,
@@ -405,9 +405,7 @@ class PPOTrainer:
                     logits = dist
                     keys = jax.random.split(k, logits.shape[0])
                     action = jax.vmap(jax.random.categorical)(keys, logits)
-                    logp = jnp.take_along_axis(
-                        jax.nn.log_softmax(logits), action[:, None], axis=1
-                    )[:, 0]
+                    logp = picked_logp(jax.nn.log_softmax(logits), action)
             # env_core.step plants env_step/{tape_read,dynamics,obs}
             env_states2, obs2, reward, done, _ = vstep(
                 cfg, eparams, data, env_states, action
@@ -490,9 +488,7 @@ class PPOTrainer:
         else:
             logits = dist
             logp_all = jax.nn.log_softmax(logits)
-            logp = jnp.take_along_axis(
-                logp_all, batch["action"][:, None], axis=1
-            )[:, 0]
+            logp = picked_logp(logp_all, batch["action"])
             entropy = -jnp.mean(jnp.sum(jnp.exp(logp_all) * logp_all, axis=-1))
         ratio = jnp.exp(logp - batch["logp"])
         adv = batch["adv"]

@@ -39,6 +39,14 @@ def uptrend_df(n=40, start_price=1.1, rate=2e-4):
     return make_df(closes, highs=closes + 1e-5, lows=closes - 1e-5)
 
 
+def gather_op_paths(hlo_text):
+    """The ``op_name`` path of every ``gather`` of an HLO text, inside a
+    fusion or out: where a compiled step still looks rows up by index."""
+    return [line.split('op_name="')[1].split('"')[0]
+            for line in hlo_text.splitlines()
+            if " gather(" in line and 'op_name="' in line]
+
+
 def build_smoke_trainer(family, csv_path, csv2_path=None):
     """Tiny trainer fixture shared by the 2-process distributed smoke
     workers (subprocess scripts) and their in-process single-process

@@ -25,7 +25,7 @@ from gymfx_tpu.config import DEFAULT_VALUES
 from gymfx_tpu.core.runtime import Environment
 from gymfx_tpu.data.feed import MarketDataset
 from gymfx_tpu.telemetry import scopes
-from tests.helpers import uptrend_df
+from tests.helpers import gather_op_paths, uptrend_df
 
 POLICIES = {
     # the env-dynamics kernels interpreted, so that their scopes are there
@@ -97,11 +97,24 @@ def test_no_path_outside_the_vocabulary_and_every_path_rooted(handed_out, policy
 
 @pytest.mark.parametrize("policy", POLICIES)
 def test_the_loss_has_both_directions_and_the_rollout_none(handed_out, policy):
-    _step, scope_map = handed_out[policy]
+    step, scope_map = handed_out[policy]
     ways = {}
     for scope in scope_map.values():
         ways.setdefault(scope.path, set()).add(scope.direction)
     forward = scopes.join(scopes.UPDATE, scopes.LOSS, scopes.POLICY_FORWARD)
+    if policy == "mla_moe_decoder":
+        # XLA:CPU fuses backward ops of the decoder's dispatch with constants
+        # it shares with the forward into ONE fusion that has no op_name of
+        # its own, so it inherits the path and no direction; no other
+        # instruction may lose its direction, and no other policy has one
+        lost = [name for name, scope in scope_map.items()
+                if scope.path == forward and scope.direction is None]
+        assert len(lost) <= 1
+        defined = [line for line in step.as_text().splitlines()
+                   if line.split("=")[0].split()[-1:] in [[f"%{name}"] for name in lost]]
+        assert len(defined) == len(lost)
+        assert all(" fusion(" in line and "op_name=" not in line for line in defined)
+        ways[forward].discard(None)
     assert ways[forward] == {scopes.FWD, scopes.BWD}
     assert scopes.FWD in ways[scopes.join(scopes.UPDATE, scopes.LOSS)]
     for path, found in ways.items():
@@ -125,6 +138,19 @@ def test_every_scan_of_the_step_is_charged_to_its_phase_or_layer(handed_out, pol
     loss = scopes.join(scopes.UPDATE, scopes.LOSS)
     assert all(scope_map[name].direction is None for name in whiles
                if not scope_map[name].path.startswith(loss))
+
+
+@pytest.mark.parametrize("policy", ["mlp", "transformer_ring"])
+def test_no_gather_picks_the_actions_log_probability(handed_out, policy):
+    # train/common.picked_logp is a compare and a sum: every gather left in
+    # the step, inside a fusion or out, reads the tape or takes a minibatch
+    # (the decoder trunk's router keeps a gather of its own under policy_act)
+    step, _scope_map = handed_out[policy]
+    paths = gather_op_paths(step.as_text())
+    assert any(f"/{scopes.TAPE_READ}/" in path for path in paths)
+    assert any(f"/{scopes.MINIBATCH_TAKE}/" in path for path in paths)
+    assert not [path for path in paths
+                if f"/{scopes.POLICY_ACT}/" in path or f"/{scopes.LOSS}/" in path]
 
 
 HLO = """\

@@ -261,6 +261,36 @@ def test_env_mark_reward_kernel_compiles(one_chip, reward):
 
 
 # ---------------------------------------------------------------------------
+# the flagship's whole step (chip_smoke.flagship_config, fewer envs): what
+# the chip's compiler leaves of the lookups by index.  A TPU gather costs
+# per index (PERF.md section 6), so the step has them only where rows are
+# really fetched: the packed tape row and the minibatch's fields.  The
+# action's log-probability is picked by a select (train/common.picked_logp).
+# ---------------------------------------------------------------------------
+def test_flagship_step_gathers_only_the_tape_and_the_minibatch(one_chip, monkeypatch):
+    from gymfx_tpu.ops import dispatch
+    from gymfx_tpu.train.ppo import PPOTrainer, ppo_config_from
+    from tests.helpers import gather_op_paths, make_env, uptrend_df
+
+    # code that asks the backend sees the CPU here: steered in the test
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    env = make_env(
+        uptrend_df(500), num_envs=1024, ppo_horizon=8, ppo_epochs=1,
+        ppo_minibatches=4, policy="mlp", policy_dtype="bfloat16", window_size=32,
+        ppo_minibatch_scheme="env_permute", rollout_collect_dtype="bfloat16",
+        rollout_env_kernel="on")
+    trainer = PPOTrainer(env, ppo_config_from(env.config))
+    hlo = _compile(trainer._train_step_impl,
+                   jax.eval_shape(trainer.init_state, 0), sharding=one_chip)
+
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    paths = gather_op_paths(hlo)
+    taken = [path for path in paths if f"/{scopes.MINIBATCH_TAKE}/" in path]
+    read = [path for path in paths if f"/{scopes.TAPE_READ}/" in path]
+    assert (len(paths), len(taken), len(read)) == (6, 5, 1), paths
+
+
+# ---------------------------------------------------------------------------
 # LOB stream matcher: 1024 books x 24 levels x 4 slots (venue default)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("n_msgs", [16, 256], ids=["seed16", "bench256"])
