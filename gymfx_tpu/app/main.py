@@ -98,22 +98,14 @@ def run_mode(config: Dict[str, Any]) -> Dict[str, Any]:
     loop (the reference validates the mode but runs the same loop for
     all three — app/main.py:84; training/policy are new capability)."""
     if config.get("mode") == "training":
+        from gymfx_tpu.train import impala, pbt, portfolio_ppo, ppo
+        from gymfx_tpu.train.loop import train_entry
+
         trainer = str(config.get("trainer", "ppo")).lower()
-        if trainer == "impala":
-            from gymfx_tpu.train.impala import train_impala_from_config
-
-            return train_impala_from_config(config)
         if trainer == "pbt":
-            from gymfx_tpu.train.pbt import train_pbt_from_config
-
-            return train_pbt_from_config(config)
-        if trainer == "portfolio":
-            from gymfx_tpu.train.portfolio_ppo import train_portfolio_from_config
-
-            return train_portfolio_from_config(config)
-        from gymfx_tpu.train.ppo import train_from_config
-
-        return train_from_config(config)
+            return pbt.train_pbt_from_config(config)
+        specs = {"impala": impala.SPEC, "portfolio": portfolio_ppo.SPEC}
+        return train_entry(config, specs.get(trainer, ppo.SPEC))
     if config.get("mode") == "optimization":
         from gymfx_tpu.train.optimize import optimize_from_config
 

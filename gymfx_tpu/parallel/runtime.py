@@ -2,8 +2,7 @@
 
 Before this module every trainer carried its own copy of the placement
 logic (PPO's private ``_shard_state``, IMPALA/portfolio duplicating the
-same groups through ``train/common.shard_train_state``, PBT's ad-hoc
-``_place``).  :class:`ShardedRuntime` centralizes the whole story:
+same groups, PBT's ad-hoc ``_place``).  :class:`ShardedRuntime` centralizes the whole story:
 
   * the **mesh** (built here from ``mesh_shape`` config, or adopted);
   * the **NamedSharding plan** — one committed placement per state

@@ -1,7 +1,8 @@
-"""Host-side per-iteration resilience hooks shared by the trainer loops.
+"""Host-side per-iteration resilience hooks of the training loop.
 
 The jitted train steps carry the in-graph guards (guards.py); this
-module is the thin host loop around them:
+module is what the one host loop (train/loop.py ``train_loop``, which
+PPO, IMPALA and the portfolio trainer all run) calls round them:
 
   * the SkipMonitor divergence watchdog, run ONE STEP DELAYED — the
     guard counters for iteration ``i`` are fetched only after iteration
@@ -13,7 +14,8 @@ module is the thin host loop around them:
   * the simulated-preemption kill for checkpoint/resume drills
     (``fault_profile`` ``preempt_at`` clause).
 
-One definition so the PPO and IMPALA loops cannot drift.
+One definition of the hooks, constructed in one place: the loop round
+them exists once too, so no trainer's copy can drift.
 """
 from __future__ import annotations
 

@@ -6,14 +6,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-# DelayedLogger grew into the telemetry device stream (same delayed-
-# drain discipline, optionally feeding a MetricsRegistry/JSONL sink);
-# the original class name and construction stay importable from here.
 from gymfx_tpu.telemetry import scopes
-from gymfx_tpu.telemetry.device_stream import (  # noqa: F401
-    DelayedLogger,
-    DeviceMetricStream,
-)
 
 
 def make_train_many(step_impl):
@@ -122,6 +115,77 @@ def make_train_many_overlapped(
         return final, metrics
 
     return jax.jit(impl, static_argnums=1, donate_argnums=0)
+
+
+def wire_step_programs(trainer, *, supersteps: bool = True,
+                       overlap: bool = False,
+                       learner_fields=("params", "opt_state")) -> None:
+    """The tail of every step-program trainer's ``__init__``: jit
+    ``trainer._train_step_impl`` as the donated ``_train_step`` and, with
+    ``supersteps``, as the body of the K-step ``_train_many`` (the
+    pipelined driver under ``overlap``, whose update phase owns
+    ``learner_fields``).
+
+    feed=curriculum: the sampler swaps whole tapes at superstep
+    boundaries, so the tape becomes a TRACED argument of
+    ``_train_step_data`` / ``_train_many_data``: one executable serves
+    every tape, and only the state is donated, never the shared tape."""
+    impl = trainer._train_step_impl
+    trainer._train_step = jax.jit(impl, donate_argnums=0)
+    trainer.curriculum = getattr(trainer.env, "curriculum", None)
+    if trainer.curriculum is not None and overlap:
+        raise ValueError(
+            "feed=curriculum cannot be combined with "
+            "superstep_overlap: the pipelined driver issues rollout "
+            "i+1 before update i, so a tape swap inside the dispatch "
+            "would feed half a superstep from the wrong tape"
+        )
+    if trainer.curriculum is not None:
+        trainer._train_step_data = jax.jit(impl, donate_argnums=0)
+        if supersteps:
+            trainer._train_many_data = make_train_many_with_data(impl)
+    if not supersteps:
+        return
+    if overlap:
+        trainer._train_many = make_train_many_overlapped(
+            trainer._rollout_phase, trainer._update_phase,
+            learner_fields=learner_fields,
+        )
+    else:
+        trainer._train_many = make_train_many(impl)
+
+
+def resolve_collect_dtype(config: Dict[str, Any], policy_dtype) -> Any:
+    """Trajectory-obs storage dtype: the narrower of
+    ``rollout_collect_dtype`` and the policy compute dtype.  Every
+    policy casts its input to its compute dtype at entry, so storing
+    wider than that cast is pure HBM waste (bf16 policies keep the
+    historical bf16 storage under the f32 default), while
+    ``rollout_collect_dtype: bfloat16`` with a f32 policy is the lossy
+    opt-in documented in docs/performance.md."""
+    cd = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
+        str(config.get("rollout_collect_dtype", "float32"))
+    ]
+    if policy_dtype == jnp.bfloat16 or cd == jnp.bfloat16:
+        return jnp.bfloat16
+    return cd
+
+
+def resolve_optimizer_state_dtype(config: Dict[str, Any]) -> Any:
+    """Adam first-moment storage dtype from the config knob.  The
+    master-weight rule is fixed, not configurable: only ``mu`` narrows
+    (it is a smoothed gradient — bf16's ~3 decimal digits track it),
+    while params and ``nu`` stay float32 (``nu`` feeds the 1/sqrt
+    rescale where bf16 quantization would modulate the effective lr).
+    Mirrors :func:`resolve_collect_dtype`'s one-definition discipline —
+    every trainer resolves through here."""
+    dt = str(config.get("optimizer_state_dtype", "float32")).lower()
+    if dt not in ("float32", "bfloat16"):
+        raise ValueError(
+            f"optimizer_state_dtype must be 'float32' or 'bfloat16', "
+            f"got {dt!r}"
+        )
+    return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[dt]
 
 
 def build_train_eval_envs(config: Dict[str, Any]) -> Tuple[Any, Optional[Any]]:
@@ -452,23 +516,6 @@ def masked_reset(done, fresh_tree, cur_tree):
         ),
         fresh_tree,
         cur_tree,
-    )
-
-
-def shard_train_state(
-    mesh,
-    *,
-    params: Dict[str, Any],
-    replicated: Dict[str, Any],
-    batched: Dict[str, Any],
-) -> Dict[str, Any]:
-    """Legacy surface: the placement plan moved to
-    :class:`~gymfx_tpu.parallel.runtime.ShardedRuntime` (one owner for
-    all four trainers); this wrapper keeps old callers working."""
-    from gymfx_tpu.parallel.runtime import ShardedRuntime
-
-    return ShardedRuntime(mesh).place_groups(
-        params=params, replicated=replicated, batched=batched
     )
 
 

@@ -21,19 +21,26 @@ with rho_t = min(rho_bar, pi/mu), c_t = min(c_bar, pi/mu).
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 
 from gymfx_tpu.core import env as env_core
 from gymfx_tpu.core.runtime import Environment
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
+from gymfx_tpu.resilience.faults import apply_fault_profile_to_market_data
 from gymfx_tpu.telemetry import scopes
-from gymfx_tpu.train.common import masked_reset, picked_logp
+from gymfx_tpu.train.common import (
+    build_train_eval_envs,
+    masked_reset,
+    picked_logp,
+    resolve_collect_dtype,
+    resolve_optimizer_state_dtype,
+    wire_step_programs,
+)
+from gymfx_tpu.train.loop import TrainerSpec, train_entry, train_loop
 from gymfx_tpu.train.policies import (
     flatten_obs,
     gaussian_entropy,
@@ -62,33 +69,19 @@ class ImpalaConfig(NamedTuple):
     policy_dtype: Any = jnp.float32
     policy_kwargs: Tuple[Tuple[str, Any], ...] = ()
     # trajectory-obs storage dtype (resolved like PPO's:
-    # train/ppo.resolve_collect_dtype — never wider than policy_dtype)
+    # train/common.resolve_collect_dtype — never wider than policy_dtype)
     collect_dtype: Any = jnp.float32
     # non-finite guard (resilience/guards.py): skip the whole learner
     # update when loss/grads go non-finite and quarantine-reset envs
     # whose segment produced NaN/inf (see train/ppo.py)
     nonfinite_guard: bool = True
     # Adam first-moment storage dtype — resolved through the shared
-    # master-weight rule (train/ppo.resolve_optimizer_state_dtype)
+    # master-weight rule (train/common.resolve_optimizer_state_dtype)
     opt_state_dtype: Any = jnp.float32
     # software-pipelined superstep driver (see train/ppo.PPOConfig);
     # for IMPALA the one-update-stale rollout params are the NATIVE
     # regime — V-trace corrects actor/learner staleness by design
     superstep_overlap: bool = False
-
-
-def _resolve_collect_dtype(config, policy_dtype):
-    # ONE definition of the collect-dtype resolution (train/ppo.py);
-    # imported lazily to keep this module import-light
-    from gymfx_tpu.train.ppo import resolve_collect_dtype
-
-    return resolve_collect_dtype(config, policy_dtype)
-
-
-def _resolve_opt_state_dtype(config):
-    from gymfx_tpu.train.ppo import resolve_optimizer_state_dtype
-
-    return resolve_optimizer_state_dtype(config)
 
 
 def impala_config_from(config: Dict[str, Any]) -> ImpalaConfig:
@@ -112,9 +105,9 @@ def impala_config_from(config: Dict[str, Any]) -> ImpalaConfig:
             (k, tuple(v) if isinstance(v, list) else v)
             for k, v in policy_kwargs_from(config).items()
         ),
-        collect_dtype=_resolve_collect_dtype(config, dt),
+        collect_dtype=resolve_collect_dtype(config, dt),
         nonfinite_guard=bool(config.get("nonfinite_guard", True)),
-        opt_state_dtype=_resolve_opt_state_dtype(config),
+        opt_state_dtype=resolve_optimizer_state_dtype(config),
         superstep_overlap=bool(config.get("superstep_overlap", False)),
     )
 
@@ -131,6 +124,8 @@ class ImpalaState(NamedTuple):
 
 
 class ImpalaTrainer:
+    ALGO = "impala"
+
     # shared placement plan (parallel/runtime.ShardedRuntime): learner
     # AND actor params are tensor-shard candidates, the sync counter
     # replicates with opt/rng, the env batch shards over 'data'
@@ -145,6 +140,9 @@ class ImpalaTrainer:
         self.icfg = icfg
         self.mesh = mesh
         self.runtime = None if mesh is None else ShardedRuntime(mesh)
+        # what the host loop reads beside ALGO (train/loop.py)
+        self.steps_per_iter = icfg.n_envs * icfg.unroll
+        self.nonfinite_guard = icfg.nonfinite_guard
         # V-trace is distribution-agnostic: continuous mode swaps in the
         # Gaussian twin via the shared construction path (only the
         # log-prob and entropy terms change, train/policies.py)
@@ -172,42 +170,15 @@ class ImpalaTrainer:
         # hot path must not re-sort keys per call)
         self.obs_spec = make_obs_spec(reset_obs)
         self._reset_vec = self._encode(reset_obs)
-        self._train_step = jax.jit(self._train_step_impl, donate_argnums=0)
-        from gymfx_tpu.train.common import (
-            make_train_many,
-            make_train_many_overlapped,
-            make_train_many_with_data,
+        # overlapped supersteps: the update phase owns both param sets
+        # (learner gradients, periodic actor sync) and the staleness counter
+        wire_step_programs(
+            self, overlap=icfg.superstep_overlap,
+            learner_fields=(
+                "learner_params", "actor_params", "opt_state",
+                "updates_since_sync",
+            ),
         )
-
-        # feed=curriculum: tape swaps at superstep boundaries with the
-        # tape as a traced argument (see PPOTrainer)
-        self.curriculum = getattr(env, "curriculum", None)
-        if self.curriculum is not None and icfg.superstep_overlap:
-            raise ValueError(
-                "feed=curriculum cannot be combined with "
-                "superstep_overlap: the pipelined driver issues rollout "
-                "i+1 before update i, so a tape swap inside the dispatch "
-                "would feed half a superstep from the wrong tape"
-            )
-        if self.curriculum is not None:
-            self._train_step_data = jax.jit(
-                self._train_step_impl, donate_argnums=0
-            )
-            self._train_many_data = make_train_many_with_data(
-                self._train_step_impl
-            )
-        if icfg.superstep_overlap:
-            # the update phase owns both param sets (learner gradients,
-            # periodic actor sync) and the staleness counter
-            self._train_many = make_train_many_overlapped(
-                self._rollout_phase, self._update_phase,
-                learner_fields=(
-                    "learner_params", "actor_params", "opt_state",
-                    "updates_since_sync",
-                ),
-            )
-        else:
-            self._train_many = make_train_many(self._train_step_impl)
 
     def _encode(self, obs):
         spec = getattr(self, "obs_spec", None)
@@ -298,7 +269,7 @@ class ImpalaTrainer:
             out = dict(
                 # obs stored in the resolved collect dtype (never wider
                 # than the policy's entry cast — see
-                # train/ppo.resolve_collect_dtype); halves the
+                # train/common.resolve_collect_dtype); halves the
                 # learner-pass HBM buffer under bf16
                 obs=obs_vec.astype(self.icfg.collect_dtype),
                 action=action, mu_logp=logp,
@@ -506,271 +477,31 @@ class ImpalaTrainer:
         back stacked on a leading ``(k,)`` axis (see PPOTrainer.train_many)."""
         return self._train_many(state, int(k))
 
+    # -- the host loop's contract (train/loop.py) -----------------------
+    def learner_params(self, state: ImpalaState):
+        return state.learner_params
+
+    def with_params(self, state: ImpalaState, params) -> ImpalaState:
+        # both copies (learner + stale actor), each a buffer of its own
+        return state._replace(
+            learner_params=params,
+            actor_params=jax.tree.map(jnp.copy, params),
+        )
+
+    def profiler_info(self) -> Dict[str, int]:
+        return dict(n_envs=self.icfg.n_envs, horizon=self.icfg.unroll,
+                    update_epochs=1)
+
     def train(self, total_env_steps: int, seed: int = 0, log_every: int = 0,
               initial_state: Optional[ImpalaState] = None,
-              initial_params=None,
-              *, checkpoint_dir: Optional[str] = None,
-              checkpoint_every: int = 0, step_offset: int = 0,
-              checkpoint_metadata: Optional[Dict[str, Any]] = None,
-              max_consecutive_skips: int = 10,
-              preempt_at: Optional[int] = None,
-              supersteps_per_dispatch: int = 1,
-              telemetry=None,
-              mesh_faults=(),
-              checkpoint_keep: int = 0):
-        if initial_state is not None:
-            state = initial_state
-            if self.runtime is not None:
-                state = self.runtime.place_state(state, self.STATE_PLAN)
-        else:
-            state = self.init_state(seed)
-        if initial_params is not None:
-            # params-only warm start: both copies (learner + stale actor)
-            state = state._replace(
-                learner_params=initial_params,
-                actor_params=jax.tree.map(jnp.copy, initial_params),
-            )
-            if self.runtime is not None:
-                # restored host arrays must re-enter the mesh placement
-                state = self.runtime.place_state(state, self.STATE_PLAN)
-        per_iter = self.icfg.n_envs * self.icfg.unroll
-        iters = max(1, int(total_env_steps) // per_iter)
-        from gymfx_tpu.resilience.loop import ResilientLoop
-
-        K = max(1, int(supersteps_per_dispatch or 1))
-        from gymfx_tpu.train.common import DelayedLogger
-
-        if telemetry is not None:
-            logger = telemetry.device_stream(
-                "impala", iters=iters, log_every=log_every,
-                steps_per_iter=per_iter,
-            )
-        else:
-            logger = DelayedLogger("impala", log_every, iters)
-        # mesh health supervision (see PPOTrainer.train): only when a
-        # mesh exists AND something observes it
-        supervisor = None
-        if self.runtime is not None and (mesh_faults or telemetry is not None):
-            from gymfx_tpu.parallel.elastic import MeshSupervisor
-
-            supervisor = MeshSupervisor(self.runtime.mesh)
-        hooks = ResilientLoop(
-            steps_per_iter=per_iter,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every,
-            step_offset=step_offset,
-            checkpoint_metadata=checkpoint_metadata,
-            max_consecutive_skips=(
-                max_consecutive_skips if self.icfg.nonfinite_guard else 0
-            ),
-            preempt_at=preempt_at,
-            loggers=(logger,),
-            ledger=telemetry.ledger if telemetry is not None else None,
-            recorder=telemetry.recorder if telemetry is not None else None,
-            profiler=telemetry.profiler if telemetry is not None else None,
-            mesh_faults=tuple(mesh_faults or ()),
-            supervisor=supervisor,
-            checkpoint_keep=int(checkpoint_keep or 0),
+              initial_params=None, **hooks):
+        """:func:`gymfx_tpu.train.loop.train_loop` on this trainer;
+        ``hooks`` are its keyword arguments."""
+        return train_loop(
+            self, total_env_steps, seed=seed, log_every=log_every,
+            initial_state=initial_state, initial_params=initial_params,
+            **hooks,
         )
-        if telemetry is not None and supervisor is not None:
-            from gymfx_tpu.telemetry import register_mesh_health
-
-            register_mesh_health(telemetry.registry, supervisor, name="impala")
-        if telemetry is not None and telemetry.profiler is not None:
-            from gymfx_tpu.train.common import profiler_workload
-
-            # late-binding over the rebound local (see PPO): resolved
-            # at bundle-write time against the live state
-            telemetry.profiler.set_workload_source(
-                lambda it_start, kk: profiler_workload(
-                    self, state, kk, algo="impala",
-                    params=state.learner_params,
-                    n_envs=self.icfg.n_envs, horizon=self.icfg.unroll,
-                )
-            )
-        if telemetry is not None and telemetry.recorder is not None:
-            # the closure reads the rebound local, so a postmortem dump
-            # captures the rng key the run DIED with, not the seed key
-            telemetry.recorder.set_rng_source(lambda: state.rng)
-        if telemetry is not None and hooks.monitor is not None:
-            from gymfx_tpu.telemetry import register_resilience
-
-            register_resilience(
-                telemetry.registry, monitor=hooks.monitor, name="impala"
-            )
-        from gymfx_tpu.telemetry import null_tracer
-
-        tracer = telemetry.tracer if telemetry is not None else null_tracer()
-        t0 = time.perf_counter()
-        metrics: Dict[str, Any] = {}
-        it = 0
-        while it < iters:
-            k = min(K, iters - it)
-            capturing = hooks.begin_superstep(it, k)
-            # curriculum: one seed-deterministic weighted tape draw per
-            # superstep boundary (ledgered as a curriculum_pick row)
-            tape = None
-            if self.curriculum is not None:
-                _ti, _label, tape = self.curriculum.pick(it)
-            with tracer.span("train/superstep", algo="impala", it=it, k=k):
-                if k == 1:
-                    if tape is None:
-                        state, metrics = self.train_step(state)
-                    else:
-                        state, metrics = self._train_step_data(state, tape)
-                    guard_metrics = metrics
-                else:
-                    if tape is None:
-                        state, stacked = self.train_many(state, k)
-                    else:
-                        state, stacked = self._train_many_data(state, tape, k)
-                    metrics = jax.tree.map(lambda x: x[-1], stacked)
-                    guard_metrics = stacked
-            if capturing:
-                # sync so the trace window covers the device work —
-                # only on capture supersteps (see PPO)
-                jax.block_until_ready(state)
-            # logger first: an aborting hook flushes the attached logger,
-            # which must already hold this superstep's metrics (see PPO)
-            logger.after_dispatch(it, k, guard_metrics)
-            hooks.after_superstep(
-                it, k, guard_metrics,
-                lambda: (state._asdict(), state.learner_params),
-            )
-            it += k
-        logger.finish()
-        hooks.finish(lambda: (state._asdict(), state.learner_params))
-        jax.block_until_ready(state.learner_params)
-        dt = time.perf_counter() - t0
-        out = {k: float(v) for k, v in metrics.items()}
-        out["env_steps_per_sec"] = per_iter * iters / dt
-        out["iterations"] = iters
-        out["total_env_steps"] = per_iter * iters
-        if hooks.last_checkpoint_step is not None:
-            out["last_checkpoint_step"] = hooks.last_checkpoint_step
-        return state, out
-
-
-def train_impala_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
-    """CLI entry; with ``elastic_resume`` set the run routes through the
-    elastic auto-resume controller (parallel/elastic.py, see
-    train/ppo.py train_from_config)."""
-    from gymfx_tpu.parallel.elastic import elastic_entry
-
-    return elastic_entry(
-        _train_impala_from_config, config,
-        must_divide=(int(config.get("num_envs", 256) or 256),),
-    )
-
-
-def _train_impala_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
-    from gymfx_tpu.train.common import build_train_eval_envs
-
-    env, eval_env = build_train_eval_envs(config)
-    # chaos runs: contaminate the TRAINING feed per the fault_profile
-    # knob before the trainer closes over it (train/ppo.py)
-    from gymfx_tpu.resilience.faults import (
-        apply_fault_profile_to_market_data,
-        parse_fault_profile,
-    )
-
-    profile = parse_fault_profile(config.get("fault_profile"))
-    if profile["nan_bars"] or profile["inf_bars"] or profile.get("scengen"):
-        env.data = apply_fault_profile_to_market_data(env.data, profile)
-    icfg = impala_config_from(config)
-    from gymfx_tpu.parallel import mesh_from_config, validate_batch_axis
-
-    mesh = mesh_from_config(config)
-    validate_batch_axis(mesh, icfg.n_envs, "num_envs")
-    trainer = ImpalaTrainer(env, icfg, mesh=mesh)
-    total = int(config.get("train_total_steps", 1_000_000))
-    from gymfx_tpu.train.checkpoint import resume_from_config
-
-    resume_state, resume_params, resume_step = resume_from_config(
-        config, trainer, ImpalaState
-    )
-    from gymfx_tpu.telemetry import telemetry_from_config
-
-    telemetry = telemetry_from_config(config)
-    if telemetry is not None and telemetry.ledger is not None and (
-            resume_state is not None or resume_params is not None):
-        telemetry.ledger.record("checkpoint_restore", step=int(resume_step))
-        if config.get("elastic_attempt"):
-            # elastic re-entry: digest-verified restore re-entering the
-            # SURVIVOR mesh plan (see train/ppo.py)
-            telemetry.ledger.record(
-                "mesh_resume", step=int(resume_step),
-                attempt=int(config["elastic_attempt"]), verified=True,
-                mesh_shape=dict(mesh.shape) if mesh is not None else None,
-            )
-    try:
-        state, train_metrics = trainer.train(
-            total, seed=int(config.get("seed", 0) or 0),
-            initial_state=resume_state, initial_params=resume_params,
-            checkpoint_dir=config.get("checkpoint_dir"),
-            checkpoint_every=int(config.get("checkpoint_every", 0) or 0),
-            step_offset=resume_step,
-            checkpoint_metadata={"policy": icfg.policy,
-                                 "policy_kwargs": dict(icfg.policy_kwargs)},
-            max_consecutive_skips=int(
-                config.get("guard_max_consecutive_skips", 10) or 0
-            ),
-            supersteps_per_dispatch=int(
-                config.get("supersteps_per_dispatch", 1) or 1
-            ),
-            preempt_at=profile.get("preempt_at"),
-            telemetry=telemetry,
-            mesh_faults=profile.get("mesh") or (),
-            checkpoint_keep=int(config.get("checkpoint_keep", 0) or 0),
-        )
-    except BaseException:
-        # abort paths (preemption drill, divergence) still seal the run
-        # ledger with its run_end row — the postmortem bundle was
-        # already dumped by ResilientLoop before the raise
-        if telemetry is not None:
-            telemetry.close()
-        raise
-    if telemetry is not None and telemetry.sink is not None:
-        telemetry.sink.append({
-            "kind": "metrics_snapshot", "algo": "impala",
-            "registry": telemetry.registry.snapshot(),
-        })
-    if telemetry is not None:
-        telemetry.close()
-
-    # greedy eval through the shared evaluate() machinery
-    from gymfx_tpu.train import ppo as ppo_mod
-
-    from gymfx_tpu.train.common import labeled_eval_summary
-
-    summary = labeled_eval_summary(
-        lambda e: ppo_mod.evaluate(
-            _EvalShim(trainer, env=e), state.learner_params
-        ),
-        env, eval_env,
-    )
-    summary["train_metrics"] = train_metrics
-    if mesh is not None:
-        summary["mesh_shape"] = dict(mesh.shape)
-
-    ckpt_dir = config.get("checkpoint_dir")
-    if ckpt_dir:
-        from gymfx_tpu.train.checkpoint import save_checkpoint
-
-        # skip when the periodic auto-checkpoint already landed here
-        final_step = resume_step + train_metrics["total_env_steps"]
-        if train_metrics.get("last_checkpoint_step") != final_step:
-            save_checkpoint(
-                ckpt_dir, state._asdict(),
-                step=final_step,
-                metadata={"policy": icfg.policy,
-                          "policy_kwargs": dict(icfg.policy_kwargs)},
-                params=state.learner_params,
-                keep=int(config.get("checkpoint_keep", 0) or 0),
-                protect=(int(resume_step),),
-            )
-        summary["checkpoint_dir"] = str(ckpt_dir)
-    return summary
 
 
 class _EvalShim:
@@ -784,3 +515,28 @@ class _EvalShim:
         self._policy_forward = trainer._forward
         self._greedy_driver = None
         self._continuous = trainer._continuous
+
+
+def _evaluate(trainer, params, env):
+    # greedy eval through PPO's evaluate() machinery, imported here so
+    # that the trainers' modules do not import each other
+    from gymfx_tpu.train.ppo import evaluate
+
+    return evaluate(_EvalShim(trainer, env=env), params)
+
+
+SPEC = TrainerSpec(
+    build_envs=build_train_eval_envs,
+    config_from=impala_config_from,
+    trainer_cls=ImpalaTrainer,
+    state_cls=ImpalaState,
+    checkpoint_metadata=lambda icfg, env: {
+        "policy": icfg.policy, "policy_kwargs": dict(icfg.policy_kwargs)},
+    evaluate=_evaluate,
+    feed_faults=apply_fault_profile_to_market_data,
+)
+
+
+def train_impala_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
+    """CLI mode=training entry (train/loop.py ``train_entry``)."""
+    return train_entry(config, SPEC)

@@ -11,7 +11,6 @@ Differences from the single-pair trainer (train/ppo.py):
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -22,7 +21,17 @@ import optax
 
 from gymfx_tpu.core import portfolio as P
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
-from gymfx_tpu.train.common import masked_reset, picked_logp
+from gymfx_tpu.train.common import (
+    build_portfolio_train_eval_envs,
+    masked_reset,
+    minibatch_plan,
+    picked_logp,
+    resolve_collect_dtype,
+    resolve_optimizer_state_dtype,
+    validate_minibatch_scheme,
+    wire_step_programs,
+)
+from gymfx_tpu.train.loop import TrainerSpec, train_entry, train_loop
 from gymfx_tpu.train.policies import RingTransformerEncoder, is_token_policy
 
 
@@ -128,12 +137,32 @@ class PortfolioPPOConfig(NamedTuple):
     policy_dtype: Any = jnp.float32
     # trajectory-obs storage dtype — THE widest buffers in the repo
     # ((T, N, window*pairs*features) portfolio obs); resolved like the
-    # single-pair trainers (train/ppo.resolve_collect_dtype)
+    # single-pair trainers (train/common.resolve_collect_dtype)
     collect_dtype: Any = jnp.float32
-    # Adam first-moment dtype (train/ppo.resolve_optimizer_state_dtype):
+    # Adam first-moment dtype (train/common.resolve_optimizer_state_dtype):
     # only mu narrows — nu feeds the 1/sqrt(nu) rescale and stays f32
     # alongside the master weights
     opt_state_dtype: Any = jnp.float32
+
+
+def portfolio_config_from(config: Dict[str, Any]) -> PortfolioPPOConfig:
+    dt = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
+        str(config.get("policy_dtype", "float32"))
+    ]
+    return PortfolioPPOConfig(
+        n_envs=int(config.get("num_envs", 64) or 64),
+        horizon=int(config.get("ppo_horizon", 64)),
+        epochs=int(config.get("ppo_epochs", 2)),
+        minibatches=int(config.get("ppo_minibatches", 4)),
+        lr=float(config.get("learning_rate", 3e-4)),
+        policy=str(config.get("policy") or "mlp"),
+        minibatch_scheme=str(
+            config.get("ppo_minibatch_scheme", "env_permute")
+        ),
+        policy_dtype=dt,
+        collect_dtype=resolve_collect_dtype(config, dt),
+        opt_state_dtype=resolve_optimizer_state_dtype(config),
+    )
 
 
 class PortfolioTrainState(NamedTuple):
@@ -166,6 +195,8 @@ def _encode_tokens(obs: Dict[str, Any], window: int):
 
 
 class PortfolioPPOTrainer:
+    ALGO = "portfolio_ppo"
+
     # shared placement plan (parallel/runtime.ShardedRuntime); the
     # portfolio state has no recurrent carry — otherwise identical to PPO
     STATE_PLAN = StatePlan(
@@ -180,8 +211,10 @@ class PortfolioPPOTrainer:
         self.pcfg = pcfg
         self.mesh = mesh
         self.runtime = None if mesh is None else ShardedRuntime(mesh)
-        from gymfx_tpu.train.common import validate_minibatch_scheme
-
+        # what the host loop reads beside ALGO (train/loop.py); the step
+        # carries no non-finite guard, so no skip watchdog runs
+        self.steps_per_iter = pcfg.n_envs * pcfg.horizon
+        self.nonfinite_guard = False
         validate_minibatch_scheme(
             pcfg.minibatch_scheme, pcfg.n_envs, pcfg.minibatches,
             horizon=pcfg.horizon,
@@ -213,12 +246,8 @@ class PortfolioPPOTrainer:
         self._window = env.cfg.window_size
         self._is_transformer = is_token_policy(pcfg.policy)
         self._reset_vec = self._encode(reset_obs)
-        self._train_step = jax.jit(self._train_step_impl, donate_argnums=0)
-        # curriculum feed (data/tapes.py): the sampler picks a tape per
-        # iteration and the step runs against it as a traced argument —
-        # donate the state only, never the shared tape
-        self.curriculum = getattr(env, "curriculum", None)
-        self._train_step_data = jax.jit(self._train_step_impl, donate_argnums=0)
+        # no K-step program: the portfolio trains one step a dispatch
+        wire_step_programs(self, supersteps=False)
 
     def _encode(self, obs):
         if self._is_transformer:
@@ -288,7 +317,7 @@ class PortfolioPPOTrainer:
             out = dict(
                 # the (T, N, window*pairs*features) obs block is the
                 # repo's widest trajectory buffer — stored in the
-                # resolved collect dtype (train/ppo.resolve_collect_dtype;
+                # resolved collect dtype (train/common.resolve_collect_dtype;
                 # bf16 halves its write+read HBM traffic); actions/
                 # log-probs/values stay f32 so ratio numerics hold
                 obs=obs_vec.astype(self.pcfg.collect_dtype),
@@ -373,8 +402,6 @@ class PortfolioPPOTrainer:
             "adv": advs,
             "ret": returns,
         }
-        from gymfx_tpu.train.common import minibatch_plan
-
         n_perm, mb, take = minibatch_plan(
             fields, scheme=pcfg.minibatch_scheme, n_envs=pcfg.n_envs,
             horizon=pcfg.horizon, minibatches=pcfg.minibatches,
@@ -423,87 +450,29 @@ class PortfolioPPOTrainer:
     def train_step(self, state):
         return self._train_step(state)
 
+    # -- the host loop's contract (train/loop.py) -----------------------
+    def learner_params(self, state: PortfolioTrainState):
+        return state.params
+
+    def with_params(self, state: PortfolioTrainState,
+                    params) -> PortfolioTrainState:
+        return state._replace(params=params)
+
+    def profiler_info(self) -> Dict[str, int]:
+        return dict(n_envs=self.pcfg.n_envs, horizon=self.pcfg.horizon,
+                    update_epochs=self.pcfg.epochs)
+
     def train(self, total_env_steps: int, seed: int = 0,
-              initial_params=None, initial_state=None,
-              *, checkpoint_dir: Optional[str] = None,
-              checkpoint_every: int = 0, step_offset: int = 0,
-              checkpoint_metadata: Optional[Dict[str, Any]] = None,
-              preempt_at: Optional[int] = None,
-              telemetry=None,
-              mesh_faults=(),
-              checkpoint_keep: int = 0):
-        """``initial_state`` continues a checkpointed run exactly (full
-        PortfolioTrainState: params + opt state + env batch + RNG);
-        ``initial_params`` is a params-only warm start — the same
-        contract as the single-pair trainers (train/ppo.py).
-
-        The resilience hooks carry the same contract as PPOTrainer.train
-        (resilience/loop.py): periodic full-state checkpoints with
-        retention (``checkpoint_keep``), scripted ``mesh_faults`` and
-        mesh health supervision, simulated preemption, and ledger rows —
-        with every kwarg unset this loop is the exact pre-elastic one."""
-        if initial_state is not None:
-            state = initial_state
-            if self.runtime is not None:
-                state = self.runtime.place_state(state, self.STATE_PLAN)
-        else:
-            state = self.init_state(seed)
-        if initial_params is not None:
-            state = state._replace(params=initial_params)
-            if self.runtime is not None:
-                # restored host arrays must re-enter the mesh placement
-                # (model-axis tensor sharding), like the full-state path
-                state = self.runtime.place_state(state, self.STATE_PLAN)
-        per_iter = self.pcfg.n_envs * self.pcfg.horizon
-        iters = max(1, int(total_env_steps) // per_iter)
-        from gymfx_tpu.resilience.loop import ResilientLoop
-
-        supervisor = None
-        if self.runtime is not None and (mesh_faults or telemetry is not None):
-            from gymfx_tpu.parallel.elastic import MeshSupervisor
-
-            supervisor = MeshSupervisor(self.runtime.mesh)
-        hooks = ResilientLoop(
-            steps_per_iter=per_iter,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every,
-            step_offset=step_offset,
-            checkpoint_metadata=checkpoint_metadata,
-            max_consecutive_skips=0,
-            preempt_at=preempt_at,
-            ledger=telemetry.ledger if telemetry is not None else None,
-            recorder=telemetry.recorder if telemetry is not None else None,
-            mesh_faults=tuple(mesh_faults or ()),
-            supervisor=supervisor,
-            checkpoint_keep=int(checkpoint_keep or 0),
+              initial_params=None, initial_state=None, **hooks):
+        """:func:`gymfx_tpu.train.loop.train_loop` on this trainer;
+        ``hooks`` are its keyword arguments.  The same resume contract
+        as the single-pair trainers: ``initial_state`` continues a
+        checkpointed run exactly, ``initial_params`` warm-starts."""
+        return train_loop(
+            self, total_env_steps, seed=seed,
+            initial_params=initial_params, initial_state=initial_state,
+            **hooks,
         )
-        if telemetry is not None and supervisor is not None:
-            from gymfx_tpu.telemetry import register_mesh_health
-
-            register_mesh_health(
-                telemetry.registry, supervisor, name="portfolio_ppo"
-            )
-        t0 = time.perf_counter()
-        metrics: Dict[str, Any] = {}
-        for it in range(iters):
-            hooks.begin_superstep(it, 1)
-            if self.curriculum is not None:
-                _ti, _label, tape = self.curriculum.pick(it)
-                state, metrics = self._train_step_data(state, tape)
-            else:
-                state, metrics = self.train_step(state)
-            hooks.after_superstep(
-                it, 1, metrics, lambda: (state._asdict(), state.params)
-            )
-        hooks.finish(lambda: (state._asdict(), state.params))
-        jax.block_until_ready(state.params)
-        out = {k: float(v) for k, v in metrics.items()}
-        out["env_steps_per_sec"] = per_iter * iters / (time.perf_counter() - t0)
-        out["iterations"] = iters
-        out["total_env_steps"] = per_iter * iters
-        if hooks.last_checkpoint_step is not None:
-            out["last_checkpoint_step"] = hooks.last_checkpoint_step
-        return state, out
 
 
 def evaluate(trainer: "PortfolioPPOTrainer", params,
@@ -576,10 +545,7 @@ def eval_portfolio_policy_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
     evaluation of a checkpointed portfolio policy via the shared
     skeleton (train/common.py eval_checkpointed_policy), with the
     pair-set checked against the checkpoint (positional heads)."""
-    from gymfx_tpu.train.common import (
-        build_portfolio_train_eval_envs,
-        eval_checkpointed_policy,
-    )
+    from gymfx_tpu.train.common import eval_checkpointed_policy
 
     def resolve(meta, cfg):
         stored = str(meta.get("policy") or "")
@@ -605,133 +571,23 @@ def eval_portfolio_policy_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+SPEC = TrainerSpec(
+    build_envs=build_portfolio_train_eval_envs,
+    config_from=portfolio_config_from,
+    trainer_cls=PortfolioPPOTrainer,
+    state_cls=PortfolioTrainState,
+    # composite checkpoints: the FULL train state for exact resume plus
+    # a standalone params item for cheap evaluation restores
+    checkpoint_metadata=lambda pcfg, env: {
+        "policy": f"portfolio_{pcfg.policy}", "pairs": env.pairs},
+    evaluate=lambda trainer, params, env: evaluate(
+        trainer if env is None else PortfolioPPOTrainer(env, trainer.pcfg),
+        params),
+    summary_extra=lambda env: {
+        "mode": "training", "trainer": "portfolio_ppo", "pairs": env.pairs},
+)
+
+
 def train_portfolio_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
-    """CLI entry; with ``elastic_resume`` set the run routes through the
-    elastic auto-resume controller (parallel/elastic.py, see
-    train/ppo.py train_from_config)."""
-    from gymfx_tpu.parallel.elastic import elastic_entry
-
-    return elastic_entry(
-        _train_portfolio_from_config, config,
-        must_divide=(int(config.get("num_envs", 64) or 64),),
-    )
-
-
-def _train_portfolio_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
-    from gymfx_tpu.train.common import (
-        build_portfolio_train_eval_envs,
-        labeled_eval_summary,
-    )
-
-    env, eval_env = build_portfolio_train_eval_envs(config)
-    from gymfx_tpu.train.common import resolve_minibatch_scheme
-    from gymfx_tpu.train.ppo import (
-        resolve_collect_dtype,
-        resolve_optimizer_state_dtype,
-    )
-
-    n_envs = int(config.get("num_envs", 64) or 64)
-    resolve_minibatch_scheme(
-        config, n_envs, int(config.get("ppo_minibatches", 4))
-    )
-    pdt = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
-        str(config.get("policy_dtype", "float32"))
-    ]
-    pcfg = PortfolioPPOConfig(
-        n_envs=n_envs,
-        horizon=int(config.get("ppo_horizon", 64)),
-        epochs=int(config.get("ppo_epochs", 2)),
-        minibatches=int(config.get("ppo_minibatches", 4)),
-        lr=float(config.get("learning_rate", 3e-4)),
-        policy=str(config.get("policy") or "mlp"),
-        minibatch_scheme=str(
-            config.get("ppo_minibatch_scheme", "env_permute")
-        ),
-        policy_dtype=pdt,
-        collect_dtype=resolve_collect_dtype(config, pdt),
-        opt_state_dtype=resolve_optimizer_state_dtype(config),
-    )
-    from gymfx_tpu.parallel import mesh_from_config, validate_batch_axis
-
-    mesh = mesh_from_config(config)
-    validate_batch_axis(mesh, pcfg.n_envs, "num_envs")
-    trainer = PortfolioPPOTrainer(env, pcfg, mesh=mesh)
-    from gymfx_tpu.train.checkpoint import resume_from_config
-
-    # full-state checkpoints continue the exact trajectory (opt moments,
-    # env batch, RNG); legacy params-only ones warm-start — the same
-    # resume contract as PPO/IMPALA (r4 closes the portfolio gap)
-    resume_state, resume_params, resume_step = resume_from_config(
-        config, trainer, PortfolioTrainState
-    )
-    # the elastic/resilience wiring rides the inherited PPOTrainer.train
-    # loop: scripted mesh faults, periodic checkpoints, retention, and
-    # the mesh_resume ledger row on an elastic re-entry
-    from gymfx_tpu.resilience.faults import parse_fault_profile
-    from gymfx_tpu.telemetry import telemetry_from_config
-
-    profile = parse_fault_profile(config.get("fault_profile"))
-    telemetry = telemetry_from_config(config)
-    if telemetry is not None and telemetry.ledger is not None and (
-            resume_state is not None or resume_params is not None):
-        telemetry.ledger.record("checkpoint_restore", step=int(resume_step))
-        if config.get("elastic_attempt"):
-            telemetry.ledger.record(
-                "mesh_resume", step=int(resume_step),
-                attempt=int(config["elastic_attempt"]), verified=True,
-                mesh_shape=dict(mesh.shape) if mesh is not None else None,
-            )
-    try:
-        state, metrics = trainer.train(
-            int(config.get("train_total_steps", 1_000_000)),
-            seed=int(config.get("seed", 0) or 0),
-            initial_params=resume_params, initial_state=resume_state,
-            checkpoint_dir=config.get("checkpoint_dir"),
-            checkpoint_every=int(config.get("checkpoint_every", 0) or 0),
-            step_offset=resume_step,
-            checkpoint_metadata={"policy": f"portfolio_{pcfg.policy}",
-                                 "pairs": env.pairs},
-            preempt_at=profile.get("preempt_at"),
-            telemetry=telemetry,
-            mesh_faults=profile.get("mesh") or (),
-            checkpoint_keep=int(config.get("checkpoint_keep", 0) or 0),
-        )
-    except BaseException:
-        if telemetry is not None:
-            telemetry.close()
-        raise
-    if telemetry is not None:
-        telemetry.close()
-    # held-out evaluation (VERDICT r4 item #3): greedy episode on the
-    # aligned bars the agent never trained on, in-sample riding along
-    summary = labeled_eval_summary(
-        lambda e: evaluate(
-            trainer if e is None else PortfolioPPOTrainer(e, pcfg),
-            state.params,
-        ),
-        env, eval_env,
-    )
-    summary.update({"mode": "training", "trainer": "portfolio_ppo",
-                    "pairs": env.pairs, "train_metrics": metrics})
-    if mesh is not None:
-        summary["mesh_shape"] = dict(mesh.shape)
-    ckpt_dir = config.get("checkpoint_dir")
-    if ckpt_dir:
-        from gymfx_tpu.train.checkpoint import save_checkpoint
-
-        # composite format: the FULL train state for exact resume plus a
-        # standalone params item for cheap evaluation restores; the step
-        # is cumulative so a resumed run advances past the loaded step
-        final_step = resume_step + metrics["total_env_steps"]
-        if metrics.get("last_checkpoint_step") != final_step:
-            save_checkpoint(
-                ckpt_dir, state._asdict(),
-                step=final_step,
-                metadata={"policy": f"portfolio_{pcfg.policy}",
-                          "pairs": env.pairs},
-                params=state.params,
-                keep=int(config.get("checkpoint_keep", 0) or 0),
-                protect=(int(resume_step),),
-            )
-        summary["checkpoint_dir"] = str(ckpt_dir)
-    return summary
+    """CLI mode=training entry (train/loop.py ``train_entry``)."""
+    return train_entry(config, SPEC)

@@ -17,7 +17,6 @@ trainer.  Design:
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -29,8 +28,19 @@ import optax
 from gymfx_tpu.core import env as env_core
 from gymfx_tpu.core.runtime import Environment
 from gymfx_tpu.parallel.runtime import ShardedRuntime, StatePlan
+from gymfx_tpu.resilience.faults import apply_fault_profile_to_market_data
 from gymfx_tpu.telemetry import scopes
-from gymfx_tpu.train.common import masked_reset, picked_logp
+from gymfx_tpu.train.common import (
+    build_train_eval_envs,
+    masked_reset,
+    minibatch_plan,
+    picked_logp,
+    resolve_collect_dtype,
+    resolve_optimizer_state_dtype,
+    validate_minibatch_scheme,
+    wire_step_programs,
+)
+from gymfx_tpu.train.loop import TrainerSpec, train_entry, train_loop
 from gymfx_tpu.train.policies import (
     flatten_obs,
     gaussian_entropy,
@@ -99,39 +109,6 @@ class PPOConfig(NamedTuple):
     update_remat: bool = False
 
 
-def resolve_collect_dtype(config: Dict[str, Any], policy_dtype) -> Any:
-    """Trajectory-obs storage dtype: the narrower of
-    ``rollout_collect_dtype`` and the policy compute dtype.  Every
-    policy casts its input to its compute dtype at entry, so storing
-    wider than that cast is pure HBM waste (bf16 policies keep the
-    historical bf16 storage under the f32 default), while
-    ``rollout_collect_dtype: bfloat16`` with a f32 policy is the lossy
-    opt-in documented in docs/performance.md."""
-    cd = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
-        str(config.get("rollout_collect_dtype", "float32"))
-    ]
-    if policy_dtype == jnp.bfloat16 or cd == jnp.bfloat16:
-        return jnp.bfloat16
-    return cd
-
-
-def resolve_optimizer_state_dtype(config: Dict[str, Any]) -> Any:
-    """Adam first-moment storage dtype from the config knob.  The
-    master-weight rule is fixed, not configurable: only ``mu`` narrows
-    (it is a smoothed gradient — bf16's ~3 decimal digits track it),
-    while params and ``nu`` stay float32 (``nu`` feeds the 1/sqrt
-    rescale where bf16 quantization would modulate the effective lr).
-    Mirrors :func:`resolve_collect_dtype`'s one-definition discipline —
-    every trainer resolves through here."""
-    dt = str(config.get("optimizer_state_dtype", "float32")).lower()
-    if dt not in ("float32", "bfloat16"):
-        raise ValueError(
-            f"optimizer_state_dtype must be 'float32' or 'bfloat16', "
-            f"got {dt!r}"
-        )
-    return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[dt]
-
-
 def ppo_config_from(config: Dict[str, Any]) -> PPOConfig:
     dt = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[
         str(config.get("policy_dtype", "float32"))
@@ -182,6 +159,8 @@ class TrainState(NamedTuple):
 class PPOTrainer:
     """Builds the jitted train_step for (Environment, PPOConfig)."""
 
+    ALGO = "ppo"
+
     # shared placement plan (parallel/runtime.ShardedRuntime): params
     # tensor-shard wide matrices over 'model', opt/rng replicate, the
     # env batch shards its leading axis over 'data'
@@ -196,8 +175,9 @@ class PPOTrainer:
         self.pcfg = pcfg
         self.mesh = mesh
         self.runtime = None if mesh is None else ShardedRuntime(mesh)
-        from gymfx_tpu.train.common import validate_minibatch_scheme
-
+        # what the host loop reads beside ALGO (train/loop.py)
+        self.steps_per_iter = pcfg.n_envs * pcfg.horizon
+        self.nonfinite_guard = pcfg.nonfinite_guard
         validate_minibatch_scheme(
             pcfg.minibatch_scheme, pcfg.n_envs, pcfg.minibatches,
             horizon=pcfg.horizon,
@@ -226,37 +206,7 @@ class PPOTrainer:
         self.obs_dim = self._reset_vec.shape
 
         self._random_start = bool(env.config.get("random_episode_start", False))
-        self._train_step = jax.jit(self._train_step_impl, donate_argnums=0)
-        from gymfx_tpu.train.common import (
-            make_train_many,
-            make_train_many_overlapped,
-            make_train_many_with_data,
-        )
-
-        # feed=curriculum: the sampler swaps whole tapes at superstep
-        # boundaries, so the tape becomes a TRACED train_many argument
-        # (make_train_many_with_data) — one executable serves every tape
-        self.curriculum = getattr(env, "curriculum", None)
-        if self.curriculum is not None and pcfg.superstep_overlap:
-            raise ValueError(
-                "feed=curriculum cannot be combined with "
-                "superstep_overlap: the pipelined driver issues rollout "
-                "i+1 before update i, so a tape swap inside the dispatch "
-                "would feed half a superstep from the wrong tape"
-            )
-        if self.curriculum is not None:
-            self._train_step_data = jax.jit(
-                self._train_step_impl, donate_argnums=0
-            )
-            self._train_many_data = make_train_many_with_data(
-                self._train_step_impl
-            )
-        if pcfg.superstep_overlap:
-            self._train_many = make_train_many_overlapped(
-                self._rollout_phase, self._update_phase
-            )
-        else:
-            self._train_many = make_train_many(self._train_step_impl)
+        wire_step_programs(self, overlap=pcfg.superstep_overlap)
 
     # ------------------------------------------------------------------
     def _make_optimizer(self):
@@ -568,8 +518,6 @@ class PPOTrainer:
             "ret": returns,
             "pcarry": traj["pcarry"],
         }
-        from gymfx_tpu.train.common import minibatch_plan
-
         n_perm, mb, take = minibatch_plan(
             fields, scheme=pcfg.minibatch_scheme, n_envs=pcfg.n_envs,
             horizon=pcfg.horizon, minibatches=pcfg.minibatches,
@@ -708,172 +656,28 @@ class PPOTrainer:
         device, fetched by the caller once per superstep."""
         return self._train_many(state, int(k))
 
+    # -- the host loop's contract (train/loop.py) -----------------------
+    def learner_params(self, state: TrainState):
+        return state.params
+
+    def with_params(self, state: TrainState, params) -> TrainState:
+        return state._replace(params=params)
+
+    def profiler_info(self) -> Dict[str, int]:
+        return dict(n_envs=self.pcfg.n_envs, horizon=self.pcfg.horizon,
+                    update_epochs=self.pcfg.epochs)
+
     def train(self, total_env_steps: int, seed: int = 0, log_every: int = 0,
               initial_params=None, initial_state: Optional[TrainState] = None,
-              *, checkpoint_dir: Optional[str] = None,
-              checkpoint_every: int = 0, step_offset: int = 0,
-              checkpoint_metadata: Optional[Dict[str, Any]] = None,
-              max_consecutive_skips: int = 10,
-              preempt_at: Optional[int] = None,
-              supersteps_per_dispatch: int = 1,
-              telemetry=None,
-              mesh_faults=(),
-              checkpoint_keep: int = 0):
-        """Run PPO for ~total_env_steps; log metrics every ``log_every``
-        iterations when > 0.  ``initial_state`` continues a checkpointed
-        run exactly (full TrainState: params + opt_state + env batch +
-        RNG); ``initial_params`` is a params-only warm start.
-
-        ``supersteps_per_dispatch=K > 1`` drives the loop through
-        :meth:`train_many`: one donated dispatch (and one host metrics
-        fetch) per K iterations.  The iteration trajectory is
-        bit-identical to K=1; resilience checkpoints/preemption land on
-        superstep boundaries.
-
-        Resilience hooks (resilience/loop.py): ``checkpoint_every > 0``
-        auto-saves the full state every that many iterations (cumulative
-        ``step_offset`` + env-steps step ids, preemption-safe resume);
-        under the non-finite guard, ``max_consecutive_skips`` fully-
-        skipped steps in a row abort with NonFiniteDivergenceError;
-        ``preempt_at`` injects a SimulatedPreemptionError after that
-        iteration (checkpoint/resume drills).
-
-        ``telemetry`` (a :class:`gymfx_tpu.telemetry.Telemetry` bundle,
-        None = off) drains the superstep's on-device metric stack into
-        its registry/sink once per dispatch and wraps each dispatch in a
-        span — no extra host syncs either way; with ``telemetry=None``
-        this loop is the exact pre-telemetry one."""
-        if initial_state is not None:
-            state = initial_state
-            if self.runtime is not None:
-                state = self.runtime.place_state(state, self.STATE_PLAN)
-        else:
-            state = self.init_state(seed)
-        if initial_params is not None:
-            state = state._replace(params=initial_params)
-            if self.runtime is not None:
-                # restored host arrays must re-enter the mesh placement
-                # (model-axis tensor sharding), like the full-state path
-                state = self.runtime.place_state(state, self.STATE_PLAN)
-        steps_per_iter = self.pcfg.n_envs * self.pcfg.horizon
-        iters = max(1, int(total_env_steps) // steps_per_iter)
-        from gymfx_tpu.resilience.loop import ResilientLoop
-
-        K = max(1, int(supersteps_per_dispatch or 1))
-        from gymfx_tpu.train.common import DelayedLogger
-
-        if telemetry is not None:
-            logger = telemetry.device_stream(
-                "ppo", iters=iters, log_every=log_every,
-                steps_per_iter=steps_per_iter,
-            )
-        else:
-            logger = DelayedLogger("ppo", log_every, iters)
-        # mesh health supervision (parallel/elastic.py): only when the
-        # run has a mesh AND something observes it — scripted mesh
-        # faults or telemetry — so the no-mesh/no-knobs path is untouched
-        supervisor = None
-        if self.runtime is not None and (mesh_faults or telemetry is not None):
-            from gymfx_tpu.parallel.elastic import MeshSupervisor
-
-            supervisor = MeshSupervisor(self.runtime.mesh)
-        hooks = ResilientLoop(
-            steps_per_iter=steps_per_iter,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every,
-            step_offset=step_offset,
-            checkpoint_metadata=checkpoint_metadata,
-            max_consecutive_skips=(
-                max_consecutive_skips if self.pcfg.nonfinite_guard else 0
-            ),
-            preempt_at=preempt_at,
-            loggers=(logger,),
-            ledger=telemetry.ledger if telemetry is not None else None,
-            recorder=telemetry.recorder if telemetry is not None else None,
-            profiler=telemetry.profiler if telemetry is not None else None,
-            mesh_faults=tuple(mesh_faults or ()),
-            supervisor=supervisor,
-            checkpoint_keep=int(checkpoint_keep or 0),
+              **hooks):
+        """Run PPO for ~total_env_steps: :func:`gymfx_tpu.train.loop.
+        train_loop` on this trainer; ``hooks`` are its keyword arguments
+        (checkpoints, preemption drill, supersteps, telemetry)."""
+        return train_loop(
+            self, total_env_steps, seed=seed, log_every=log_every,
+            initial_params=initial_params, initial_state=initial_state,
+            **hooks,
         )
-        if telemetry is not None and supervisor is not None:
-            from gymfx_tpu.telemetry import register_mesh_health
-
-            register_mesh_health(telemetry.registry, supervisor, name="ppo")
-        if telemetry is not None and telemetry.profiler is not None:
-            from gymfx_tpu.train.common import profiler_workload
-
-            # late-binding over the rebound local: the manifest payload
-            # (HLO scope map, FLOPs, phase split on a state copy) is
-            # resolved at bundle-write time against the live state
-            telemetry.profiler.set_workload_source(
-                lambda it_start, kk: profiler_workload(
-                    self, state, kk, algo="ppo", params=state.params,
-                    n_envs=self.pcfg.n_envs, horizon=self.pcfg.horizon,
-                    update_epochs=self.pcfg.epochs,
-                )
-            )
-        if telemetry is not None and telemetry.recorder is not None:
-            # the closure reads the rebound local, so a postmortem dump
-            # captures the rng key the run DIED with, not the seed key
-            telemetry.recorder.set_rng_source(lambda: state.rng)
-        if telemetry is not None and hooks.monitor is not None:
-            from gymfx_tpu.telemetry import register_resilience
-
-            register_resilience(
-                telemetry.registry, monitor=hooks.monitor, name="ppo"
-            )
-        from gymfx_tpu.telemetry import null_tracer
-
-        tracer = telemetry.tracer if telemetry is not None else null_tracer()
-        t0 = time.perf_counter()
-        metrics = {}
-        it = 0
-        while it < iters:
-            k = min(K, iters - it)
-            capturing = hooks.begin_superstep(it, k)
-            # curriculum: one weighted seed-deterministic tape draw per
-            # superstep boundary (ledgered as a curriculum_pick row)
-            tape = None
-            if self.curriculum is not None:
-                _ti, _label, tape = self.curriculum.pick(it)
-            with tracer.span("train/superstep", algo="ppo", it=it, k=k):
-                if k == 1:
-                    if tape is None:
-                        state, metrics = self.train_step(state)
-                    else:
-                        state, metrics = self._train_step_data(state, tape)
-                    guard_metrics = metrics
-                else:
-                    if tape is None:
-                        state, stacked = self.train_many(state, k)
-                    else:
-                        state, stacked = self._train_many_data(state, tape, k)
-                    # newest iteration's metrics, still on device (no sync)
-                    metrics = jax.tree.map(lambda x: x[-1], stacked)
-                    guard_metrics = stacked
-            if capturing:
-                # the trace window must cover the device work, so the
-                # async dispatch is synced — only on capture supersteps
-                jax.block_until_ready(state)
-            # logger BEFORE hooks: when the hooks abort (preemption,
-            # divergence) they flush the attached logger, so the final
-            # superstep's held metrics must already be in its hands
-            logger.after_dispatch(it, k, guard_metrics)
-            hooks.after_superstep(
-                it, k, guard_metrics, lambda: (state._asdict(), state.params)
-            )
-            it += k
-        logger.finish()
-        hooks.finish(lambda: (state._asdict(), state.params))
-        jax.block_until_ready(state.params)
-        dt = time.perf_counter() - t0
-        metrics = {k: float(v) for k, v in metrics.items()}
-        metrics["env_steps_per_sec"] = steps_per_iter * iters / dt
-        metrics["iterations"] = iters
-        metrics["total_env_steps"] = steps_per_iter * iters
-        if hooks.last_checkpoint_step is not None:
-            metrics["last_checkpoint_step"] = hooks.last_checkpoint_step
-        return state, metrics
 
 
 # ---------------------------------------------------------------------------
@@ -944,10 +748,7 @@ def eval_policy_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
     greedy evaluation episode (shared skeleton:
     train/common.py eval_checkpointed_policy — honors the checkpoint's
     recorded architecture and the out-of-sample keys)."""
-    from gymfx_tpu.train.common import (
-        build_train_eval_envs,
-        eval_checkpointed_policy,
-    )
+    from gymfx_tpu.train.common import eval_checkpointed_policy
 
     def resolve(meta, cfg):
         if not cfg.get("policy") and meta.get("policy"):
@@ -963,139 +764,19 @@ def eval_policy_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+SPEC = TrainerSpec(
+    build_envs=build_train_eval_envs,
+    config_from=ppo_config_from,
+    trainer_cls=PPOTrainer,
+    state_cls=TrainState,
+    checkpoint_metadata=lambda pcfg, env: {
+        "policy": pcfg.policy, "policy_kwargs": dict(pcfg.policy_kwargs)},
+    evaluate=lambda trainer, params, env: evaluate(
+        trainer if env is None else PPOTrainer(env, trainer.pcfg), params),
+    feed_faults=apply_fault_profile_to_market_data,
+)
+
+
 def train_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
-    """CLI mode=training entry: train PPO, optionally checkpoint,
-    return a summary merging training metrics and greedy-eval metrics.
-
-    With ``elastic_resume`` set, the run routes through the elastic
-    auto-resume controller (parallel/elastic.py): device loss re-plans
-    the mesh over survivors and resumes from the last digest-verified
-    checkpoint; unset, this call IS :func:`_train_from_config`."""
-    from gymfx_tpu.parallel.elastic import elastic_entry
-
-    return elastic_entry(
-        _train_from_config, config,
-        must_divide=(int(config.get("num_envs", 256) or 256),),
-    )
-
-
-def _train_from_config(config: Dict[str, Any]) -> Dict[str, Any]:
-    from gymfx_tpu.parallel import mesh_from_config, validate_batch_axis
-    from gymfx_tpu.train.common import build_train_eval_envs
-
-    env, eval_env = build_train_eval_envs(config)
-    # chaos runs: the fault_profile knob contaminates the TRAINING feed
-    # before the trainer closes over it (eval data stays clean so the
-    # guard's effect is measurable)
-    from gymfx_tpu.resilience.faults import (
-        apply_fault_profile_to_market_data,
-        parse_fault_profile,
-    )
-
-    profile = parse_fault_profile(config.get("fault_profile"))
-    if profile["nan_bars"] or profile["inf_bars"] or profile.get("scengen"):
-        env.data = apply_fault_profile_to_market_data(env.data, profile)
-    from gymfx_tpu.train.common import resolve_minibatch_scheme
-
-    resolve_minibatch_scheme(
-        config, int(config.get("num_envs", 256) or 256),
-        int(config.get("ppo_minibatches", 4)),
-    )
-    pcfg = ppo_config_from(config)
-    mesh = mesh_from_config(config)
-    validate_batch_axis(mesh, pcfg.n_envs, "num_envs")
-    trainer = PPOTrainer(env, pcfg, mesh=mesh)
-    total = int(config.get("train_total_steps", 1_000_000))
-    from gymfx_tpu.train.checkpoint import resume_from_config
-
-    # full-state checkpoints continue the exact trajectory (opt moments,
-    # env batch, RNG); params-only ones warm-start
-    resume_state, resume_params, resume_step = resume_from_config(
-        config, trainer, TrainState
-    )
-    ckpt_meta = {"policy": pcfg.policy,
-                 "policy_kwargs": dict(pcfg.policy_kwargs)}
-    from gymfx_tpu.telemetry import telemetry_from_config
-
-    telemetry = telemetry_from_config(config)
-    if telemetry is not None and telemetry.ledger is not None and (
-            resume_state is not None or resume_params is not None):
-        telemetry.ledger.record("checkpoint_restore", step=int(resume_step))
-        if config.get("elastic_attempt"):
-            # elastic re-entry: the restore above came back through the
-            # digest-verified path and re-enters the SURVIVOR mesh plan
-            telemetry.ledger.record(
-                "mesh_resume", step=int(resume_step),
-                attempt=int(config["elastic_attempt"]), verified=True,
-                mesh_shape=dict(mesh.shape) if mesh is not None else None,
-            )
-    try:
-        state, train_metrics = trainer.train(
-            total, seed=int(config.get("seed", 0) or 0),
-            initial_params=resume_params, initial_state=resume_state,
-            checkpoint_dir=config.get("checkpoint_dir"),
-            checkpoint_every=int(config.get("checkpoint_every", 0) or 0),
-            step_offset=resume_step,
-            checkpoint_metadata=ckpt_meta,
-            max_consecutive_skips=int(
-                config.get("guard_max_consecutive_skips", 10) or 0
-            ),
-            preempt_at=profile.get("preempt_at"),
-            supersteps_per_dispatch=int(
-                config.get("supersteps_per_dispatch", 1) or 1
-            ),
-            telemetry=telemetry,
-            mesh_faults=profile.get("mesh") or (),
-            checkpoint_keep=int(config.get("checkpoint_keep", 0) or 0),
-        )
-    except BaseException:
-        # abort paths (preemption drill, divergence) still seal the run
-        # ledger with its run_end row — the postmortem bundle was
-        # already dumped by ResilientLoop before the raise
-        if telemetry is not None:
-            telemetry.close()
-        raise
-    if telemetry is not None and telemetry.sink is not None:
-        telemetry.sink.append({
-            "kind": "metrics_snapshot", "algo": "ppo",
-            "registry": telemetry.registry.snapshot(),
-        })
-    if telemetry is not None:
-        telemetry.close()
-
-    # out-of-sample: greedy episode on bars the agent never trained on
-    # (BASELINE metric 2 made scientifically meaningful); the in-sample
-    # numbers ride along for the generalization gap
-    from gymfx_tpu.train.common import labeled_eval_summary
-
-    summary = labeled_eval_summary(
-        lambda e: evaluate(
-            trainer if e is None else PPOTrainer(e, pcfg), state.params
-        ),
-        env, eval_env,
-    )
-    summary["train_metrics"] = train_metrics
-    if mesh is not None:
-        summary["mesh_shape"] = dict(mesh.shape)
-
-    ckpt_dir = config.get("checkpoint_dir")
-    if ckpt_dir:
-        from gymfx_tpu.train.checkpoint import save_checkpoint
-
-        # cumulative step count: orbax silently skips saving a step that
-        # already exists, so a resumed run must advance past the loaded
-        # step; a periodic auto-checkpoint that already landed on the
-        # final step makes this save redundant
-        final_step = resume_step + train_metrics["total_env_steps"]
-        if train_metrics.get("last_checkpoint_step") != final_step:
-            save_checkpoint(
-                ckpt_dir, state._asdict(),
-                step=final_step,
-                metadata={"policy": pcfg.policy,
-                          "policy_kwargs": dict(pcfg.policy_kwargs)},
-                params=state.params,
-                keep=int(config.get("checkpoint_keep", 0) or 0),
-                protect=(int(resume_step),),
-            )
-        summary["checkpoint_dir"] = str(ckpt_dir)
-    return summary
+    """CLI mode=training entry (train/loop.py ``train_entry``)."""
+    return train_entry(config, SPEC)

@@ -225,7 +225,7 @@ def test_delayed_logger_flushes_one_dispatch_late(capsys):
     """log_every snapshots are held as-is and stringified one dispatch
     later, so logging never forces a host sync on the logged iteration;
     finish() flushes the tail."""
-    from gymfx_tpu.train.common import DelayedLogger
+    from gymfx_tpu.telemetry import DelayedLogger
 
     logger = DelayedLogger("t", log_every=2, iters=4)
     logger.after_dispatch(0, 1, {"loss": 1.0})
@@ -239,7 +239,7 @@ def test_delayed_logger_flushes_one_dispatch_late(capsys):
 
 
 def test_delayed_logger_silent_when_disabled(capsys):
-    from gymfx_tpu.train.common import DelayedLogger
+    from gymfx_tpu.telemetry import DelayedLogger
 
     logger = DelayedLogger("t", log_every=0, iters=4)
     for it in range(4):
