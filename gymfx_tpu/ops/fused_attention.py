@@ -20,6 +20,12 @@ Granularity matters twice here:
     batching rule would add a size-1 grid dimension per env and
     recreate exactly the tiny-program problem.
 
+Layout: heads of 128 lanes or more are cut from (B, H, S, D), a head a
+program.  Narrower heads ride the lanes together, (B, S, H * D) — what a
+projection to d_model writes — a lane group of heads a program, parted
+inside by lane masks (``packed_lanes``; PR 32): a (B, H, S, 32) tensor is
+three quarters lane padding in HBM on the chip.
+
 Numerics run in float32 inside the kernel regardless of the policy
 dtype, like XLA's f32 matmul accumulation on bf16 inputs.
 Differentiable: the backward is a fused Pallas kernel too
@@ -53,13 +59,15 @@ from gymfx_tpu.telemetry.scopes import KERNEL_ATTENTION_BWD, KERNEL_ATTENTION_FW
 # Ulysses backends' territory anyway (parallel/ring_attention.py)
 MAX_FUSED_WINDOW = 1024
 
-# below this window the kernel LOSES to XLA: at W=32 the measured A/B
-# on the v5e chip was 30.8k vs 145.9k env-steps/s — the per-program
-# work is tiny, and the (B,S,H,D)<->(B,H,S,D) transposes around the
-# call cost more than the (small) score tensors ever did.  The fused
-# path only pays off where score HBM traffic is the wall (W^2 scaling):
-# measured 1.43x op-level at W=256.  Callers (policies.py
-# dense_window_attention) route short windows to plain XLA.
+# below this window the kernel LOST to XLA: at W=32 the measured A/B on
+# the v5e chip was 30.8k vs 145.9k env-steps/s — the per-program work is
+# tiny and the score tensors small.  That A/B was of the kernel as it
+# stood before PR 32, with (B,S,H,D)<->(B,H,S,D) transposes round the
+# call; narrow heads now go in as the projections write them, and no
+# short window has been measured since (no cell runs one).  The fused
+# path pays off where score HBM traffic is the wall (W^2 scaling).
+# Callers (policies.py ``_takes_fused_kernel``) route short windows to
+# plain XLA.
 MIN_FUSED_WINDOW = 192
 
 
@@ -67,39 +75,74 @@ MIN_FUSED_WINDOW = 192
 # of Mosaic (its default on v5e is 16 MiB of the core's 128 MiB).  The
 # gap is headroom for the compiler's own temporaries; the smallest block
 # (one env) at MAX_FUSED_WINDOW in float32 needs it: its backward with
-# HIGHEST-precision dots allocates 16.8 MB.
-_VMEM_BUDGET = 12 * 1024 * 1024
+# HIGHEST-precision dots allocates 16.8 MB.  Half the limit: over packed
+# heads ``_env_block`` counts what Mosaic allocates to within a tenth
+# (bf16, window 256, 4 x 32: 12.4 MB for the backward's 4 envs, 13.8 MB
+# for the forward's 8), and 12 MB held the backward to 2 envs a program,
+# a quarter slower on the chip (PR 32: 11.73 against 9.40 ms over 4,096
+# windows; the forward reads 3.63 ms at any block).
+_VMEM_BUDGET = 16 * 1024 * 1024
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
+
+_LANES = 128
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _env_block(batch: int, window: int, head_dim: int, itemsize: int,
-               io_blocks: int, score_blocks_live: int) -> int:
+def packed_lanes(n_heads: int, head_dim: int) -> int:
+    """Lanes of one program's block where the kernel takes heads PACKED
+    side by side on the minor axis, ``(B, S, H * D)``; 0 where it takes
+    them apart, ``(B, H, S, D)``.
+
+    The chip tiles the minor axis to 128 lanes.  A head of 128 or more
+    fills its tiles, and its (S, D) faces are cut from ``(B, H, S, D)``.
+    A narrower head does not: ``(B, H, S, 32)`` holds one value in four
+    IN HBM, in every tensor a projection writes, the kernel reads and
+    writes, and the backward pass keeps (PR 32: 1,074 MB for 268 MB of
+    values, and a train step bound by that traffic).  Such heads ride the
+    lanes together, ``min(H * D, 128)`` lanes a program, as the
+    projections write them; the kernel parts them by lane masks."""
+    width = n_heads * head_dim
+    lanes = min(width, _LANES)
+    if head_dim >= _LANES or lanes % head_dim or width % lanes:
+        return 0
+    return lanes
+
+
+def _env_block(batch: int, window: int, lanes: int, itemsize: int,
+               io_blocks: int, f32_faces: int, score_blocks_live: int) -> int:
     """Envs per program: amortize program overhead while one program's
     VMEM stays inside ``_VMEM_BUDGET``.  Counted per env, at the size
     Mosaic really allocates:
 
-      * the ``io_blocks`` (S, D) q/k/v/o (backward: q/k/v/g/dq/dk/dv)
-        faces — D lane-padded to 128, S padded to the dtype's sublane
+      * the ``io_blocks`` (S, lanes) q/k/v/o (backward: q/k/v/g/dq/dk/dv)
+        faces — lanes padded to 128, S padded to the dtype's sublane
         tile, and each DOUBLE-buffered by the pipeline;
+      * the ``f32_faces`` (S, lanes) float32 values a program over packed
+        heads holds through its head loop: the operands converted, the
+        accumulators of o (backward: dq/dk/dv), one head's masked operands
+        and products.  A program of ONE head counts none, as it always
+        has (they come out of the headroom; its blocks are the ones the
+        chip has run since PR 22);
       * the live f32 score-shaped values (forward: scores; backward:
-        scores/p, dp, ds), S x S with the key axis lane-padded.
+        scores/p, dp, ds), S x S with the key axis lane-padded — one
+        head's at a time.
 
     The forward at (256 envs, window 256, 4 x 32) in float32 is the
     shape the old score-only budget got wrong: 16 envs of q/k/v/o are
     16.8 MB before a single score is computed."""
     sublane = 8 * max(1, 4 // itemsize)
     io = (
-        io_blocks * _round_up(window, sublane) * _round_up(head_dim, 128)
+        io_blocks * _round_up(window, sublane) * _round_up(lanes, _LANES)
         * itemsize * 2
     )
+    held = f32_faces * _round_up(window, 8) * _round_up(lanes, _LANES) * 4
     scores = (
-        score_blocks_live * _round_up(window, 8) * _round_up(window, 128) * 4
+        score_blocks_live * _round_up(window, 8) * _round_up(window, _LANES) * 4
     )
-    budget = max(1, _VMEM_BUDGET // (io + scores))
+    budget = max(1, _VMEM_BUDGET // (io + held + scores))
     for eb in (16, 8, 4, 2, 1):
         if eb <= budget and batch % eb == 0:
             return eb
@@ -116,11 +159,30 @@ def _precision(ref):
     return jax.lax.Precision.HIGHEST if ref.dtype == jnp.float32 else None
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool):
-    prec = _precision(q_ref)
-    q = q_ref[:, 0].astype(jnp.float32)   # (eb, S, D)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)
+def _face(ref):
+    """A block's (eb, S, lanes) values in float32: the block is
+    (eb, 1, S, D) cut from (B, H, S, D), or (eb, S, lanes) cut from
+    packed (B, S, H * D)."""
+    return (ref[:, 0] if len(ref.shape) == 4 else ref[...]).astype(jnp.float32)
+
+
+def _put(ref, value):
+    """``_face``'s way back: (eb, S, lanes) values into a block."""
+    if len(ref.shape) == 4:
+        ref[:, 0] = value.astype(ref.dtype)
+    else:
+        ref[...] = value.astype(ref.dtype)
+
+
+def _head_masks(lanes: int, head_dim: int):
+    """One (1, 1, lanes) mask per packed head: the lanes that are its."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, lanes), 2)
+    return [(lane >= lo) & (lane < lo + head_dim)
+            for lo in range(0, lanes, head_dim)]
+
+
+def _probabilities(q, k, scale: float, causal: bool, prec):
+    """exp(scores - rowmax) of one head, (eb, Sq, Sk), unnormalised."""
     scores = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=prec,
@@ -131,42 +193,36 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool):
         col = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
         scores = jnp.where((row >= col)[None], scores, -jnp.inf)
     m = jnp.max(scores, axis=-1, keepdims=True)
-    p = jnp.exp(scores - m)
+    return jnp.exp(scores - m)
+
+
+def _head_forward(q, k, v, scale: float, causal: bool, prec):
+    """softmax(q k^T) v of ONE head on float32 (eb, S, lanes) values.
+    Over packed heads ``q`` comes masked to the head's lanes, so the
+    contraction over all the lanes IS the head's q . k^T, and the head's
+    lanes of the result are its output (the others are dropped by the
+    caller): the MXU passes a (S, 32) face padded to 128 lanes paid too."""
+    p = _probabilities(q, k, scale, causal, prec)
     num = jax.lax.dot_general(
         p, v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=prec,
-    )                                      # (eb, S, D)
-    out = num / jnp.sum(p, axis=-1, keepdims=True)
-    o_ref[:, 0] = out.astype(o_ref.dtype)
+    )                                      # (eb, S, lanes)
+    return num / jnp.sum(p, axis=-1, keepdims=True)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, dq_ref, dk_ref, dv_ref, *,
-                scale: float, causal: bool):
-    """VMEM-resident attention backward: recompute the score block from
-    q/k (cheaper than ever writing it to HBM), then the standard
+def _head_backward(q, k, v, g, scale: float, causal: bool, prec):
+    """dq, dk, dv of ONE head: recompute the score block from q/k
+    (cheaper than ever writing it to HBM), then the standard
     softmax-attention gradients — dV = P^T dO, dP = dO V^T,
-    dS = P (dP - rowsum(dP P)), dQ = scale dS K, dK = scale dS^T Q."""
-    prec = _precision(q_ref)
-    q = q_ref[:, 0].astype(jnp.float32)   # (eb, S, D)
-    k = k_ref[:, 0].astype(jnp.float32)
-    v = v_ref[:, 0].astype(jnp.float32)
-    g = g_ref[:, 0].astype(jnp.float32)
-    scores = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32, precision=prec,
-    ) * scale
-    if causal:
-        s = scores.shape[-1]
-        row = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-        scores = jnp.where((row >= col)[None], scores, -jnp.inf)
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    e = jnp.exp(scores - m)
+    dS = P (dP - rowsum(dP P)), dQ = scale dS K, dK = scale dS^T Q.
+    Over packed heads ``q`` and ``g`` come masked to the head's lanes;
+    the head's lanes of each result are its gradients."""
+    e = _probabilities(q, k, scale, causal, prec)
     p = e / jnp.sum(e, axis=-1, keepdims=True)      # (eb, Sq, Sk)
     dv = jax.lax.dot_general(
         p, g, (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=prec,
-    )                                               # (eb, Sk, D)
+    )                                               # (eb, Sk, lanes)
     dp = jax.lax.dot_general(
         g, v, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=prec,
@@ -181,81 +237,146 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, dq_ref, dk_ref, dv_ref, *,
         ds, q, (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=prec,
     )
-    dq_ref[:, 0] = dq.astype(dq_ref.dtype)
-    dk_ref[:, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[:, 0] = dv.astype(dv_ref.dtype)
+    return dq, dk, dv
 
 
-def _backward_batched(q, k, v, g, causal: bool, interpret: bool):
-    """Fused backward on (B, S, H, D) primals + cotangent."""
-    b, s, h, d = q.shape
-    eb = _env_block(b, s, d, q.dtype.itemsize, io_blocks=7,
-                    score_blocks_live=3)
-    scale = 1.0 / (d ** 0.5)
-    kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal)
-    spec = pl.BlockSpec((eb, 1, s, d), lambda i, j: (i, j, 0, 0))
-    call = pl.pallas_call(
+def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
+            head_dim: int):
+    prec = _precision(q_ref)
+    q, k, v = _face(q_ref), _face(k_ref), _face(v_ref)
+    lanes = q.shape[-1]
+    if lanes == head_dim:
+        out = _head_forward(q, k, v, scale, causal, prec)
+    else:
+        out = jnp.zeros_like(q)
+        for mask in _head_masks(lanes, head_dim):
+            head = _head_forward(
+                jnp.where(mask, q, 0.0), k, v, scale, causal, prec)
+            out = jnp.where(mask, head, out)
+    _put(o_ref, out)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, dq_ref, dk_ref, dv_ref, *,
+                scale: float, causal: bool, head_dim: int):
+    """VMEM-resident attention backward (``_head_backward``), head by
+    head where the block packs several."""
+    prec = _precision(q_ref)
+    q, k, v, g = _face(q_ref), _face(k_ref), _face(v_ref), _face(g_ref)
+    lanes = q.shape[-1]
+    if lanes == head_dim:
+        dq, dk, dv = _head_backward(q, k, v, g, scale, causal, prec)
+    else:
+        dq, dk, dv = jnp.zeros_like(q), jnp.zeros_like(q), jnp.zeros_like(q)
+        for mask in _head_masks(lanes, head_dim):
+            head = _head_backward(
+                jnp.where(mask, q, 0.0), k, v, jnp.where(mask, g, 0.0),
+                scale, causal, prec)
+            dq, dk, dv = (jnp.where(mask, new, old)
+                          for new, old in zip(head, (dq, dk, dv)))
+    _put(dq_ref, dq)
+    _put(dk_ref, dk)
+    _put(dv_ref, dv)
+
+
+def _blocks(q, n_heads, io_blocks: int, f32_faces: int,
+            score_blocks_live: int):
+    """Grid, block spec and head_dim for operands shaped like ``q``, in
+    the kernel's layout: packed (B, S, H * D) with ``n_heads`` given —
+    blocks (eb, S, lanes), a lane group of heads a program — or
+    (B, H, S, D) with ``n_heads`` None — blocks (eb, 1, S, D), a head a
+    program.  Mosaic requires the last two block dims to tile (8, 128)
+    or span the array, so the (S, lanes) face stays whole."""
+    if n_heads is None:
+        b, h, s, d = q.shape
+        eb = _env_block(b, s, d, q.dtype.itemsize, io_blocks, 0,
+                        score_blocks_live)
+        spec = pl.BlockSpec((eb, 1, s, d), lambda i, j: (i, j, 0, 0))
+        return (b // eb, h), spec, d
+    b, s, width = q.shape
+    d = width // n_heads
+    lanes = packed_lanes(n_heads, d)
+    eb = _env_block(b, s, lanes, q.dtype.itemsize, io_blocks, f32_faces,
+                    score_blocks_live)
+    spec = pl.BlockSpec((eb, s, lanes), lambda i, j: (i, 0, j))
+    return (b // eb, width // lanes), spec, d
+
+
+def _kernel_layout(x, n_heads):
+    """(B, S, H, D) <-> the (B, H, S, D) the kernel cuts one head's faces
+    from; packed operands are in the kernel's layout as they come."""
+    return x if n_heads is not None else jnp.swapaxes(x, 1, 2)
+
+
+def _backward_batched(q, k, v, g, causal: bool, interpret: bool, n_heads):
+    """Fused backward on (B, S, H, D), or packed (B, S, H * D), primals
+    + cotangent."""
+    q, k, v, g = (_kernel_layout(x, n_heads) for x in (q, k, v, g))
+    # held in float32 over packed heads: q/k/v/g, dq/dk/dv, one head's
+    # masked q and g and its three products
+    grid, spec, d = _blocks(q, n_heads, io_blocks=7, f32_faces=12,
+                            score_blocks_live=3)
+    kernel = functools.partial(
+        _bwd_kernel, scale=1.0 / (d ** 0.5), causal=causal, head_dim=d)
+    grads = pl.pallas_call(
         kernel,
-        grid=(b // eb, h),
+        grid=grid,
         in_specs=[spec] * 4,
         out_specs=[spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype)] * 3,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=KERNEL_ATTENTION_BWD,
-    )
-    sw = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
-    dq, dk, dv = call(sw(q), sw(k), sw(v), sw(g))
-    return sw(dq), sw(dk), sw(dv)
+    )(q, k, v, g)
+    return tuple(_kernel_layout(x, n_heads) for x in grads)
 
 
-def _forward_batched(q, k, v, causal: bool, interpret: bool):
-    """Fused pass on (B, S, H, D) inputs."""
-    b, s, h, d = q.shape
-    eb = _env_block(b, s, d, q.dtype.itemsize, io_blocks=4,
-                    score_blocks_live=1)
-    scale = 1.0 / (d ** 0.5)
-    kernel = functools.partial(_kernel, scale=scale, causal=causal)
-    # (B, H, S, D) layout: heads and env blocks ride the grid; Mosaic
-    # requires the last two block dims to tile (8, 128) or span the
-    # array, so the (S, D) face stays whole
-    call = pl.pallas_call(
+def _forward_batched(q, k, v, causal: bool, interpret: bool, n_heads):
+    """Fused pass on (B, S, H, D), or packed (B, S, H * D), inputs."""
+    q, k, v = (_kernel_layout(x, n_heads) for x in (q, k, v))
+    # held in float32 over packed heads: q/k/v, o, one head's masked q
+    # and its product
+    grid, spec, d = _blocks(q, n_heads, io_blocks=4, f32_faces=6,
+                            score_blocks_live=1)
+    kernel = functools.partial(
+        _kernel, scale=1.0 / (d ** 0.5), causal=causal, head_dim=d)
+    out = pl.pallas_call(
         kernel,
-        grid=(b // eb, h),
-        in_specs=[pl.BlockSpec((eb, 1, s, d), lambda i, j: (i, j, 0, 0))] * 3,
-        out_specs=pl.BlockSpec((eb, 1, s, d), lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+        grid=grid,
+        in_specs=[spec] * 3,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=KERNEL_ATTENTION_FWD,
-    )
-    out = call(
-        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
-    )
-    return jnp.swapaxes(out, 1, 2)
+    )(q, k, v)
+    return _kernel_layout(out, n_heads)
 
 
 @functools.lru_cache(maxsize=None)
-def _make(causal: bool, interpret: bool):
+def _make(causal: bool, interpret: bool, n_heads):
+    """The differentiable, vmap-collapsing attention of one env,
+    ``attend`` on (S, H, D), and of a batch, ``attend_batched`` on
+    (B, S, H, D); with ``n_heads`` given, on packed (S, H * D) and
+    (B, S, H * D)."""
     from jax.custom_batching import custom_vmap
 
     @jax.custom_vjp
-    def attend_batched(q, k, v):           # (B, S, H, D)
-        return _forward_batched(q, k, v, causal, interpret)
+    def attend_batched(q, k, v):
+        return _forward_batched(q, k, v, causal, interpret, n_heads)
 
     def fwd(q, k, v):
         return attend_batched(q, k, v), (q, k, v)
 
     def bwd(res, g):
         q, k, v = res
-        return _backward_batched(q, k, v, g, causal, interpret)
+        return _backward_batched(q, k, v, g, causal, interpret, n_heads)
 
     attend_batched.defvjp(fwd, bwd)
 
     @custom_vmap
-    def attend_raw(q, k, v):               # (S, H, D)
+    def attend_raw(q, k, v):
         return _forward_batched(
-            q[None], k[None], v[None], causal, interpret
+            q[None], k[None], v[None], causal, interpret, n_heads
         )[0]
 
     @attend_raw.def_vmap
@@ -274,9 +395,9 @@ def _make(causal: bool, interpret: bool):
     # through the default size-1-grid batching rule — the tiny-program
     # regime the env blocks exist to avoid
     @custom_vmap
-    def bwd_raw(q, k, v, g):               # (S, H, D)
+    def bwd_raw(q, k, v, g):
         dq, dk, dv = _backward_batched(
-            q[None], k[None], v[None], g[None], causal, interpret
+            q[None], k[None], v[None], g[None], causal, interpret, n_heads
         )
         return dq[0], dk[0], dv[0]
 
@@ -288,7 +409,7 @@ def _make(causal: bool, interpret: bool):
                 for x, bat in zip((q, k, v, g), in_batched)
             )
         return (
-            _backward_batched(q, k, v, g, causal, interpret),
+            _backward_batched(q, k, v, g, causal, interpret, n_heads),
             (True, True, True),
         )
 
@@ -311,24 +432,53 @@ def _make(causal: bool, interpret: bool):
     return attend, attend_batched
 
 
+def _attend(q, k, v, causal: bool, interpret, n_heads):
+    """``_make``'s pair applied to (..., W, H, D) operands, or to packed
+    (..., W, H * D) ones with ``n_heads`` given: any leading batch dims
+    are flattened into the kernel's env-block grid."""
+    interpret = resolve_interpret(interpret)
+    core = 3 if n_heads is None else 2     # dims of one env's operand
+    window = q.shape[-core]
+    if window > MAX_FUSED_WINDOW:
+        raise ValueError(
+            f"fused_window_attention holds whole {window}x{window} score "
+            f"blocks in VMEM; windows beyond {MAX_FUSED_WINDOW} belong to "
+            "the ring/Ulysses sequence-parallel backends"
+        )
+    attend, attend_batched = _make(bool(causal), bool(interpret), n_heads)
+    if q.ndim == core:
+        return attend(q, k, v)
+    flat = lambda x: x.reshape(-1, *x.shape[-core:])  # noqa: E731
+    return attend_batched(flat(q), flat(k), flat(v)).reshape(q.shape)
+
+
 def fused_window_attention(q, k, v, *, causal: bool = False,
                            interpret: bool | None = None):
     """Exact attention for (..., W, H, D) q/k/v with the score blocks
     kept in VMEM.  Any leading batch dims (flattened into the kernel's
     env-block grid).  Differentiable (fused Pallas backward that
     recomputes the probabilities in VMEM — see module docstring).
-    Returns (..., W, H, D) in the input dtype."""
-    interpret = resolve_interpret(interpret)
-    *batch, s, h, d = q.shape
-    if s > MAX_FUSED_WINDOW:
-        raise ValueError(
-            f"fused_window_attention holds whole {s}x{s} score blocks "
-            f"in VMEM; windows beyond {MAX_FUSED_WINDOW} belong to the "
-            "ring/Ulysses sequence-parallel backends"
-        )
-    attend, attend_batched = _make(bool(causal), bool(interpret))
-    if not batch:
-        return attend(q, k, v)
-    flat = lambda x: x.reshape(-1, s, h, d)  # noqa: E731
-    out = attend_batched(flat(q), flat(k), flat(v))
-    return out.reshape(*batch, s, h, d)
+    Returns (..., W, H, D) in the input dtype.  Heads narrower than the
+    128 lanes go to the kernel packed (``packed_lanes``); a caller whose
+    projections write (..., W, H * D) hands that over as it is
+    (``fused_packed_attention``) and spares the chip the re-layout."""
+    h, d = q.shape[-2:]
+    if not packed_lanes(h, d):
+        return _attend(q, k, v, causal, interpret, None)
+    pack = lambda x: x.reshape(*x.shape[:-2], h * d)  # noqa: E731
+    out = _attend(pack(q), pack(k), pack(v), causal, interpret, h)
+    return out.reshape(q.shape)
+
+
+def fused_packed_attention(q, k, v, *, n_heads: int, causal: bool = False,
+                           interpret: bool | None = None):
+    """``fused_window_attention`` on q/k/v with the heads side by side
+    on the last axis, (..., W, H * D) in and out: what a projection to
+    ``d_model`` writes, and what the kernel reads where heads are
+    narrower than the lanes — no re-layout between the two."""
+    d = q.shape[-1] // n_heads
+    if packed_lanes(n_heads, d):
+        return _attend(q, k, v, causal, interpret, n_heads)
+    part = lambda x: x.reshape(*x.shape[:-1], n_heads, d)  # noqa: E731
+    out = _attend(part(q), part(k), part(v), causal, interpret, None)
+    return out.reshape(q.shape)

@@ -162,46 +162,147 @@ def _qkv(shape, seed=0):
     return tuple(jax.random.normal(k, shape, np.float32) for k in ks)
 
 
-@pytest.mark.parametrize("shape,causal", [
-    ((256, 4, 32), False),
-    ((64, 4, 32), True),
-    ((8, 128, 4, 32), False),   # leading env-batch dim (vmap rule)
+# heads narrower than the 128 lanes go to the kernel PACKED on the minor
+# axis (ops/fused_attention.py packed_lanes): H x D = 4 x 32 fills one lane
+# group, 8 x 32 two (a grid axis over them), 2 x 32 half of one (the block
+# spans the array); the decoder trunk's 20 x 256 stay apart, (B, H, S, D)
+_F32, _BF16 = "float32", "bfloat16"
+# what a bf16 kernel may differ by from the float32 reference on the same
+# (rounded) inputs: outputs of order 1, gradients of sum(out ** 2) of order 5
+_ATOL = {_F32: (2e-6, 2e-5), _BF16: (2e-2, 1e-1)}
+
+
+def _qkv_as(shape, dtype, seed):
+    import jax.numpy as jnp
+
+    return tuple(x.astype(jnp.dtype(dtype)) for x in _qkv(shape, seed))
+
+
+def _widened(xs):
+    import jax.numpy as jnp
+
+    return tuple(x.astype(jnp.float32) for x in xs)
+
+
+@pytest.mark.parametrize("shape,causal,dtype,transform", [
+    ((256, 4, 32), False, _F32, "direct"),
+    ((64, 4, 32), True, _F32, "direct"),
+    ((8, 128, 4, 32), False, _F32, "direct"),   # leading env-batch dim
+    ((3, 64, 4, 32), True, _F32, "vmap"),       # the trainers' per-env vmap
+    ((2, 64, 8, 32), False, _F32, "direct"),
+    ((2, 64, 8, 32), True, _F32, "vmap"),
+    ((2, 64, 2, 32), False, _F32, "vmap"),
+    ((2, 64, 2, 32), True, _F32, "direct"),
+    ((2, 64, 20, 256), True, _F32, "direct"),
+    ((2, 64, 20, 256), True, _F32, "vmap"),
+    ((4, 64, 4, 32), False, _BF16, "direct"),
+    ((2, 64, 8, 32), True, _BF16, "vmap"),
+    ((2, 64, 2, 32), False, _BF16, "direct"),
+    ((2, 64, 20, 256), True, _BF16, "direct"),
 ])
-def test_fused_attention_matches_reference(shape, causal):
+def test_fused_attention_matches_reference(shape, causal, dtype, transform):
+    import jax
+
     from gymfx_tpu.ops.fused_attention import fused_window_attention
     from gymfx_tpu.parallel.ring_attention import full_attention
 
-    q, k, v = _qkv(shape)
-    ours = fused_window_attention(q, k, v, causal=causal, interpret=True)
-    ref = full_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(ours), np.asarray(ref), atol=2e-6)
+    q, k, v = _qkv_as(shape, dtype, seed=0)
+
+    def fused(q, k, v):
+        return fused_window_attention(q, k, v, causal=causal, interpret=True)
+
+    ours = (jax.vmap(fused) if transform == "vmap" else fused)(q, k, v)
+    assert ours.dtype == q.dtype and ours.shape == q.shape
+    ref = full_attention(*_widened((q, k, v)), causal=causal)
+    np.testing.assert_allclose(
+        np.asarray(ours, np.float32), np.asarray(ref), atol=_ATOL[dtype][0])
 
 
-def test_fused_attention_gradients_match_reference():
+@pytest.mark.parametrize("shape,causal,dtype,transform", [
+    ((32, 2, 16), False, _F32, "grad"),
+    ((64, 4, 32), True, _F32, "grad"),
+    ((3, 64, 4, 32), False, _F32, "grad"),       # a batch: attend_batched
+    ((3, 64, 4, 32), True, _F32, "grad_vmap"),   # the training update
+    ((2, 64, 8, 32), False, _F32, "grad_vmap"),
+    ((2, 64, 8, 32), True, _F32, "grad"),
+    ((2, 64, 2, 32), True, _F32, "grad_vmap"),
+    ((64, 2, 32), False, _F32, "grad"),
+    ((2, 64, 20, 256), True, _F32, "grad_vmap"),
+    ((64, 20, 256), True, _F32, "grad"),
+    ((4, 64, 4, 32), False, _BF16, "grad_vmap"),
+    ((2, 64, 8, 32), True, _BF16, "grad"),
+    ((2, 64, 2, 32), False, _BF16, "grad_vmap"),
+    ((2, 64, 20, 256), True, _BF16, "grad"),
+])
+def test_fused_attention_gradients_match_reference(shape, causal, dtype,
+                                                   transform):
     """The custom VJP (pallas forward AND fused pallas backward, which
     recomputes the probabilities in VMEM) must produce the reference
     gradients — the kernel is on the TRAINING path of the transformer
-    policies."""
+    policies — in every transform order they reach it by."""
     import jax
     import jax.numpy as jnp
 
     from gymfx_tpu.ops.fused_attention import fused_window_attention
     from gymfx_tpu.parallel.ring_attention import full_attention
 
-    q, k, v = _qkv((32, 2, 16), seed=3)
+    q, k, v = _qkv_as(shape, dtype, seed=3)
+
+    def fused(q, k, v):
+        return fused_window_attention(q, k, v, causal=causal, interpret=True)
+
+    if transform == "grad_vmap":
+        fused = jax.vmap(fused)
 
     def loss_fused(q, k, v):
-        return jnp.sum(
-            fused_window_attention(q, k, v, interpret=True) ** 2
-        )
+        return jnp.sum(fused(q, k, v).astype(jnp.float32) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(full_attention(q, k, v) ** 2)
+        return jnp.sum(full_attention(q, k, v, causal=causal) ** 2)
 
     g_fused = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(*_widened((q, k, v)))
     for a, b in zip(g_fused, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+        assert a.dtype == q.dtype
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b), atol=_ATOL[dtype][1])
+
+
+@pytest.mark.parametrize("heads,head_dim", [(4, 32), (8, 32), (2, 32),
+                                            (3, 48), (20, 256)])
+def test_fused_packed_attention_is_the_same_attention(heads, head_dim):
+    """q/k/v as a projection to d_model writes them, (..., W, H * D),
+    give what their (..., W, H, D) views give — whether the kernel packs
+    such heads on the lanes or takes them apart."""
+    import jax
+    import jax.numpy as jnp
+
+    from gymfx_tpu.ops.fused_attention import (
+        fused_packed_attention,
+        fused_window_attention,
+        packed_lanes,
+    )
+
+    assert packed_lanes(heads, head_dim) == {
+        (4, 32): 128, (8, 32): 128, (2, 32): 64, (3, 48): 0, (20, 256): 0,
+    }[heads, head_dim]
+    q, k, v = _qkv((2, 32, heads * head_dim), seed=7)
+
+    def packed(q, k, v):
+        return fused_packed_attention(q, k, v, n_heads=heads, interpret=True)
+
+    def parted(q, k, v):
+        return fused_window_attention(
+            *(x.reshape(2, 32, heads, head_dim) for x in (q, k, v)),
+            interpret=True).reshape(q.shape)
+
+    def grads(attend):
+        return jax.grad(lambda *a: jnp.sum(attend(*a) ** 2),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    for a, b in zip((packed(q, k, v), *grads(packed)),
+                    (parted(q, k, v), *grads(parted))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.tpu

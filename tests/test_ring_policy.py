@@ -177,3 +177,105 @@ def test_portfolio_ppo_trains_with_transformer_ring(tmp_path):
     s = tr.init_state(0)
     s, m = tr.train_step(s)
     assert np.isfinite(float(m["loss"]))
+
+
+# ---------------------------------------------------------------------------
+# PR 32: the q/k/v/o projections work on heads packed side by side on the
+# last axis (policies.PackedHeadsDense); the PARAMETERS are what
+# nn.DenseGeneral made them, so a checkpoint written before loads
+# ---------------------------------------------------------------------------
+def _ring_tree(encoder, heads, n_layers=2, d_model=128, n_heads=4, window=64,
+               token_dim=7):
+    """path -> shape of a ring policy's parameters as they have been since
+    the encoder was written (all float32)."""
+    hd = d_model // n_heads
+    enc = {"Dense_0/kernel": (token_dim, d_model), "Dense_0/bias": (d_model,),
+           "pos_embed": (window, d_model)}
+    for i in range(2 * n_layers + 1):
+        enc[f"LayerNorm_{i}/scale"] = enc[f"LayerNorm_{i}/bias"] = (d_model,)
+    for layer in range(n_layers):
+        for i in range(3):
+            enc[f"DenseGeneral_{4 * layer + i}/kernel"] = (d_model, n_heads, hd)
+            enc[f"DenseGeneral_{4 * layer + i}/bias"] = (n_heads, hd)
+        enc[f"DenseGeneral_{4 * layer + 3}/kernel"] = (n_heads, hd, d_model)
+        enc[f"DenseGeneral_{4 * layer + 3}/bias"] = (d_model,)
+        enc[f"Dense_{2 * layer + 1}/kernel"] = (d_model, 4 * d_model)
+        enc[f"Dense_{2 * layer + 1}/bias"] = (4 * d_model,)
+        enc[f"Dense_{2 * layer + 2}/kernel"] = (4 * d_model, d_model)
+        enc[f"Dense_{2 * layer + 2}/bias"] = (d_model,)
+    tree = {f"params/{encoder}/{path}": shape for path, shape in enc.items()}
+    for name, width in heads.items():
+        tree[f"params/{name}/kernel"] = (d_model, width)
+        tree[f"params/{name}/bias"] = (width,)
+    return tree
+
+
+@pytest.mark.parametrize("kind", ["single_pair", "portfolio"])
+def test_ring_policy_parameter_tree_is_what_it_was(kind):
+    from gymfx_tpu.train.portfolio_ppo import PortfolioRingTransformerPolicy
+
+    if kind == "single_pair":
+        policy = RingTransformerPolicy(window=64)
+        want = _ring_tree("RingTransformerEncoder_0",
+                          {"Dense_0": 3, "Dense_1": 1})
+    else:
+        policy = PortfolioRingTransformerPolicy(n_pairs=3, window=64)
+        want = _ring_tree("RingTransformerEncoder_0",
+                          {"Dense_0": 9, "Dense_1": 1})
+    params = jax.eval_shape(
+        policy.init, jax.random.PRNGKey(0), jnp.zeros((64, 7)))
+    got = {
+        "/".join(key.key for key in path): (leaf.shape, str(leaf.dtype))
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]
+    }
+    assert got == {path: (shape, "float32") for path, shape in want.items()}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
+def test_packed_heads_dense_is_dense_general_on_packed_heads(dtype):
+    """Same parameters (names, shapes, the values drawn from a key) and the
+    same numbers as the ``nn.DenseGeneral`` pair it stands for, into heads
+    and out of them."""
+    import flax.linen as nn
+
+    from gymfx_tpu.train.policies import PackedHeadsDense, split_heads
+
+    class General(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            heads = nn.DenseGeneral((4, 32), dtype=dtype, name="into")(x)
+            return heads, nn.DenseGeneral(
+                128, axis=(-2, -1), dtype=dtype, name="out_of")(heads)
+
+    class Packed(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            heads = PackedHeadsDense(
+                (128, 4, 32), contract=1, dtype=dtype, name="into")(x)
+            return heads, PackedHeadsDense(
+                (4, 32, 128), contract=2, dtype=dtype, name="out_of")(heads)
+
+    x = jax.random.normal(jax.random.PRNGKey(2), (5, 16, 128))
+    params = General().init(jax.random.PRNGKey(4), x)
+    packed_params = Packed().init(jax.random.PRNGKey(4), x)
+    assert jax.tree.structure(params) == jax.tree.structure(packed_params)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(packed_params)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # a bias that is not zero, so that its packing is looked at too
+    params = jax.tree.map(
+        lambda p: p + 0.1 * jax.random.normal(jax.random.PRNGKey(6), p.shape),
+        params)
+    heads, out = General().apply(params, x)
+    packed_heads, packed_out = Packed().apply(params, x)
+    assert packed_heads.shape == (5, 16, 128) and packed_out.dtype == dtype
+    # (bf16: the same products rounded the same way; float32: the CPU's
+    # dot may order a 128-term sum differently for the two shapes)
+    tol = 1e-5 if dtype == jnp.float32 else 0.0
+    np.testing.assert_allclose(
+        np.asarray(split_heads(packed_heads, 4), np.float32),
+        np.asarray(heads, np.float32), atol=tol)
+    np.testing.assert_allclose(
+        np.asarray(packed_out, np.float32), np.asarray(out, np.float32),
+        atol=tol)

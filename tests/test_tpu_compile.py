@@ -14,7 +14,9 @@ one process may load libtpu, pytest-xdist hands a file to one worker,
 and describing the topology at import (or in a ``skipif``) would give
 the workers different collections.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -288,6 +290,69 @@ def test_flagship_step_gathers_only_the_tape_and_the_minibatch(one_chip, monkeyp
     taken = [path for path in paths if f"/{scopes.MINIBATCH_TAKE}/" in path]
     read = [path for path in paths if f"/{scopes.TAPE_READ}/" in path]
     assert (len(paths), len(taken), len(read)) == (6, 5, 1), paths
+
+
+# ---------------------------------------------------------------------------
+# the transformer cell's whole step (benchmarks/configs/
+# ppo_transformer_ring_w256_bf16.json, fewer envs): the layout of q, k, v, o
+# and their gradients between the projections and the kernel.  The chip
+# tiles a tensor's minor axis to 128 lanes, so a (B, 4, 256, 32) face is
+# three quarters padding in HBM; the projections write (B, 256, 128) and the
+# kernel reads that as it is (ops/fused_attention.py packed_lanes, PR 32).
+# ---------------------------------------------------------------------------
+# `  ROOT %name = <type, a tuple's has spaces> opcode(...`
+_HLO_RESULT_RE = re.compile(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?) [\w\-]+\(")
+_HLO_SHAPE_RE = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _dims(shape_text):
+    """Every array's dimensions in an HLO result type (a tuple has several)."""
+    return [tuple(int(d) for d in dims.split(",") if d)
+            for dims in _HLO_SHAPE_RE.findall(shape_text)]
+
+
+def test_transformer_step_hands_the_kernel_lane_dense_qkv(one_chip, monkeypatch):
+    from gymfx_tpu.ops import dispatch
+    from gymfx_tpu.train.ppo import PPOTrainer, ppo_config_from
+    from tests.helpers import make_env, uptrend_df
+
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    envs, horizon, window, d_model = 16, 8, 256, 128
+    env = make_env(
+        uptrend_df(500), num_envs=envs, ppo_horizon=horizon, ppo_epochs=1,
+        ppo_minibatches=4, policy="transformer_ring", policy_dtype="bfloat16",
+        window_size=window, ppo_minibatch_scheme="env_permute",
+        rollout_collect_dtype="bfloat16")
+    trainer = PPOTrainer(env, ppo_config_from(env.config))
+    hlo = _compile(trainer._train_step_impl,
+                   jax.eval_shape(trainer.init_state, 0), sharding=one_chip)
+    lines = hlo.splitlines()
+    result = {m.group(1): m.group(2)
+              for m in map(_HLO_RESULT_RE.match, lines) if m}
+
+    # 2 layers x (rollout forward, bootstrap forward, loss forward, backward)
+    calls = [line for line in lines
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 8
+    for line in calls:
+        operands = re.findall(
+            r"%([\w.\-]+)", line.split(" custom-call(")[1].split(")")[0])
+        faces = _dims(_HLO_RESULT_RE.match(line).group(2)) + [
+            dims for name in operands for dims in _dims(result[name])]
+        assert len(faces) in (4, 7)     # q k v -> o; q k v g -> dq dk dv
+        assert all(dims[1:] == (window, d_model) for dims in faces), faces
+
+    # no (B, H, W, D) or (B, W, H, D) tensor anywhere in the step
+    padded = [m.group(1) for m in map(_HLO_RESULT_RE.match, lines)
+              if m and re.search(rf"\[\d+,(4,{window}|{window},4),32\]", m.group(2))]
+    assert not padded, padded[:8]
+    # and no window-sized tensor re-laid out between a projection and a call
+    moved = [_HLO_RESULT_RE.match(line).group(1) for line in lines
+             if re.search(r" (copy|transpose)\(", line)
+             and f"/{scopes.ATTENTION}/" in line.split('op_name="')[-1]
+             and any(math.prod(dims) >= envs * window * d_model
+                     for dims in _dims(_HLO_RESULT_RE.match(line).group(2)))]
+    assert not moved, moved[:8]
 
 
 # ---------------------------------------------------------------------------
