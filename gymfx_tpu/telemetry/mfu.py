@@ -63,6 +63,18 @@ def attention_flops_per_sample(window: int, d_model: int,
     return 4.0 * float(n_layers) * float(window) * keys * float(d_model)
 
 
+def delta_rule_flops_per_sample(window: int, heads: int, head_dim: int,
+                                n_layers: int, chunk: int = 64) -> float:
+    """A linear-attention layer's gated delta rule over a window, as the
+    chunked triangular solve any implementation has to do (key and value
+    width ``head_dim``): per position and head, the two rows of intra-chunk
+    scores (``chunk`` columns in all over the key width), the forward
+    substitution and the scores' product with the pseudo-values (the same over
+    the value width), and three key x value products with the carried state."""
+    per_position = chunk * 2.0 * head_dim + 3.0 * head_dim * head_dim
+    return 2.0 * float(n_layers) * float(window) * float(heads) * per_position
+
+
 def analytic_train_step_flops(
     params: Any,
     *,
@@ -75,11 +87,18 @@ def analytic_train_step_flops(
     n_layers: int = 0,
     causal: bool = False,
     expert_share: Optional[float] = None,
+    linear_layers: int = 0,
+    linear_heads: int = 0,
+    linear_head_dim: int = 0,
 ) -> float:
-    """Closed-form FLOPs of ONE fused rollout+update train step."""
+    """Closed-form FLOPs of ONE fused rollout+update train step.
+    ``n_layers`` counts the layers with softmax attention, ``linear_layers``
+    those with linear attention (``delta_rule_flops_per_sample``)."""
     fwd = param_flops_per_sample(params, tokens=tokens, expert_share=expert_share)
     if n_layers and window and d_model:
         fwd += attention_flops_per_sample(window, d_model, n_layers, causal)
+    if linear_layers and window:
+        fwd += delta_rule_flops_per_sample(window, linear_heads, linear_head_dim, linear_layers)
     samples = float(num_envs) * float(horizon)
     rollout = samples * fwd
     update = 3.0 * samples * fwd * float(max(1, update_epochs))

@@ -37,6 +37,10 @@ The scope paths, under ``jit(...)``:
                                 ``policy_act`` and ``policy_forward`` (the
                                 decoder trunk: latent attention; the DENSE
                                 layer's gated feed-forward)
+  .../linear_attention          the Kimi-Delta-Attention half of a hybrid
+                                trunk's block, flat under the same two: norm,
+                                projections, short convolution, gates, the
+                                chunked scan, gated norm, output product
   .../moe_router                an expert layer, flat under the same two:
                                 its norm, the scores, top-k and weights
   .../moe_dispatch              sort by expert, rows to the buffer and back,
@@ -65,6 +69,7 @@ OPTIMIZER = "optimizer"
 GUARD = "guard"
 ATTENTION = "attention"
 FFN = "ffn"
+LINEAR_ATTENTION = "linear_attention"
 MOE_ROUTER = "moe_router"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
@@ -77,7 +82,7 @@ PHASE_SCOPES = (ROLLOUT, UPDATE)
 SCOPE_NAMES = PHASE_SCOPES + (
     POLICY_ACT, ENV_STEP, TAPE_READ, DYNAMICS, OBS, AUTO_RESET, GAE,
     MINIBATCH_TAKE, LOSS, POLICY_FORWARD, OPTIMIZER, GUARD, ATTENTION, FFN,
-) + MOE_SCOPES
+) + MOE_SCOPES + (LINEAR_ATTENTION,)
 
 
 def join(*names: str) -> str:
@@ -89,6 +94,7 @@ LAYERS = (
     join(ROLLOUT, POLICY_ACT),
     join(ROLLOUT, POLICY_ACT, ATTENTION),
     join(ROLLOUT, POLICY_ACT, FFN),
+    join(ROLLOUT, POLICY_ACT, LINEAR_ATTENTION),
     *(join(ROLLOUT, POLICY_ACT, part) for part in MOE_SCOPES),
     join(ROLLOUT, ENV_STEP, TAPE_READ),
     join(ROLLOUT, ENV_STEP, DYNAMICS),
@@ -100,6 +106,7 @@ LAYERS = (
     join(UPDATE, LOSS, POLICY_FORWARD),
     join(UPDATE, LOSS, POLICY_FORWARD, ATTENTION),
     join(UPDATE, LOSS, POLICY_FORWARD, FFN),
+    join(UPDATE, LOSS, POLICY_FORWARD, LINEAR_ATTENTION),
     *(join(UPDATE, LOSS, POLICY_FORWARD, part) for part in MOE_SCOPES),
     join(UPDATE, OPTIMIZER),
     join(UPDATE, GUARD),

@@ -7,6 +7,15 @@ key shared by all heads, causal) and a gated feed-forward: dense in the
 leading ``first_k_dense_replace`` layers, an expert layer after them.  The
 last position's state feeds the actor and the critic head.
 
+A HYBRID trunk is the same trunk with its attention taken by the layer's
+index: with ``layer_group_size`` n > 0 every n-th layer (``(l + 1) % n == 0``)
+keeps latent attention and the others are Kimi Delta Attention
+(``KimiDeltaAttention``: a linear-attention layer whose per-head state is a
+key x value matrix under the gated delta rule with a decay of its own for every
+key channel; ``ops/kda_chunk_scan.py``).  ``q_lora_rank`` 0 is latent attention
+without the low-rank query path, ``attn_output_gate`` its head-wise sigmoid
+gate, ``n_group`` / ``topk_group`` the router's choice by groups of experts.
+
 The expert layer is TOLD which experts it holds (``experts_held`` of
 ``n_routed_experts`` from ``expert_offset``): the router scores all
 experts and keeps its top-k, the layer computes the terms of the sum
@@ -35,11 +44,12 @@ configuration are one ``nn.scan`` over stacked parameters, each block
 rematerialised in the backward pass (``remat``).
 
 The plain reference of the same equations is
-``gymfx_tpu/reference/mla_moe_decoder.py``.
+``gymfx_tpu/reference/mla_moe_decoder.py``; the hybrid trunk's is
+``gymfx_tpu/reference/hybrid_decoder.py``.
 """
 from __future__ import annotations
 
-from typing import Any, NamedTuple
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -68,6 +78,14 @@ class Dims(NamedTuple):
     rope_theta: float = 1e6
     experts_held: int = 64
     expert_offset: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    attn_output_gate: bool = False
+    layer_group_size: int = 0
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    kda_lower_bound: float = -5.0
+    kda_chunk: int = 64
 
 
 _matrix = nn.initializers.variance_scaling(
@@ -99,11 +117,25 @@ def swiglu(x, w_gate, w_up, w_down):
     return (nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def choose(choice, dims: Dims):
+    """The (T, k) experts with the largest ``choice`` scores (T, n_routed).
+    With ``n_group`` > 1 the experts are ``n_group`` runs of neighbours: a
+    group's score is the sum of its two largest choice scores, the
+    ``topk_group`` best groups stay, and the top-k is taken inside them."""
+    if dims.n_group > 1 and dims.topk_group < dims.n_group:
+        grouped = choice.reshape(choice.shape[0], dims.n_group, -1)
+        best_two, _ = jax.lax.top_k(grouped, 2)
+        _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), dims.topk_group)
+        stays = jnp.any(kept[:, :, None] == jnp.arange(dims.n_group)[None, None, :], axis=1)
+        choice = jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(choice.shape)
+    return jax.lax.top_k(choice, dims.num_experts_per_tok)[1]
+
+
 def route(scores, bias, dims: Dims):
-    """Top-k of ``scores + bias`` over ALL experts; the weights are the
+    """Top-k of ``scores + bias`` (``choose``); the weights are the
     chosen SCORES (the bias steers the choice only), renormalised and
     scaled.  ``scores`` (T, n_routed) float32 -> (idx, weights) (T, k)."""
-    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), dims.num_experts_per_tok)
+    idx = choose(scores + jax.lax.stop_gradient(bias), dims)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if dims.norm_topk_prob:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -124,7 +156,7 @@ def balanced_choice_bias(scores, bias, dims: Dims):
     rounds = 400
 
     def step(i, b):
-        _, idx = jax.lax.top_k(scores + b, dims.num_experts_per_tok)
+        idx = choose(scores + b, dims)
         load = jnp.sum(idx[..., None] == jnp.arange(n, dtype=idx.dtype), axis=(0, 1))
         rate = 0.05 * 1e-3 ** (i / (rounds - 1))
         return b - rate * jnp.clip(load / even - 1.0, -1.0, 1.0)
@@ -149,12 +181,19 @@ class Plan(NamedTuple):
 def buffer_rows(tokens: int, dims: Dims, align: int, worst: bool = True) -> int:
     """Rows of the sorted buffer, in whole tiles of ``align`` rows.  ``worst``:
     every choice of every token may fall on an expert held here (dropless).
-    Else TWICE what the experts held here expect of ``tokens`` tokens.  Either
+    Else TWICE what the experts held here expect of ``tokens`` tokens, and no
+    less than an eighth of the worst case: a share of under one expert in 16
+    expects so few rows that ONE expert running hot fills twice of them (8 of
+    512 held, PR 33: the held experts alone get a gradient, a few steps make
+    one of them hot, and once it drew 3.5 % of a layer's choices every pass of
+    that layer went through the worst case's buffer: the step's time moved
+    1 % with the seed, and still 0.4 % with a floor of a sixteenth).  Either
     way one tile more per expert, for the padding of its last one."""
     k, held = dims.num_experts_per_tok, dims.experts_held
     rows = tokens * min(k, held)
     if not worst:
-        rows = min(rows, 2 * -(-tokens * k * held // dims.n_routed_experts))
+        expected = -(-tokens * k * held // dims.n_routed_experts)
+        rows = min(rows, max(2 * expected, rows // 8))
     return -(-rows // align) * align + held * align
 
 
@@ -359,8 +398,11 @@ class LatentAttention(_Layer):
         heads, nope, rope, vdim = (d.num_attention_heads, d.qk_nope_head_dim,
                                    d.qk_rope_head_dim, d.v_head_dim)
         y = self.norm("attn_norm", x)
-        c_q = self.norm("q_a_norm", y @ self.weight("q_a", (d.hidden_size, d.q_lora_rank)))
-        q = c_q @ self.weight("q_b", (d.q_lora_rank, heads * (nope + rope)))
+        if d.q_lora_rank:
+            c_q = self.norm("q_a_norm", y @ self.weight("q_a", (d.hidden_size, d.q_lora_rank)))
+            q = c_q @ self.weight("q_b", (d.q_lora_rank, heads * (nope + rope)))
+        else:
+            q = y @ self.weight("q", (d.hidden_size, heads * (nope + rope)))
         q = q.reshape(*q.shape[:-1], heads, nope + rope)
         kv_a = y @ self.weight("kv_a", (d.hidden_size, d.kv_lora_rank + rope))
         c_kv = self.norm("kv_a_norm", kv_a[..., :d.kv_lora_rank])
@@ -371,9 +413,80 @@ class LatentAttention(_Layer):
             [q[..., :nope], rope_interleaved(q[..., nope:], d.rope_theta)], axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rope, (*kv.shape[:-1], rope))], axis=-1)
-        a = dense_window_attention(q, k, kv[..., nope:], causal=True)
+        v = kv[..., nope:]
+        if vdim < nope + rope:
+            # the attention kernel takes ONE head width: values narrower than the
+            # keys go in padded with zero columns, which come out as zeros
+            v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, nope + rope - vdim)])
+        a = dense_window_attention(q, k, v, causal=True)[..., :vdim]
+        if d.attn_output_gate:
+            gate = jax.nn.sigmoid(y @ self.weight("head_gate", (d.hidden_size, heads)))
+            a = a * gate[..., None]
         return a.reshape(*a.shape[:-2], heads * vdim) @ self.weight(
             "o", (heads * vdim, d.hidden_size))
+
+
+def _decay_bias(lower_bound: float):
+    """Initialiser of a KDA gate's ``dt_bias``: with ``A_log`` 0 and ``W_f``'s
+    part at zero a channel's log-decay is ``-1 / tau`` a step, ``tau``
+    log-uniform between 1 and 1,000 positions (``lower_bound * sigmoid(bias) =
+    -1 / tau``; a bound nearer 0 than -1 caps the share of it at 0.99)."""
+    def init(key, shape, dtype=jnp.float32):
+        tau = jnp.exp(jax.random.uniform(key, shape, dtype, 0.0, jnp.log(1000.0)))
+        share = jnp.minimum(1.0 / (-lower_bound * tau), 0.99)
+        return jnp.log(share) - jnp.log1p(-share)
+    return init
+
+
+class KimiDeltaAttention(_Layer):
+    """Pre-norm Kimi Delta Attention: (B, W, hidden) -> (the same, the mean
+    log-decay of the layer, float32).  q, k, v through a short causal
+    convolution and SiLU; q and k of unit length a head; a log-decay for every
+    key channel, bounded below; the gated delta rule over the window
+    (``ops/kda_chunk_scan.py``); each head's output RMS-normalised, gated, and
+    projected back."""
+
+    @nn.compact
+    def __call__(self, x):
+        from gymfx_tpu.ops.kda_chunk_scan import (
+            MAX_LOG_DECAY_STEP,
+            causal_conv,
+            kda_chunk_scan,
+        )
+
+        d = self.dims
+        heads, width = d.num_attention_heads, d.kda_head_dim
+        if not 0.0 <= -d.kda_lower_bound <= MAX_LOG_DECAY_STEP:
+            raise ValueError(f"kda_lower_bound {d.kda_lower_bound} outside "
+                             f"[-{MAX_LOG_DECAY_STEP:.2f}, 0]")
+        y = self.norm("attn_norm", x)
+        inner = heads * width
+
+        def mixed(name):
+            taps = self.weight(f"{name}_conv", (d.kda_conv_size, inner))
+            out = nn.silu(causal_conv(y @ self.weight(name, (d.hidden_size, inner)), taps))
+            return out.reshape(*out.shape[:-1], heads, width)
+
+        def unit(t):
+            t32 = t.astype(jnp.float32)
+            return t32 * jax.lax.rsqrt(jnp.sum(t32 * t32, axis=-1, keepdims=True) + 1e-6)
+
+        q = (unit(mixed("q")) * width ** -0.5).astype(self.dtype)
+        k = unit(mixed("k")).astype(self.dtype)
+        v = mixed("v")
+        rate = jnp.exp(self.param("A_log", nn.initializers.zeros, (heads,), jnp.float32))
+        dt_bias = self.param(
+            "dt_bias", _decay_bias(d.kda_lower_bound), (inner,), jnp.float32)
+        f = (y @ self.weight("f", (d.hidden_size, inner))).astype(jnp.float32) + dt_bias
+        g = d.kda_lower_bound * jax.nn.sigmoid(
+            f.reshape(*f.shape[:-1], heads, width) * rate[:, None])
+        beta = jax.nn.sigmoid(
+            (y @ self.weight("b", (d.hidden_size, heads))).astype(jnp.float32))
+        o = kda_chunk_scan(q, k, v, g, beta, chunk=d.kda_chunk)
+        weight = self.param("o_norm", nn.initializers.ones, (width,), jnp.float32)
+        o = rms_norm(o, weight, d.rms_norm_eps).reshape(*o.shape[:-2], inner)
+        o = o * jax.nn.sigmoid(y @ self.weight("g", (d.hidden_size, inner)))
+        return o @ self.weight("o", (inner, d.hidden_size)), jnp.mean(g)
 
 
 class DenseFfn(_Layer):
@@ -427,25 +540,53 @@ class ExpertLayer(_Layer):
 
 
 class _Block(nn.Module):
-    """One pre-norm residual block: latent attention, then a gated
-    feed-forward that is dense (``sparse=False``) or the expert layer.
-    Called on (B, W, hidden); returns it with the expert layer's counters
-    and choices (``None`` for a dense block), in the shape ``nn.scan`` wants."""
+    """One pre-norm residual block: latent attention (or, ``linear``, Kimi
+    Delta Attention), then a gated feed-forward that is dense
+    (``sparse=False``) or the expert layer.  Called on (B, W, hidden); returns
+    it with (the expert layer's counters, its choices, the linear layer's mean
+    log-decay), ``None`` what the block has not, in the shape ``nn.scan`` wants."""
 
     dims: Dims
     sparse: bool
     dtype: Any
+    linear: bool = False
 
     @nn.compact
     def __call__(self, x, _=None):
-        with jax.named_scope(scopes.ATTENTION):
-            x = x + LatentAttention(self.dims, self.dtype, name="attn")(x)
+        decay = None
+        if self.linear:
+            with jax.named_scope(scopes.LINEAR_ATTENTION):
+                mixed, decay = KimiDeltaAttention(self.dims, self.dtype, name="kda")(x)
+                x = x + mixed
+        else:
+            with jax.named_scope(scopes.ATTENTION):
+                x = x + LatentAttention(self.dims, self.dtype, name="attn")(x)
         if not self.sparse:
             with jax.named_scope(scopes.FFN):
-                return x + DenseFfn(self.dims, self.dtype, name="ffn")(x), None
+                x = x + DenseFfn(self.dims, self.dtype, name="ffn")(x)
+            return x, (None, None, decay)
         out, counters, idx = ExpertLayer(self.dims, self.dtype, name="experts")(
             x.reshape(-1, self.dims.hidden_size))
-        return x + out.reshape(x.shape), (counters, idx)
+        return x + out.reshape(x.shape), (counters, idx, decay)
+
+
+def layer_runs(n_layers: int, first_k_dense_replace: int, layer_group_size: int):
+    """The trunk's layers as (name, first layer, layers, sparse, linear): every
+    leading dense layer alone (``dense_<l>``), the expert layers in runs of one
+    attention kind, each run one ``nn.scan`` (``moe`` where there is one run,
+    ``moe_<first layer>`` else)."""
+    dense = min(first_k_dense_replace, n_layers)
+
+    def linear(layer):
+        return bool(layer_group_size) and (layer + 1) % layer_group_size != 0
+
+    runs = [(f"dense_{l}", l, 1, False, linear(l)) for l in range(dense)]
+    starts = [l for l in range(dense, n_layers)
+              if l == dense or linear(l) != linear(l - 1)]
+    for start, end in zip(starts, starts[1:] + [n_layers]):
+        name = "moe" if len(starts) == 1 else f"moe_{start}"
+        runs.append((name, start, end - start, True, linear(start)))
+    return runs
 
 
 class MlaMoeDecoderPolicy(nn.Module):
@@ -457,7 +598,7 @@ class MlaMoeDecoderPolicy(nn.Module):
     first_k_dense_replace: int = 1
     remat: bool = True
     hidden_size: int = Dims._field_defaults["hidden_size"]
-    q_lora_rank: int = Dims._field_defaults["q_lora_rank"]
+    q_lora_rank: Optional[int] = Dims._field_defaults["q_lora_rank"]   # None or 0: no such path
     kv_lora_rank: int = Dims._field_defaults["kv_lora_rank"]
     num_attention_heads: int = Dims._field_defaults["num_attention_heads"]
     qk_nope_head_dim: int = Dims._field_defaults["qk_nope_head_dim"]
@@ -474,11 +615,29 @@ class MlaMoeDecoderPolicy(nn.Module):
     rope_theta: float = Dims._field_defaults["rope_theta"]
     experts_held: int = 0          # 0: all of n_routed_experts
     expert_offset: int = 0
+    n_group: int = Dims._field_defaults["n_group"]
+    topk_group: int = Dims._field_defaults["topk_group"]
+    attn_output_gate: bool = Dims._field_defaults["attn_output_gate"]
+    layer_group_size: int = Dims._field_defaults["layer_group_size"]
+    kda_head_dim: int = Dims._field_defaults["kda_head_dim"]
+    kda_conv_size: int = Dims._field_defaults["kda_conv_size"]
+    kda_lower_bound: float = Dims._field_defaults["kda_lower_bound"]
+    kda_chunk: int = Dims._field_defaults["kda_chunk"]
 
     # the trainers call this module on the whole env batch (no vmap), and
-    # ask it for the expert layers' counters inside the loss
+    # ask it for its layers' counters inside the loss
     takes_batch = True
-    COUNTERS = ("moe_held_share", "moe_load_max_over_mean")
+
+    @property
+    def COUNTERS(self):
+        """Counters the loss carries out: the expert layers' two and, of a
+        trunk with linear-attention layers, their mean log-decay."""
+        moe = ("moe_held_share", "moe_load_max_over_mean")
+        linear = any(run[4] for run in self._runs())
+        return moe + (("kda_log_decay_mean",) if linear else ())
+
+    def _runs(self):
+        return layer_runs(self.n_layers, self.first_k_dense_replace, self.layer_group_size)
 
     def dims(self) -> Dims:
         held = self.experts_held or self.n_routed_experts
@@ -487,7 +646,8 @@ class MlaMoeDecoderPolicy(nn.Module):
                 f"experts {self.expert_offset}..{self.expert_offset + held} held of "
                 f"{self.n_routed_experts}")
         values = {name: getattr(self, name) for name in Dims._fields}
-        return Dims(**{**values, "experts_held": held})
+        return Dims(**{**values, "experts_held": held,
+                       "q_lora_rank": self.q_lora_rank or 0})
 
     @nn.compact
     def __call__(self, tokens, counters: bool = False, routing: bool = False):
@@ -499,16 +659,26 @@ class MlaMoeDecoderPolicy(nn.Module):
         w_in = self.param("in_proj", _matrix, (x.shape[-1], dims.hidden_size), jnp.float32)
         x = x @ w_in.astype(self.dtype)
         block = nn.remat(_Block) if self.remat else _Block
-        dense = min(self.first_k_dense_replace, self.n_layers)
-        for i in range(dense):
-            x, _ = block(dims, False, self.dtype, name=f"dense_{i}")(x)
-        sparse = self.n_layers - dense
-        stats, chosen = jnp.zeros((1, 2), jnp.float32), None
-        if sparse:
-            x, (stats, chosen) = nn.scan(
-                block, variable_axes={"params": 0}, split_rngs={"params": True},
-                length=sparse,
-            )(dims, True, self.dtype, name="moe")(x, None)
+        stats, chosen, decays = [], [], []
+        for name, _first, layers, sparse, linear in self._runs():
+            if sparse:
+                x, (counted, idx, decay) = nn.scan(
+                    block, variable_axes={"params": 0}, split_rngs={"params": True},
+                    length=layers,
+                )(dims, True, self.dtype, linear, name=name)(x, None)
+                stats.append(counted)
+                chosen.append(idx)
+            else:
+                x, (_, _, decay) = block(dims, False, self.dtype, linear, name=name)(x)
+                decay = decay if decay is None else decay[None]
+            if linear:
+                decays.append(decay)
+        if not stats:
+            stats, chosen = jnp.zeros((1, 2), jnp.float32), None
+        elif len(stats) == 1:       # one run: its own arrays, no copy
+            stats, chosen = stats[0], chosen[0]
+        else:
+            stats, chosen = jnp.concatenate(stats), jnp.concatenate(chosen)
         weight = self.param("final_norm", nn.initializers.ones, (dims.hidden_size,),
                             jnp.float32)
         last = rms_norm(x[:, -1, :], weight, dims.rms_norm_eps)
@@ -520,10 +690,13 @@ class MlaMoeDecoderPolicy(nn.Module):
             return logits, value
         choices = x.shape[0] * x.shape[1] * dims.num_experts_per_tok
         held, largest = jnp.mean(stats[:, 0]), jnp.mean(stats[:, 1])
-        return logits, value, {
+        counted = {
             "moe_held_share": held / choices,
             "moe_load_max_over_mean": largest * dims.experts_held / jnp.maximum(held, 1.0),
         }
+        if decays:
+            counted["kda_log_decay_mean"] = jnp.mean(jnp.concatenate(decays))
+        return logits, value, counted
 
     def initial_carry(self, batch_shape=()):
         return ()

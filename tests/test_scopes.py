@@ -41,11 +41,22 @@ POLICIES = {
             qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16, intermediate_size=64,
             moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
             n_layers=2, experts_held=4)),
+    # the same trunk with Kimi Delta Attention in the layers its index gives
+    "hybrid_decoder": dict(
+        policy="mla_moe_decoder",
+        policy_kwargs=dict(
+            hidden_size=32, q_lora_rank=None, kv_lora_rank=8, num_attention_heads=2,
+            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, intermediate_size=64,
+            moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
+            n_group=2, topk_group=1, n_layers=3, experts_held=4, layer_group_size=3,
+            attn_output_gate=True, kda_head_dim=16, kda_chunk=16)),
 }
 # the parts of a policy's blocks, by policy: the layers that apply to it
 BLOCKS = {"mlp": (), "transformer_ring": (scopes.ATTENTION, scopes.FFN),
-          "mla_moe_decoder": (scopes.ATTENTION, scopes.FFN) + scopes.MOE_SCOPES}
-ALL_BLOCKS = BLOCKS["mla_moe_decoder"]
+          "mla_moe_decoder": (scopes.ATTENTION, scopes.FFN) + scopes.MOE_SCOPES,
+          "hybrid_decoder": (scopes.ATTENTION, scopes.FFN, scopes.LINEAR_ATTENTION)
+          + scopes.MOE_SCOPES}
+ALL_BLOCKS = BLOCKS["hybrid_decoder"]
 
 
 def layers_of(policy):
@@ -102,14 +113,15 @@ def test_the_loss_has_both_directions_and_the_rollout_none(handed_out, policy):
     for scope in scope_map.values():
         ways.setdefault(scope.path, set()).add(scope.direction)
     forward = scopes.join(scopes.UPDATE, scopes.LOSS, scopes.POLICY_FORWARD)
-    if policy == "mla_moe_decoder":
+    if POLICIES[policy]["policy"] == "mla_moe_decoder":
         # XLA:CPU fuses backward ops of the decoder's dispatch with constants
         # it shares with the forward into ONE fusion that has no op_name of
-        # its own, so it inherits the path and no direction; no other
-        # instruction may lose its direction, and no other policy has one
+        # its own, so it inherits the path and no direction (one for each run
+        # of expert layers: the hybrid trunk has two); no other instruction
+        # may lose its direction, and no other policy has one
         lost = [name for name, scope in scope_map.items()
                 if scope.path == forward and scope.direction is None]
-        assert len(lost) <= 1
+        assert len(lost) <= (2 if policy == "hybrid_decoder" else 1)
         defined = [line for line in step.as_text().splitlines()
                    if line.split("=")[0].split()[-1:] in [[f"%{name}"] for name in lost]]
         assert len(defined) == len(lost)

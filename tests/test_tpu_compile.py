@@ -356,6 +356,55 @@ def test_transformer_step_hands_the_kernel_lane_dense_qkv(one_chip, monkeypatch)
 
 
 # ---------------------------------------------------------------------------
+# the hybrid decoder cell's whole step (benchmarks/configs/
+# ppo_ling3flash_ep64_bf16.json) at a cut size: the published head widths (KDA
+# 128, MLA 128 | 64 keys against 128 values), window 1,024 and chunk 64, the
+# six-layer pattern whole, fewer heads and narrower matrices.  The chunked scan
+# is plain XLA (no custom call of its own); the MLA layer's values go to the
+# attention kernel padded to the keys' width; the expert layers' products are
+# the grouped kernels, one set a buffer length and pass.
+# ---------------------------------------------------------------------------
+def test_hybrid_decoder_step_compiles_with_its_kernels_by_name(one_chip, monkeypatch):
+    from collections import Counter
+
+    from gymfx_tpu.ops import dispatch
+    from gymfx_tpu.train.ppo import PPOTrainer, ppo_config_from
+    from tests.helpers import make_env, uptrend_df
+
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    env = make_env(
+        uptrend_df(1500), num_envs=4, ppo_horizon=2, ppo_epochs=1, ppo_minibatches=2,
+        policy="mla_moe_decoder", policy_dtype="bfloat16", window_size=1024,
+        ppo_minibatch_scheme="sample_permute", rollout_collect_dtype="bfloat16",
+        policy_kwargs=dict(
+            hidden_size=512, q_lora_rank=None, kv_lora_rank=128, num_attention_heads=4,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, intermediate_size=1024,
+            moe_intermediate_size=256, n_routed_experts=64, num_experts_per_tok=8, n_group=8,
+            topk_group=4, routed_scaling_factor=2.5, rms_norm_eps=1e-6, rope_theta=6e6,
+            first_k_dense_replace=1, n_layers=6, experts_held=8, layer_group_size=6,
+            attn_output_gate=True, kda_head_dim=128, kda_chunk=64))
+    trainer = PPOTrainer(env, ppo_config_from(env.config))
+    hlo = _compile(trainer._train_step_impl,
+                   jax.eval_shape(trainer.init_state, 0), sharding=one_chip)
+    names = [line.split("=")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+             for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    counts = Counter(names)
+    assert set(counts) <= set(scopes.KERNEL_NAMES), counts
+    # ONE latent-attention layer: rollout, bootstrap, the loss's forward and its
+    # recomputation; one backward
+    assert counts[scopes.KERNEL_ATTENTION_FWD] == 4 and counts[scopes.KERNEL_ATTENTION_BWD] == 1
+    # two runs of expert layers (four KDA blocks under one scan, the MLA block)
+    assert counts[scopes.KERNEL_GROUPED_MATMUL] == 40
+    assert counts[scopes.KERNEL_GROUPED_MATMUL_DW] == 8
+    # the attention kernel takes keys and values of ONE width: 192
+    attention = [line for line in hlo.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line and "fused_attention" in line]
+    assert all("1024,192]" in line for line in attention), attention[:1]
+    # the scan's chunk products are there, batched over a window's 16 chunks x 4 heads
+    assert re.search(r"\[16,4,64,64\]", hlo)
+
+
+# ---------------------------------------------------------------------------
 # LOB stream matcher: 1024 books x 24 levels x 4 slots (venue default)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("n_msgs", [16, 256], ids=["seed16", "bench256"])
