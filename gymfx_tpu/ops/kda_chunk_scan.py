@@ -34,12 +34,36 @@ The inverse of the unit lower-triangular ``I + A`` is built by halves
 substitution loop and no power of ``A``.
 
 Products take their operands in the dtype of ``q`` (bfloat16 in one pass,
-float32 at ``HIGHEST``) and accumulate in float32; running sums, decays and the
-carried state are float32.  The backward pass is JAX's own of this form, a
-window at a time: the carried states of the window's chunks are what it keeps
-(a 128 x 128 float32 state a head and chunk), everything inside a chunk it has
-from the chunk's parts.  The position-by-position recurrence is the
-reference's (``gymfx_tpu/reference/hybrid_decoder.py``), not the program's.
+float32 at ``HIGHEST``) and accumulate in float32; running sums, decays, the
+carried state and its cotangent are float32.  The position-by-position
+recurrence is the reference's (``gymfx_tpu/reference/hybrid_decoder.py``), not
+the program's.
+
+The backward pass is the scan's own (``jax.custom_vjp``, PR 34), of the same
+chunked form, a window at a time.  Between the passes it keeps the five inputs
+and nothing else.  For a window it forms the chunks' parts again, walks the
+chunks forward for their start states, ``w = vb - Kd S_0^T`` and ``u = T w``
+(``chunk_state``: three of the forward's five products), and then walks them
+ONCE in reverse carrying the state's cotangent (``chunk_apply_transposed``: ten
+products a chunk, none on a stacked residual):
+
+    du   = Aqk^T dO + Ke dS^T            dw = T^T du
+    dS_0 = dS * exp(G_C) + dO^T Qd - dw^T Kd
+    dQd = dO S_0,  dKd = -dw S_0,  dKe = u dS,  d exp(G_C) = sum_V dS * S_0
+    dT  = du w^T,  dAqk = dO u^T,  dvb = dw
+
+The parts' cotangents go back to q, k, v, g, beta for all chunks at once:
+``jax.vjp`` of ``chunk_products``, and ``dA = -T^T dT T^T`` for the inverse in
+the place of a walk back through its rounds.  What JAX derives from the
+``lax.scan`` instead stacks every chunk's operands (0.5 GB a window) and walks
+the forward a second time under the ``jax.checkpoint`` that bounded it: 15.5 ms
+a call of [4, 1024, 32, 128] bfloat16 against 12.6 (``tools/kda_scan_ab.py``,
+"TPU v5 lite", PR 34).  Keeping the chunks' start states from the forward pass
+saved 0.2 ms a call more and cost the train step 0.26 GB at its peak, which is
+in the expert layer's backward pass, where a block's residuals wait: dropped.
+The log-decay's cotangent is written over the log-decay's own rows
+(``_scan_bwd``): as one more stacked output of the loop over windows XLA
+allocated it before the block's backward pass began, 0.13 GB at the same peak.
 
 This is plain ``jax.numpy``, and no Mosaic kernel, by the chip's A/B (PR 33,
 ``tools/kda_scan_ab.py``, "TPU v5 lite", [4, 1024, 32, 128] bfloat16): a kernel
@@ -51,8 +75,6 @@ batches a window's 512 chunk-heads into every product, the kernel ran them one
 64 x 128 tile at a time.  The kernels are gone; PERF.md section 6 has the numbers.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -101,10 +123,10 @@ def unit_lower_inverse(a, operand=jnp.float32):
     return inverse
 
 
-def chunk_parts(q, k, kb, g, operand, sub: int = SUB):
-    """What a chunk's step needs beside its start state, from float32 tiles
-    (..., C, K) of q, k, ``kb = beta k`` and the log-decay: (q * exp(G),
-    kb * exp(G), k * exp(G_C - G), exp(G_C) (..., 1, K), (I + A)^-1, Aqk)."""
+def chunk_products(q, k, kb, g, operand, sub: int = SUB):
+    """What a chunk's step needs beside its start state, short of the inverse,
+    from float32 tiles (..., C, K) of q, k, ``kb = beta k`` and the log-decay:
+    (q * exp(G), kb * exp(G), k * exp(G_C - G), exp(G_C) (..., 1, K), A, Aqk)."""
     size = q.shape[-2]
     sub = min(sub, size)
     row, col = _iota(size, 0), _iota(size, 1)
@@ -125,56 +147,159 @@ def chunk_parts(q, k, kb, g, operand, sub: int = SUB):
     qk = jnp.where(row >= col, jnp.concatenate(qk_rows, axis=-2), 0.0)
     decay = jnp.exp(running)
     end = running[..., size - 1:, :]
-    return (q * decay, kb * decay, k * jnp.exp(end - running), jnp.exp(end),
-            unit_lower_inverse(a, operand), qk)
+    return q * decay, kb * decay, k * jnp.exp(end - running), jnp.exp(end), a, qk
+
+
+def chunk_parts(q, k, kb, g, operand, sub: int = SUB):
+    """``chunk_products`` with ``(I + A)^-1`` in the place of ``A``."""
+    *decayed, a, qk = chunk_products(q, k, kb, g, operand, sub)
+    return (*decayed, unit_lower_inverse(a, operand), qk)
+
+
+def chunk_state(state, kb_decayed, k_to_end, decay_to_end, inverse, vb, operand):
+    """A chunk's ``w = vb - Kd S^T`` and ``u = T w`` from ``state`` (..., V, K),
+    the state TRANSPOSED (its decay is then a row's scale), and the state
+    after: (w, u (..., C, V), the state after)."""
+    w = vb - _mm(kb_decayed, state, "nt", operand)
+    u = _mm(inverse, w, "nn", operand)
+    return w, u, state * decay_to_end + _mm(u, k_to_end, "tn", operand)
 
 
 def chunk_apply(state, q_decayed, kb_decayed, k_to_end, decay_to_end, inverse, qk, vb,
                 operand):
-    """One chunk from ``state`` (..., V, K), the state TRANSPOSED (its decay is
-    then a row's scale): (the chunk's outputs (..., C, V), the state after)."""
-    u = _mm(inverse, vb - _mm(kb_decayed, state, "nt", operand), "nn", operand)
-    out = _mm(q_decayed, state, "nt", operand) + _mm(qk, u, "nn", operand)
-    return out, state * decay_to_end + _mm(u, k_to_end, "tn", operand)
+    """One chunk from ``state``: (the chunk's outputs (..., C, V), the state
+    after)."""
+    _, u, after = chunk_state(state, kb_decayed, k_to_end, decay_to_end, inverse, vb, operand)
+    return _mm(q_decayed, state, "nt", operand) + _mm(qk, u, "nn", operand), after
+
+
+def chunk_apply_transposed(dstate, dout, state, w, u, q_decayed, kb_decayed, k_to_end,
+                           decay_to_end, inverse, qk, operand):
+    """``chunk_apply``'s derivative turned round: from the cotangents ``dout``
+    (..., C, V) of a chunk's outputs and ``dstate`` (..., V, K) of the state
+    after, (the cotangent of the state it started from, the cotangents of
+    ``chunk_apply``'s seven operands in its order).  Ten products."""
+    du = _mm(qk, dout, "tn", operand) + _mm(k_to_end, dstate, "nt", operand)
+    dw = _mm(inverse, du, "tn", operand)
+    before = (dstate * decay_to_end + _mm(dout, q_decayed, "tn", operand)
+              - _mm(dw, kb_decayed, "tn", operand))
+    return before, (_mm(dout, state, "nn", operand), -_mm(dw, state, "nn", operand),
+                    _mm(u, dstate, "nn", operand),
+                    jnp.sum(dstate * state, axis=-2, keepdims=True),
+                    _mm(du, w, "nt", operand), _mm(dout, u, "nt", operand), dw)
+
+
+def _operand(dtype):
+    return jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
+
+
+def _tiles(x, chunk: int):
+    """(W, H, D) -> float32 (chunks, H, chunk, D), zeros behind the window."""
+    window, heads, _ = x.shape
+    chunks = -(-window // chunk)
+    x = jnp.pad(x.astype(jnp.float32), ((0, chunks * chunk - window), (0, 0), (0, 0)))
+    return x.reshape(chunks, chunk, heads, -1).transpose(0, 2, 1, 3)
+
+
+def _window_parts(q, k, v, g, beta, chunk: int, build):
+    """A window's tiles through ``build`` (``chunk_parts`` or ``chunk_products``),
+    and ``beta v`` behind them."""
+    operand = _operand(q.dtype)
+    q, k, v, g, beta = (_tiles(x, chunk) for x in (q, k, v, g, beta[..., None]))
+    return (*build(q, k, k * beta, g, operand), v * beta)
 
 
 def _window_scan(q, k, v, g, beta, chunk: int):
     """One window: q, k, g (W, H, K), v (W, H, V), beta (W, H) -> (W, H, V)."""
     window, heads, _ = q.shape
     dtype = q.dtype
-    operand = jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
-    chunks = -(-window // chunk)
-
-    def tiles(x):
-        """(W, H, D) -> float32 (chunks, H, chunk, D)."""
-        x = jnp.pad(x.astype(jnp.float32), ((0, chunks * chunk - window), (0, 0), (0, 0)))
-        return x.reshape(chunks, chunk, heads, -1).transpose(0, 2, 1, 3)
-
-    q, k, v, g, beta = (tiles(x) for x in (q, k, v, g, beta[..., None]))
-    parts = chunk_parts(q, k, k * beta, g, operand)
+    operand = _operand(dtype)
+    parts = _window_parts(q, k, v, g, beta, chunk, chunk_parts)
 
     def step(state, xs):
         out, state = chunk_apply(state, *xs, operand)
         return state, out
 
     state = jnp.zeros((heads, v.shape[-1], k.shape[-1]), jnp.float32)
-    _, out = jax.lax.scan(step, state, (*parts, v * beta))
-    out = out.transpose(0, 2, 1, 3).reshape(chunks * chunk, heads, -1)
+    _, out = jax.lax.scan(step, state, parts)
+    out = out.transpose(0, 2, 1, 3).reshape(-1, heads, out.shape[-1])
     return out[:window].astype(dtype)
+
+
+def _window_scan_transposed(q, k, v, g, beta, dout, chunk: int):
+    """One window's cotangents of q, k, v, g, beta, in their shapes and dtypes,
+    from the outputs' ``dout`` (W, H, V): the parts of all chunks again; the
+    chunks' start states, ``w`` and ``u`` by a walk of ``chunk_state`` (three of
+    the forward's five products); ONE reverse walk of ``chunk_apply_transposed``
+    carrying the state's cotangent; the parts' cotangents back to the inputs,
+    all chunks at once."""
+    operand = _operand(q.dtype)
+    parts, parts_back = jax.vjp(
+        lambda *x: _window_parts(*x, chunk, chunk_products), q, k, v, g, beta)
+    *decayed, decay_to_end, a, qk, vb = parts
+    inverse = unit_lower_inverse(a, operand)
+    # the products' operands ONCE, ahead of both walks, in the dtype _mm gives
+    # them (the same values; half the bytes a step reads in bfloat16)
+    q_decayed, kb_decayed, k_to_end, inverse, qk, dout = (
+        x.astype(operand) for x in (*decayed, inverse, qk, _tiles(dout, chunk)))
+
+    def forth(state, xs):
+        w, u, after = chunk_state(state, *xs, operand)
+        return after, (state, w, u)
+
+    def back(dstate, xs):
+        return chunk_apply_transposed(dstate, *xs, operand)
+
+    zero = jnp.zeros((q.shape[1], v.shape[-1], k.shape[-1]), jnp.float32)
+    _, walked = jax.lax.scan(forth, zero, (kb_decayed, k_to_end, decay_to_end, inverse, vb))
+    _, dparts = jax.lax.scan(
+        back, zero,
+        (dout, *walked, q_decayed, kb_decayed, k_to_end, decay_to_end, inverse, qk),
+        reverse=True)
+    # d(I + A)^-1 = -T^T dT T^T; parts_back keeps its strictly lower part
+    da = -_mm(_mm(inverse, dparts[4], "tn", operand), inverse, "nt", operand)
+    return parts_back((*dparts[:4], da, *dparts[5:]))
+
+
+def scan_forward(q, k, v, g, beta, chunk: int = CHUNK):
+    """``kda_chunk_scan`` without its own derivative: a window at a time."""
+    return jax.lax.map(lambda x: _window_scan(*x, chunk), (q, k, v, g, beta))
+
+
+_scan = jax.custom_vjp(scan_forward, nondiff_argnums=(5,))
+
+
+def _scan_fwd(q, k, v, g, beta, chunk):
+    return scan_forward(q, k, v, g, beta, chunk), (q, k, v, g, beta)
+
+
+def _scan_bwd(chunk, kept, dout):
+    """A window at a time.  The log-decay's cotangent, the one float32 array of
+    the inputs' size, is written over the log-decay's own rows: as a stacked
+    output XLA allocated it before the block's backward pass began, and it lay
+    at the train step's memory peak."""
+    q, k, v, g, beta = kept
+
+    def window(g, xs):
+        i, *one = xs
+        dq, dk, dv, dg, dbeta = _window_scan_transposed(
+            *one[:3], jax.lax.dynamic_index_in_dim(g, i, keepdims=False), *one[3:], chunk)
+        return jax.lax.dynamic_update_index_in_dim(g, dg, i, 0), (dq, dk, dv, dbeta)
+
+    dg, (dq, dk, dv, dbeta) = jax.lax.scan(
+        window, g, (jnp.arange(g.shape[0]), q, k, v, beta, dout))
+    return dq, dk, dv, dg, dbeta
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 def kda_chunk_scan(q, k, v, g, beta, *, chunk: int = CHUNK):
     """``o`` (B, W, H, V) of the recurrence above for q, k, g (B, W, H, K),
     v (B, W, H, V), beta (B, W, H); every window from a zero state.  A window
     that is no multiple of ``chunk`` is padded behind its last position (a
-    position there changes no output before it).  Out in ``q.dtype``.
-
-    A window at a time (``lax.map``), each under ``jax.checkpoint``: the
-    backward pass then holds ONE window's parts and chunk states (0.5 GB at
-    1,024 x 32 x 128) and walks that window again, where the whole batch's are
-    2.2 GB at four windows."""
-    one = jax.checkpoint(functools.partial(_window_scan, chunk=chunk))
-    return jax.lax.map(lambda x: one(*x), (q, k, v, g, beta))
+    position there changes no output before it).  Out in ``q.dtype``."""
+    return _scan(q, k, v, g, beta, chunk)
 
 
 def causal_conv(x, taps):

@@ -4,7 +4,9 @@ against the recurrence walked position by position (the reference's form,
 and the gradients of q, k, v, the log-decay and beta; windows that are and are
 not a multiple of the chunk; keys that look alike (where powers of the
 chunk's triangular matrix outgrow float32); the triangular inverse; the short
-causal convolution against a shifted sum."""
+causal convolution against a shifted sum.  The scan's own backward rule
+against JAX's derivative of the same forward, under ``jax.checkpoint`` inside
+``lax.scan`` (how the trunk's blocks call it), and what it keeps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -69,6 +71,93 @@ def test_bfloat16_operands_keep_float32_decays_and_stay_near_the_recurrence():
     assert out.dtype == jnp.bfloat16
     error = jnp.linalg.norm(out.astype(jnp.float32) - want) / jnp.linalg.norm(want)
     assert float(error) < 0.02
+
+
+NAMES = "q k v g beta".split()
+
+
+def gradients(scan, args, weight, **kwargs):
+    return jax.grad(lambda *a: jnp.sum(scan(*a, **kwargs).astype(jnp.float32) * weight),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+
+
+def assert_gradients_close(got, wanted, tolerance):
+    """Each of the five within ``tolerance`` of its wanted gradient's largest value."""
+    for name, a, b in zip(NAMES, got, wanted):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert float(jnp.abs(b).max()) > 0, name
+        np.testing.assert_allclose(a, b, atol=tolerance * float(jnp.abs(b).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+@pytest.mark.parametrize("window, chunk", [(128, 64), (100, 64), (40, 16)])
+def test_the_backward_rule_is_the_derivative_of_the_forward(batch, window, chunk):
+    """The rule written by hand against what JAX derives from the undecorated
+    forward, in float32: one function, two backward passes."""
+    args = operands(batch, window, 2, 32, seed=window + batch)
+    weight = jax.random.normal(jax.random.PRNGKey(7), args[2].shape, jnp.float32)
+    np.testing.assert_array_equal(ops.kda_chunk_scan(*args, chunk=chunk),
+                                  ops.scan_forward(*args, chunk))
+    got = gradients(ops.kda_chunk_scan, args, weight, chunk=chunk)
+    wanted = gradients(ops.scan_forward, args, weight, chunk=chunk)
+    assert_gradients_close(got, wanted, 2e-6)
+
+
+@pytest.mark.parametrize("alike, decay", [(0.6, 0.02), (0.9, 0.002), (0.6, 1.0)])
+def test_gradients_with_keys_that_look_alike_and_a_slow_decay(alike, decay):
+    """Where the chunk's inverse holds entries in the thousands the rule's
+    ``-T^T dT T^T`` still is the recurrence's gradient."""
+    args = operands(1, 128, 2, 32, seed=3, alike=alike, decay=decay)
+    weight = jax.random.normal(jax.random.PRNGKey(8), args[2].shape, jnp.float32)
+    got = gradients(ops.kda_chunk_scan, args, weight)
+    assert_gradients_close(got, gradients(walked, args, weight), 5e-5)
+
+
+@pytest.mark.parametrize("alike, decay", [(0.0, 1.0), (0.3, 0.1)])
+def test_bfloat16_gradients_stay_near_the_float32_recurrences(alike, decay):
+    q, k, v, g, beta = operands(2, 128, 2, 32, seed=5, alike=alike, decay=decay)
+    low = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+    weight = jax.random.normal(jax.random.PRNGKey(9), v.shape, jnp.float32)
+    got = gradients(ops.kda_chunk_scan, (*low, g, beta), weight)
+    wanted = gradients(walked, (*[x.astype(jnp.float32) for x in low], g, beta), weight)
+    for name, a, b, like in zip(NAMES, got, wanted, (*low, g, beta)):
+        assert a.dtype == like.dtype and a.shape == like.shape, name
+        error = jnp.linalg.norm(a.astype(jnp.float32) - b) / jnp.linalg.norm(b)
+        assert float(error) < 0.03, (name, float(error))
+
+
+@pytest.mark.parametrize("window, chunk", [(64, 16), (40, 16)])
+def test_the_rule_under_checkpoint_inside_a_scan_of_layers(window, chunk):
+    """How the trunk calls it: ``nn.remat`` blocks under ``nn.scan``."""
+    args = operands(2, window, 2, 32, seed=11)
+    scales = jnp.asarray([1.0, 0.7, 1.3], jnp.float32)
+
+    def trunk(scan, checkpointed):
+        def loss(q, k, v, g, beta):
+            def layer(x, scale):
+                return x + scan(q * scale, k, x, g * scale, beta, chunk=chunk), None
+
+            body = jax.checkpoint(layer) if checkpointed else layer
+            return jnp.sum(jax.lax.scan(body, v, scales)[0] ** 2)
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+    assert_gradients_close(trunk(ops.kda_chunk_scan, True), trunk(ops.scan_forward, False), 2e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("batch, window, chunk", [(1, 128, 64), (3, 100, 64), (2, 40, 16)])
+def test_the_forward_rule_keeps_the_inputs_and_at_most_the_chunk_states(
+        dtype, batch, window, chunk):
+    """What lies between the forward and the backward pass: no more bytes than
+    the five inputs and a float32 state a head and chunk."""
+    q, k, v, g, beta = operands(batch, window, 2, 32, seed=1)
+    args = (*[x.astype(dtype) for x in (q, k, v)], g, beta)
+    kept = jax.tree.leaves(jax.eval_shape(
+        lambda *a: jax.vjp(lambda *b: ops.kda_chunk_scan(*b, chunk=chunk), *a)[1], *args))
+    size = lambda x: int(np.prod(x.shape)) * x.dtype.itemsize  # noqa: E731
+    states = batch * -(-window // chunk) * 2 * 32 * 32 * 4
+    assert sum(map(size, kept)) <= sum(map(size, args)) + states
 
 
 @pytest.mark.parametrize("size", [16, 64])

@@ -3,9 +3,12 @@
 the forward and of forward + backward at one shape, one JSON line a variant,
 then the variants' largest differences.  ``python3 tools/kda_scan_ab.py [B W H D]``
 (default 4 1024 32 128, bfloat16 operands, float32 decay).  ``VARIANTS`` names
-the functions of that module to time: the chunked ``jax.numpy`` form that ships,
-and beside it whatever is tried against it (PR 33 timed a Mosaic kernel pair
-here and dropped it).  Numbers from a CPU are no device numbers."""
+the functions of that module to time: ``kda_chunk_scan``, which ships with a
+backward pass of its own (PR 34), and ``scan_forward``, the same forward left
+to JAX's derivative (every window's stacked residuals at once: the parent's
+backward pass without the ``jax.checkpoint`` that walked each window again),
+or whatever else is tried against it (PR 33 timed a Mosaic kernel pair here and
+dropped it).  Numbers from a CPU are no device numbers."""
 from __future__ import annotations
 
 import json
@@ -15,7 +18,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-VARIANTS = ("kda_chunk_scan",)
+VARIANTS = ("kda_chunk_scan", "scan_forward")
 
 
 def main(argv) -> int:
