@@ -1,4 +1,11 @@
-"""Plain reference of the ``mla_moe_decoder`` trunk whose blocks take their
+"""The benchmark's OWN copy of the plain reference of the ``mla_moe_decoder``
+trunk whose blocks take their mixer by kind (``gymfx_tpu/reference/hybrid_decoder.py``
+at PR 35: gated short convolutions and grouped-query attention beside the
+linear-attention hybrid of PR 33): the yardstick may not move with the program.
+``checks/reference_policy_conv_hybrid.py`` holds the measured program against it.
+Everything below the next line is that file's text.
+
+Plain reference of the ``mla_moe_decoder`` trunk whose blocks take their
 token mixer BY KIND: linear-attention layers with one latent-attention layer a
 period (experts chosen by groups), or gated short convolutions with one
 grouped-query attention layer a period (no shared expert): forward, PPO loss,

@@ -35,18 +35,22 @@ The scope paths, under ``jit(...)``:
   update/guard                  metrics, quarantine, masked resets
   .../attention, .../ffn        the two halves of a transformer block, under
                                 ``policy_act`` and ``policy_forward`` (the
-                                decoder trunk: latent attention; the DENSE
-                                layer's gated feed-forward)
+                                decoder trunk: latent or grouped-query
+                                attention; the DENSE layer's gated feed-forward)
   .../linear_attention          the Kimi-Delta-Attention half of a hybrid
                                 trunk's block, flat under the same two: norm,
                                 projections, short convolution, gates, the
                                 chunked scan, gated norm, output product
+  .../short_conv                the gated-short-convolution half of a block
+                                whose kind is ``conv``, flat under the same
+                                two: norm, the one projection to B | C | u,
+                                the gates, the causal taps, the output product
   .../moe_router                an expert layer, flat under the same two:
                                 its norm, the scores, top-k and weights
   .../moe_dispatch              sort by expert, rows to the buffer and back,
                                 the weighted sum
   .../moe_experts               grouped products over the experts held
-  .../moe_shared                the shared expert
+  .../moe_shared                the shared expert (none where a model has none)
 """
 from __future__ import annotations
 
@@ -70,6 +74,7 @@ GUARD = "guard"
 ATTENTION = "attention"
 FFN = "ffn"
 LINEAR_ATTENTION = "linear_attention"
+SHORT_CONV = "short_conv"
 MOE_ROUTER = "moe_router"
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
@@ -82,7 +87,7 @@ PHASE_SCOPES = (ROLLOUT, UPDATE)
 SCOPE_NAMES = PHASE_SCOPES + (
     POLICY_ACT, ENV_STEP, TAPE_READ, DYNAMICS, OBS, AUTO_RESET, GAE,
     MINIBATCH_TAKE, LOSS, POLICY_FORWARD, OPTIMIZER, GUARD, ATTENTION, FFN,
-) + MOE_SCOPES + (LINEAR_ATTENTION,)
+) + MOE_SCOPES + (LINEAR_ATTENTION, SHORT_CONV)
 
 
 def join(*names: str) -> str:
@@ -95,6 +100,7 @@ LAYERS = (
     join(ROLLOUT, POLICY_ACT, ATTENTION),
     join(ROLLOUT, POLICY_ACT, FFN),
     join(ROLLOUT, POLICY_ACT, LINEAR_ATTENTION),
+    join(ROLLOUT, POLICY_ACT, SHORT_CONV),
     *(join(ROLLOUT, POLICY_ACT, part) for part in MOE_SCOPES),
     join(ROLLOUT, ENV_STEP, TAPE_READ),
     join(ROLLOUT, ENV_STEP, DYNAMICS),
@@ -107,6 +113,7 @@ LAYERS = (
     join(UPDATE, LOSS, POLICY_FORWARD, ATTENTION),
     join(UPDATE, LOSS, POLICY_FORWARD, FFN),
     join(UPDATE, LOSS, POLICY_FORWARD, LINEAR_ATTENTION),
+    join(UPDATE, LOSS, POLICY_FORWARD, SHORT_CONV),
     *(join(UPDATE, LOSS, POLICY_FORWARD, part) for part in MOE_SCOPES),
     join(UPDATE, OPTIMIZER),
     join(UPDATE, GUARD),

@@ -7,20 +7,39 @@ key shared by all heads, causal) and a gated feed-forward: dense in the
 leading ``first_k_dense_replace`` layers, an expert layer after them.  The
 last position's state feeds the actor and the critic head.
 
-A HYBRID trunk is the same trunk with its attention taken by the layer's
-index: with ``layer_group_size`` n > 0 every n-th layer (``(l + 1) % n == 0``)
-keeps latent attention and the others are Kimi Delta Attention
-(``KimiDeltaAttention``: a linear-attention layer whose per-head state is a
-key x value matrix under the gated delta rule with a decay of its own for every
-key channel; ``ops/kda_chunk_scan.py``).  ``q_lora_rank`` 0 is latent attention
-without the low-rank query path, ``attn_output_gate`` its head-wise sigmoid
-gate, ``n_group`` / ``topk_group`` the router's choice by groups of experts.
+A block takes its token mixer by the layer's KIND (``layer_kinds``), of four:
+
+  ``latent_attention``  ``LatentAttention`` (scope ``attention``): the default of
+                        every layer; ``q_lora_rank`` 0 is latent attention without
+                        the low-rank query path, ``attn_output_gate`` its
+                        head-wise sigmoid gate
+  ``linear_attention``  ``KimiDeltaAttention`` (scope ``linear_attention``): a
+                        layer whose per-head state is a key x value matrix under
+                        the gated delta rule with a decay of its own for every key
+                        channel (``ops/kda_chunk_scan.py``); with
+                        ``layer_group_size`` n > 0 every layer but the n-th
+                        (``(l + 1) % n == 0``, which keeps latent attention)
+  ``conv``              ``ShortConv`` (scope ``short_conv``): a gated short
+                        convolution, ``out = (C * conv(B * u)) W_out`` with
+                        ``B, C, u`` the thirds of one projection and
+                        ``conv_L_cache`` causal taps a channel (the same
+                        ``causal_conv`` KDA's projections pass through): no
+                        state, no scan, no score matrix
+  ``full_attention``    ``GroupedQueryAttention`` (scope ``attention``):
+                        ``num_key_value_heads`` key-value heads shared by runs of
+                        query heads, RMSNorm over each head of q and of k, rotary
+                        positions over the whole head, causal
+
+The last two are named by the published ``layer_types`` list, one entry a layer
+(``policy_kwargs.layer_types``); the first two by ``layer_group_size``.  ``n_group``
+/ ``topk_group`` are the router's choice by groups of experts.
 
 The expert layer is TOLD which experts it holds (``experts_held`` of
 ``n_routed_experts`` from ``expert_offset``): the router scores all
 experts and keeps its top-k, the layer computes the terms of the sum
 whose expert it holds, for the tokens routed there, plus the shared
-expert every token passes through, and hands the partial result on.
+expert every token passes through (none with ``n_shared_experts`` 0: no
+``shared_*`` parameter, no ``moe_shared`` scope), and hands the partial result on.
 Nothing stands in for the chips that hold the other experts.  Dispatch is
 dropless: token choices are placed by expert in a buffer (twice the expected
 rows where the batch fits that, the worst case's rows else), a grouped matrix
@@ -39,12 +58,17 @@ Parameters are float32, compute is ``dtype``; RMSNorm, rotary angles,
 the router's scores and the softmax of attention run in float32.  The
 module takes any leading batch dims itself (``takes_batch``): the expert
 layer sorts the tokens of the WHOLE batch, so a trainer calls it on the
-batch instead of vmapping it over envs.  The four expert layers of a
-configuration are one ``nn.scan`` over stacked parameters, each block
-rematerialised in the backward pass (``remat``).
+batch instead of vmapping it over envs.  The expert layers of a
+configuration are one ``nn.scan`` over stacked parameters for every run of one
+kind (``layer_runs``), each block rematerialised in the backward pass
+(``remat``).  Counters (``COUNTERS``): the expert layers' ``moe_held_share`` and
+``moe_load_max_over_mean``; ``kda_log_decay_mean`` where a layer is linear
+attention; ``short_conv_gate_rms`` where one is a convolution (the root mean
+square of ``B * u`` ahead of the taps, the mean over those layers: a gate that
+has died reads 0, one that has blown up reads it).
 
 The plain reference of the same equations is
-``gymfx_tpu/reference/mla_moe_decoder.py``; the hybrid trunk's is
+``gymfx_tpu/reference/mla_moe_decoder.py``; the trunk by kinds (all four) is
 ``gymfx_tpu/reference/hybrid_decoder.py``.
 """
 from __future__ import annotations
@@ -86,6 +110,13 @@ class Dims(NamedTuple):
     kda_conv_size: int = 4
     kda_lower_bound: float = -5.0
     kda_chunk: int = 64
+    num_key_value_heads: int = 8
+    conv_L_cache: int = 3
+
+
+# the kinds of token mixer (``layer_kinds``); the last two are the published
+# ``layer_types`` entries
+LATENT, LINEAR, CONV, FULL = "latent_attention", "linear_attention", "conv", "full_attention"
 
 
 _matrix = nn.initializers.variance_scaling(
@@ -489,6 +520,54 @@ class KimiDeltaAttention(_Layer):
         return o @ self.weight("o", (inner, d.hidden_size)), jnp.mean(g)
 
 
+class ShortConv(_Layer):
+    """Pre-norm gated short convolution: (B, W, hidden) -> (the same, the root
+    mean square of the gated input ahead of the taps, float32).  One projection
+    to three times the width, parted ``B | C | u``; ``conv_L_cache`` causal taps
+    a channel over ``B * u`` (zeros before the window, no activation), gated by
+    ``C``, projected back."""
+
+    @nn.compact
+    def __call__(self, x):
+        from gymfx_tpu.ops.kda_chunk_scan import causal_conv
+
+        d = self.dims
+        y = self.norm("attn_norm", x)
+        b, c, u = jnp.split(
+            y @ self.weight("in_proj", (d.hidden_size, 3 * d.hidden_size)), 3, axis=-1)
+        gated = b * u
+        z = causal_conv(gated, self.weight("taps", (d.conv_L_cache, d.hidden_size)))
+        rms = jnp.sqrt(jnp.mean(jnp.square(gated.astype(jnp.float32))))
+        return (c * z) @ self.weight("out_proj", (d.hidden_size, d.hidden_size)), rms
+
+
+class GroupedQueryAttention(_Layer):
+    """Pre-norm grouped-query attention: (B, W, hidden) -> the same.  Query head
+    h reads key-value head ``h // (heads / num_key_value_heads)``; q and k are
+    RMS-normalised a head (64 weights each) and rotated over the whole head."""
+
+    @nn.compact
+    def __call__(self, x):
+        from gymfx_tpu.train.policies import dense_window_attention
+
+        d = self.dims
+        heads, kv_heads = d.num_attention_heads, d.num_key_value_heads
+        width = d.hidden_size // heads
+        y = self.norm("attn_norm", x)
+
+        def parted(name, n):
+            out = y @ self.weight(name, (d.hidden_size, n * width))
+            return out.reshape(*out.shape[:-1], n, width)
+
+        q = rope_interleaved(self.norm("q_norm", parted("q", heads)), d.rope_theta)
+        k = rope_interleaved(self.norm("k_norm", parted("k", kv_heads)), d.rope_theta)
+        # the attention kernel takes ONE head count: k and v repeated to the query heads
+        k, v = (jnp.repeat(t, heads // kv_heads, axis=-2) for t in (k, parted("v", kv_heads)))
+        a = dense_window_attention(q, k, v, causal=True)
+        return a.reshape(*a.shape[:-2], heads * width) @ self.weight(
+            "o", (heads * width, d.hidden_size))
+
+
 class DenseFfn(_Layer):
     """Pre-norm gated (SwiGLU) feed-forward of a leading dense layer."""
 
@@ -503,7 +582,8 @@ class DenseFfn(_Layer):
 
 class ExpertLayer(_Layer):
     """Pre-norm expert layer of the chip's share: (T, hidden) -> the partial
-    sum of the experts held here plus the shared expert, the counters
+    sum of the experts held here plus the shared expert (where the model has
+    one), the counters
     (choices on the experts held, the largest expert's load, float32) and the
     (T, k) expert choices."""
 
@@ -529,63 +609,87 @@ class ExpertLayer(_Layer):
         w_up = self.weight("experts_up", shape, _expert_matrix)
         w_down = self.weight("experts_down", (shape[0], shape[2], shape[1]), _expert_matrix)
 
-        routed = routed_experts(d, tokens, align)(y, idx, weights, w_gate, w_up, w_down)
-        with jax.named_scope(scopes.MOE_SHARED):
-            width = d.moe_intermediate_size * d.n_shared_experts
-            shared = swiglu(y, self.weight("shared_gate", (d.hidden_size, width)),
-                            self.weight("shared_up", (d.hidden_size, width)),
-                            self.weight("shared_down", (width, d.hidden_size)))
+        out = routed_experts(d, tokens, align)(y, idx, weights, w_gate, w_up, w_down)
+        shared = None
+        if d.n_shared_experts:
+            with jax.named_scope(scopes.MOE_SHARED):
+                width = d.moe_intermediate_size * d.n_shared_experts
+                shared = swiglu(y, self.weight("shared_gate", (d.hidden_size, width)),
+                                self.weight("shared_up", (d.hidden_size, width)),
+                                self.weight("shared_down", (width, d.hidden_size)))
         loads = expert_loads(idx, d)[2].astype(jnp.float32)
-        return routed + shared, jnp.stack([jnp.sum(loads), jnp.max(loads)]), idx
+        # (summed behind the loads: where the accepted configurations' step has it)
+        out = out if shared is None else out + shared
+        return out, jnp.stack([jnp.sum(loads), jnp.max(loads)]), idx
+
+
+# kind -> (the mixer, its name in the parameter tree, its scope, the counter of its
+# own that it returns beside its output: the policy hands out the mean over its layers)
+MIXERS = {
+    LATENT: (LatentAttention, "attn", scopes.ATTENTION, None),
+    LINEAR: (KimiDeltaAttention, "kda", scopes.LINEAR_ATTENTION, "kda_log_decay_mean"),
+    CONV: (ShortConv, "conv", scopes.SHORT_CONV, "short_conv_gate_rms"),
+    FULL: (GroupedQueryAttention, "self_attn", scopes.ATTENTION, None),
+}
 
 
 class _Block(nn.Module):
-    """One pre-norm residual block: latent attention (or, ``linear``, Kimi
-    Delta Attention), then a gated feed-forward that is dense
-    (``sparse=False``) or the expert layer.  Called on (B, W, hidden); returns
-    it with (the expert layer's counters, its choices, the linear layer's mean
-    log-decay), ``None`` what the block has not, in the shape ``nn.scan`` wants."""
+    """One pre-norm residual block: the token mixer of the layer's ``kind``
+    (``MIXERS``), then a gated feed-forward that is dense (``sparse=False``) or
+    the expert layer.  Called on (B, W, hidden); returns it with (the expert
+    layer's counters, its choices, the mixer's own counter: a linear-attention
+    layer's mean log-decay, a convolution's gate RMS), ``None`` what the block
+    has not, in the shape ``nn.scan`` wants."""
 
     dims: Dims
     sparse: bool
     dtype: Any
-    linear: bool = False
+    kind: str = LATENT
 
     @nn.compact
     def __call__(self, x, _=None):
-        decay = None
-        if self.linear:
-            with jax.named_scope(scopes.LINEAR_ATTENTION):
-                mixed, decay = KimiDeltaAttention(self.dims, self.dtype, name="kda")(x)
-                x = x + mixed
-        else:
-            with jax.named_scope(scopes.ATTENTION):
-                x = x + LatentAttention(self.dims, self.dtype, name="attn")(x)
+        mixer, name, scope, counts = MIXERS[self.kind]
+        with jax.named_scope(scope):
+            mixed = mixer(self.dims, self.dtype, name=name)(x)
+            mixed, counter = mixed if counts else (mixed, None)
+            x = x + mixed
         if not self.sparse:
             with jax.named_scope(scopes.FFN):
                 x = x + DenseFfn(self.dims, self.dtype, name="ffn")(x)
-            return x, (None, None, decay)
+            return x, (None, None, counter)
         out, counters, idx = ExpertLayer(self.dims, self.dtype, name="experts")(
             x.reshape(-1, self.dims.hidden_size))
-        return x + out.reshape(x.shape), (counters, idx, decay)
+        return x + out.reshape(x.shape), (counters, idx, counter)
 
 
-def layer_runs(n_layers: int, first_k_dense_replace: int, layer_group_size: int):
-    """The trunk's layers as (name, first layer, layers, sparse, linear): every
+def layer_kinds(n_layers: int, layer_group_size: int = 0, layer_types=None):
+    """The mixer's kind of every layer: the published ``layer_types`` where a
+    configuration has the list (one entry a layer held), else latent attention
+    in every ``layer_group_size``-th layer and linear attention in the others
+    (every layer latent with ``layer_group_size`` 0)."""
+    if layer_types is not None:
+        kinds = tuple(layer_types)
+        unknown = sorted(set(kinds) - set(MIXERS))
+        if unknown or len(kinds) != n_layers:
+            raise ValueError(f"layer_types: {len(kinds)} entries for {n_layers} layers, "
+                             f"unknown kinds {unknown} (known: {sorted(MIXERS)})")
+        return kinds
+    return tuple(LINEAR if layer_group_size and (l + 1) % layer_group_size else LATENT
+                 for l in range(n_layers))
+
+
+def layer_runs(kinds, first_k_dense_replace: int):
+    """The trunk's layers as (name, first layer, layers, sparse, kind): every
     leading dense layer alone (``dense_<l>``), the expert layers in runs of one
-    attention kind, each run one ``nn.scan`` (``moe`` where there is one run,
+    kind of mixer, each run one ``nn.scan`` (``moe`` where there is one run,
     ``moe_<first layer>`` else)."""
+    n_layers = len(kinds)
     dense = min(first_k_dense_replace, n_layers)
-
-    def linear(layer):
-        return bool(layer_group_size) and (layer + 1) % layer_group_size != 0
-
-    runs = [(f"dense_{l}", l, 1, False, linear(l)) for l in range(dense)]
-    starts = [l for l in range(dense, n_layers)
-              if l == dense or linear(l) != linear(l - 1)]
+    runs = [(f"dense_{l}", l, 1, False, kinds[l]) for l in range(dense)]
+    starts = [l for l in range(dense, n_layers) if l == dense or kinds[l] != kinds[l - 1]]
     for start, end in zip(starts, starts[1:] + [n_layers]):
         name = "moe" if len(starts) == 1 else f"moe_{start}"
-        runs.append((name, start, end - start, True, linear(start)))
+        runs.append((name, start, end - start, True, kinds[start]))
     return runs
 
 
@@ -623,21 +727,30 @@ class MlaMoeDecoderPolicy(nn.Module):
     kda_conv_size: int = Dims._field_defaults["kda_conv_size"]
     kda_lower_bound: float = Dims._field_defaults["kda_lower_bound"]
     kda_chunk: int = Dims._field_defaults["kda_chunk"]
+    layer_types: Optional[tuple] = None      # the published list, one kind a layer held
+    num_key_value_heads: int = Dims._field_defaults["num_key_value_heads"]
+    conv_L_cache: int = Dims._field_defaults["conv_L_cache"]
 
     # the trainers call this module on the whole env batch (no vmap), and
     # ask it for its layers' counters inside the loss
     takes_batch = True
 
+    def __post_init__(self):
+        if isinstance(self.layer_types, list):      # JSON's list: a module's fields are hashed
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        super().__post_init__()
+
     @property
     def COUNTERS(self):
         """Counters the loss carries out: the expert layers' two and, of a
-        trunk with linear-attention layers, their mean log-decay."""
-        moe = ("moe_held_share", "moe_load_max_over_mean")
-        linear = any(run[4] for run in self._runs())
-        return moe + (("kda_log_decay_mean",) if linear else ())
+        trunk with linear-attention or convolution layers, those layers' own."""
+        kinds = {run[4] for run in self._runs()}
+        return ("moe_held_share", "moe_load_max_over_mean") + tuple(
+            counts for kind, (*_, counts) in MIXERS.items() if counts and kind in kinds)
 
     def _runs(self):
-        return layer_runs(self.n_layers, self.first_k_dense_replace, self.layer_group_size)
+        return layer_runs(layer_kinds(self.n_layers, self.layer_group_size, self.layer_types),
+                          self.first_k_dense_replace)
 
     def dims(self) -> Dims:
         held = self.experts_held or self.n_routed_experts
@@ -659,20 +772,20 @@ class MlaMoeDecoderPolicy(nn.Module):
         w_in = self.param("in_proj", _matrix, (x.shape[-1], dims.hidden_size), jnp.float32)
         x = x @ w_in.astype(self.dtype)
         block = nn.remat(_Block) if self.remat else _Block
-        stats, chosen, decays = [], [], []
-        for name, _first, layers, sparse, linear in self._runs():
+        stats, chosen, own = [], [], {}
+        for name, _first, layers, sparse, kind in self._runs():
             if sparse:
-                x, (counted, idx, decay) = nn.scan(
+                x, (counted, idx, counter) = nn.scan(
                     block, variable_axes={"params": 0}, split_rngs={"params": True},
                     length=layers,
-                )(dims, True, self.dtype, linear, name=name)(x, None)
+                )(dims, True, self.dtype, kind, name=name)(x, None)
                 stats.append(counted)
                 chosen.append(idx)
             else:
-                x, (_, _, decay) = block(dims, False, self.dtype, linear, name=name)(x)
-                decay = decay if decay is None else decay[None]
-            if linear:
-                decays.append(decay)
+                x, (_, _, counter) = block(dims, False, self.dtype, kind, name=name)(x)
+                counter = counter if counter is None else counter[None]
+            if counter is not None:
+                own.setdefault(MIXERS[kind][3], []).append(counter)
         if not stats:
             stats, chosen = jnp.zeros((1, 2), jnp.float32), None
         elif len(stats) == 1:       # one run: its own arrays, no copy
@@ -694,8 +807,8 @@ class MlaMoeDecoderPolicy(nn.Module):
             "moe_held_share": held / choices,
             "moe_load_max_over_mean": largest * dims.experts_held / jnp.maximum(held, 1.0),
         }
-        if decays:
-            counted["kda_log_decay_mean"] = jnp.mean(jnp.concatenate(decays))
+        for name, read in own.items():
+            counted[name] = jnp.mean(jnp.concatenate(read))
         return logits, value, counted
 
     def initial_carry(self, batch_shape=()):

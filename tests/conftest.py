@@ -37,3 +37,35 @@ from gymfx_tpu.compile_cache import CACHE_ENV, enable_compile_cache  # noqa: E40
 os.environ[CACHE_ENV] = enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
+
+import gc  # noqa: E402
+
+import pytest  # noqa: E402
+
+# A process may hold 65,530 memory mappings (``vm.max_map_count``), and every
+# executable XLA:CPU compiles or loads from the cache maps its code: a decoder
+# trunk's CLI train-resume-serve test leaves some 12,000 behind (its Pallas
+# kernels run interpreted: large programs), two test files 58,000.  A worker
+# that reached the limit died of a segmentation fault inside the next compile or
+# cache read, whichever test that was (the driver's run of PR 34's tree:
+# ``ServingEngine.warmup``; PR 35's first whole run: that and IMPALA's
+# ``train_many``), and passed alone.  JAX's in-memory caches keep the
+# executables alive; dropping them releases the mappings (30,726 -> 1,223), and
+# what a later test needs again comes out of the persistent cache.
+MAPPINGS_BEFORE_A_CLEAR = 12_000
+
+
+def _mappings() -> int:
+    try:
+        with open("/proc/self/maps") as maps:
+            return sum(1 for _ in maps)
+    except OSError:     # no procfs here: nothing to count, nothing to clear
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def _executables_do_not_pile_up():
+    yield
+    if _mappings() > MAPPINGS_BEFORE_A_CLEAR:
+        jax.clear_caches()
+        gc.collect()

@@ -43,12 +43,12 @@ def policy_and_params(dtype=jnp.float32, seed=0, window=40, **over):
 
 
 def test_the_layers_kinds_follow_the_index_and_runs_of_one_kind_are_one_scan():
-    assert mod.layer_runs(5, 1, 0) == [("dense_0", 0, 1, False, False),
-                                       ("moe", 1, 4, True, False)]
-    assert mod.layer_runs(6, 1, 6) == [("dense_0", 0, 1, False, True),
-                                       ("moe_1", 1, 4, True, True),
-                                       ("moe_5", 5, 1, True, False)]
-    assert [run[1:] for run in mod.layer_runs(12, 2, 6)] == ref.layer_runs(
+    assert mod.layer_runs(mod.layer_kinds(5), 1) == [
+        ("dense_0", 0, 1, False, mod.LATENT), ("moe", 1, 4, True, mod.LATENT)]
+    assert mod.layer_runs(mod.layer_kinds(6, 6), 1) == [
+        ("dense_0", 0, 1, False, mod.LINEAR), ("moe_1", 1, 4, True, mod.LINEAR),
+        ("moe_5", 5, 1, True, mod.LATENT)]
+    assert [run[1:] for run in mod.layer_runs(mod.layer_kinds(12, 6), 2)] == ref.layer_runs(
         dict(n_layers=12, first_k_dense_replace=2, layer_group_size=6))
     _policy, params, _ = policy_and_params()
     tree = params["params"]

@@ -536,10 +536,24 @@ def test_the_analytic_flops_count_experts_by_their_active_share_as_the_benchmark
 # ---------------------------------------------------------------------------
 # the normal path beyond the trainer: the CLI, a checkpoint, serving
 # ---------------------------------------------------------------------------
-def test_cli_training_checkpoint_resume_and_one_served_decision(tmp_path):
+# the trunk with its mixers by the published ``layer_types`` (tests/test_conv_hybrid_decoder.py)
+CONV_TINY = dict(hidden_size=64, num_attention_heads=8, num_key_value_heads=2, conv_L_cache=3,
+                 intermediate_size=160, moe_intermediate_size=48, n_routed_experts=16,
+                 num_experts_per_tok=4, n_shared_experts=0, routed_scaling_factor=1.0,
+                 n_layers=4, experts_held=4, expert_offset=4,
+                 layer_types=["conv", "conv", "conv", "full_attention"])
+
+
+@pytest.mark.parametrize("kwargs, run, mixer, counter", [
+    (TINY, "moe", "attn", "moe_load_max_over_mean"),
+    (CONV_TINY, "moe_1", "conv", "short_conv_gate_rms"),
+], ids=["latent_attention", "conv_hybrid"])
+def test_cli_training_checkpoint_resume_and_one_served_decision(tmp_path, kwargs, run, mixer,
+                                                                counter):
     """``--mode training`` with the policy's nested ``policy_kwargs`` as JSON
     on the command line -> checkpoint -> ``--resume_training`` -> a decision
-    served by ``engine_from_config`` from that checkpoint."""
+    served by ``engine_from_config`` from that checkpoint (the engine calls such
+    a policy on the bucket as it is: ``takes_batch``)."""
     from gymfx_tpu.app.main import main
     from gymfx_tpu.serve.engine import engine_from_config
     from gymfx_tpu.train.checkpoint import load_checkpoint, read_metadata
@@ -548,18 +562,21 @@ def test_cli_training_checkpoint_resume_and_one_served_decision(tmp_path):
     base = ["--mode", "training", "--input_data_file", "examples/data/eurusd_uptrend.csv",
             "--num_envs", "4", "--ppo_horizon", "4", "--ppo_minibatches", "2",
             "--window_size", "8", "--policy", "mla_moe_decoder",
-            "--policy_kwargs", json.dumps(TINY), "--train_total_steps", "32",
+            "--policy_kwargs", json.dumps(kwargs), "--train_total_steps", "32",
             "--checkpoint_dir", str(ck), "--quiet_mode"]
     first = main(base + ["--results_file", str(tmp_path / "r1.json")])
     assert np.isfinite(first["train_metrics"]["loss"])
     assert 0.0 <= first["train_metrics"]["moe_held_share"] <= 1.0
+    assert first["train_metrics"][counter] > 0.0
     second = main(base + ["--resume_training", "true",
                           "--results_file", str(tmp_path / "r2.json")])
     assert np.isfinite(second["train_metrics"]["loss"])
     tree, step = load_checkpoint(str(ck))
     assert step == 64
     assert read_metadata(str(ck))["policy_kwargs"]["experts_held"] == 4
-    assert tree["params"]["params"]["moe"]["experts"]["experts_gate"].shape == (2, 4, 64, 48)
+    block = tree["params"]["params"][run]
+    assert mixer in block
+    assert block["experts"]["experts_gate"].shape == (2, 4, 64, 48)
 
     config = dict(DEFAULT_VALUES)
     config.update(input_data_file="examples/data/eurusd_uptrend.csv", window_size=8,

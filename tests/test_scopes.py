@@ -50,13 +50,24 @@ POLICIES = {
             moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
             n_group=2, topk_group=1, n_layers=3, experts_held=4, layer_group_size=3,
             attn_output_gate=True, kda_head_dim=16, kda_chunk=16)),
+    # the same trunk with its mixers by the published ``layer_types``: gated short
+    # convolutions, one grouped-query layer, an expert layer without a shared expert
+    "conv_hybrid_decoder": dict(
+        policy="mla_moe_decoder",
+        policy_kwargs=dict(
+            hidden_size=32, num_attention_heads=4, num_key_value_heads=2, conv_L_cache=3,
+            intermediate_size=64, moe_intermediate_size=16, n_routed_experts=8,
+            num_experts_per_tok=2, n_shared_experts=0, n_layers=3, experts_held=4,
+            layer_types=["conv", "conv", "full_attention"])),
 }
 # the parts of a policy's blocks, by policy: the layers that apply to it
 BLOCKS = {"mlp": (), "transformer_ring": (scopes.ATTENTION, scopes.FFN),
           "mla_moe_decoder": (scopes.ATTENTION, scopes.FFN) + scopes.MOE_SCOPES,
           "hybrid_decoder": (scopes.ATTENTION, scopes.FFN, scopes.LINEAR_ATTENTION)
-          + scopes.MOE_SCOPES}
-ALL_BLOCKS = BLOCKS["hybrid_decoder"]
+          + scopes.MOE_SCOPES,
+          "conv_hybrid_decoder": (scopes.ATTENTION, scopes.FFN, scopes.SHORT_CONV,
+                                  scopes.MOE_ROUTER, scopes.MOE_DISPATCH, scopes.MOE_EXPERTS)}
+ALL_BLOCKS = set().union(*BLOCKS.values())
 
 
 def layers_of(policy):
@@ -121,7 +132,7 @@ def test_the_loss_has_both_directions_and_the_rollout_none(handed_out, policy):
         # may lose its direction, and no other policy has one
         lost = [name for name, scope in scope_map.items()
                 if scope.path == forward and scope.direction is None]
-        assert len(lost) <= (2 if policy == "hybrid_decoder" else 1)
+        assert len(lost) <= (1 if policy == "mla_moe_decoder" else 2)
         defined = [line for line in step.as_text().splitlines()
                    if line.split("=")[0].split()[-1:] in [[f"%{name}"] for name in lost]]
         assert len(defined) == len(lost)

@@ -113,12 +113,17 @@ def test_fused_attention_compiles(one_chip, window, dtype, direction):
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_fused_attention_compiles_causal_at_the_decoder_trunks_heads(one_chip, direction):
-    """``mla_moe_decoder`` at published widths: 20 heads of 256 (192 nope + 64
-    rope for q.k, 256 for v), causal, window 256, a minibatch's 64 windows."""
+@pytest.mark.parametrize("shape", [(64, 256, 20, 256), (16, 1024, 32, 64)],
+                         ids=["latent_20x256_w256", "grouped_query_32x64_w1024"])
+def test_fused_attention_compiles_causal_at_the_decoder_trunks_heads(one_chip, shape, direction):
+    """``mla_moe_decoder`` at published widths, causal, a minibatch's windows:
+    latent attention's 20 heads of 256 (192 nope + 64 rope for q.k, 256 for v)
+    at window 256, a head a program; grouped-query attention's 32 heads of 64
+    at window 1,024, the PACKED route (two heads a lane group of 128) at the
+    longest window the kernel takes."""
     from gymfx_tpu.ops.fused_attention import fused_window_attention
 
-    x = _sds((64, 256, 20, 256), jnp.bfloat16)
+    x = _sds(shape, jnp.bfloat16)
 
     def fwd(q, k, v):
         return fused_window_attention(q, k, v, causal=True, interpret=False)
@@ -402,6 +407,51 @@ def test_hybrid_decoder_step_compiles_with_its_kernels_by_name(one_chip, monkeyp
     assert all("1024,192]" in line for line in attention), attention[:1]
     # the scan's chunk products are there, batched over a window's 16 chunks x 4 heads
     assert re.search(r"\[16,4,64,64\]", hlo)
+
+
+# ---------------------------------------------------------------------------
+# the convolution-attention trunk's whole train step (benchmarks/configs/
+# ppo_lfm2moe_ep8_bf16.json) at a cut size: the published head width (64, four
+# query heads a key-value head), window 1,024, the five layers whole, fewer heads
+# and narrower matrices.  The convolution layers are plain XLA; the ONE
+# grouped-query layer hands the attention kernel q, k and v of one head count on
+# its PACKED route (two 64-wide heads a lane group); no shared expert.
+# ---------------------------------------------------------------------------
+def test_conv_hybrid_decoder_step_compiles_with_its_kernels_by_name(one_chip, monkeypatch):
+    from collections import Counter
+
+    from gymfx_tpu.ops import dispatch
+    from gymfx_tpu.train.ppo import PPOTrainer, ppo_config_from
+    from tests.helpers import make_env, uptrend_df
+
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    env = make_env(
+        uptrend_df(1500), num_envs=4, ppo_horizon=2, ppo_epochs=1, ppo_minibatches=2,
+        policy="mla_moe_decoder", policy_dtype="bfloat16", window_size=1024,
+        ppo_minibatch_scheme="env_permute", rollout_collect_dtype="bfloat16",
+        policy_kwargs=dict(
+            hidden_size=512, num_attention_heads=8, num_key_value_heads=2, conv_L_cache=3,
+            intermediate_size=1024, moe_intermediate_size=256, n_routed_experts=64,
+            num_experts_per_tok=4, n_shared_experts=0, routed_scaling_factor=1.0,
+            first_k_dense_replace=1, n_layers=5, experts_held=8,
+            layer_types=["conv", "conv", "conv", "conv", "full_attention"]))
+    trainer = PPOTrainer(env, ppo_config_from(env.config))
+    hlo = _compile(trainer._train_step_impl,
+                   jax.eval_shape(trainer.init_state, 0), sharding=one_chip)
+    calls = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    counts = Counter(line.split("=")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+                     for line in calls)
+    assert set(counts) <= set(scopes.KERNEL_NAMES), counts
+    # ONE grouped-query layer: rollout, bootstrap, the loss's forward and its
+    # recomputation; one backward
+    assert counts[scopes.KERNEL_ATTENTION_FWD] == 4 and counts[scopes.KERNEL_ATTENTION_BWD] == 1
+    # two runs of expert layers (three convolution blocks under one scan, the attention block)
+    assert counts[scopes.KERNEL_GROUPED_MATMUL] == 40
+    assert counts[scopes.KERNEL_GROUPED_MATMUL_DW] == 8
+    # q, k and v reach the kernel lane-dense with ONE head count: (windows, 1024, 8 x 64)
+    attention = [line for line in calls if "fused_attention" in line]
+    assert all("1024,512]" in line and "1024,128]" not in line for line in attention), (
+        attention[:1])
 
 
 # ---------------------------------------------------------------------------
