@@ -61,8 +61,9 @@ layer sorts the tokens of the WHOLE batch, so a trainer calls it on the
 batch instead of vmapping it over envs.  The expert layers of a
 configuration are one ``nn.scan`` over stacked parameters for every run of one
 kind (``layer_runs``), each block rematerialised in the backward pass
-(``remat``).  Counters (``COUNTERS``): the expert layers' ``moe_held_share`` and
-``moe_load_max_over_mean``; ``kda_log_decay_mean`` where a layer is linear
+(``remat``).  Counters (``COUNTERS``): the expert layers' ``moe_held_share``,
+``moe_load_max_over_mean`` and ``moe_short_buffer_share`` (the share of layer
+passes whose choices fit the short buffer); ``kda_log_decay_mean`` where a layer is linear
 attention; ``short_conv_gate_rms`` where one is a convolution (the root mean
 square of ``B * u`` ahead of the taps, the mean over those layers: a gate that
 has died reads 0, one that has blown up reads it).
@@ -245,6 +246,16 @@ def padded_sizes(loads, align: int):
     return jnp.maximum(align, -(-loads // align) * align)
 
 
+def fits_short_buffer(idx, dims: Dims, align: int):
+    """Whether the rows the (T, k) choices take on the experts held here,
+    every expert's in whole tiles, fit the short buffer (bool scalar); never
+    where the short buffer would be no shorter than the worst case's."""
+    short, worst = (buffer_rows(idx.shape[0], dims, align, worst=w) for w in (False, True))
+    if short >= worst:
+        return jnp.zeros((), bool)
+    return jnp.sum(padded_sizes(expert_loads(idx, dims)[2], align)) <= short
+
+
 def routing_plan(idx, dims: Dims, align: int, rows: int = 0) -> Plan:
     """Place the (T, k) expert choices in a buffer of ``rows`` rows (the
     worst case by default) sorted by expert: the experts held here in order,
@@ -287,10 +298,13 @@ def _sum_of_choices(rows, plan: Plan, weights=None):
 @jax.custom_vjp
 def dispatch_rows(y, plan: Plan):
     """Tokens (T, hidden) -> the sorted buffer (rows, hidden): row r holds the
-    token whose choice was placed there, padding rows zeros.  The plan is a
-    partial one-to-one map of choices and rows known from both ends, so the
-    backward pass is gathers too (no scatter-add)."""
-    return _rows_of(y, plan.token, plan.valid)
+    token whose choice was placed there.  A padding row holds token 0's and is
+    NOT zeroed (a pass over the buffer for rows nothing reads: the way back
+    takes valid rows only, and a padding row's gradient is zero, so it adds
+    nothing to an expert's weights either).  The plan is a partial one-to-one
+    map of choices and rows known from both ends, so the backward pass is
+    gathers too (no scatter-add)."""
+    return jnp.take(y, plan.token, axis=0, mode="clip")
 
 
 def _dispatch_fwd(y, plan):
@@ -317,13 +331,16 @@ def _combine_fwd(ys, weights, plan):
 
 def _combine_bwd(res, g):
     ys, weights, plan = res
-    k, tokens = plan.dest.shape
-    row_weight = jnp.take(weights.reshape(-1), plan.choice, mode="clip").astype(g.dtype)
-    g_ys = _rows_of(g, plan.token, plan.valid) * row_weight[:, None]
-    g_weights = jnp.stack([
-        jnp.sum(_rows_of(ys, plan.dest[j], plan.held[j]).astype(jnp.float32)
-                * g.astype(jnp.float32), axis=-1) for j in range(k)])
-    return g_ys, g_weights.astype(weights.dtype), None
+    # zero where the row is padding: that row's gradient is then zero with no pass of its own
+    row_weight = jnp.where(plan.valid, jnp.take(weights.reshape(-1), plan.choice, mode="clip"), 0)
+    g_rows = jnp.take(g, plan.token, axis=0, mode="clip")
+    # a weight's gradient is the float32 dot of its row with the row's gradient, placed
+    # at the row's choice (a padding row's is dropped): no row of ``ys`` is gathered again
+    g_row_weight = jnp.sum(ys.astype(jnp.float32) * g_rows.astype(jnp.float32), axis=-1)
+    g_weights = jnp.zeros(weights.size, jnp.float32).at[plan.choice].set(
+        g_row_weight, mode="drop").reshape(weights.shape)
+    return (g_rows * row_weight.astype(g.dtype)[:, None], g_weights.astype(weights.dtype),
+            None)
 
 
 combine_rows.defvjp(_combine_fwd, _combine_bwd)
@@ -369,7 +386,7 @@ def routed_experts(dims: Dims, tokens: int, align: int):
 
     def fits(idx):
         with jax.named_scope(scopes.MOE_DISPATCH):
-            return jnp.sum(padded_sizes(expert_loads(idx, dims)[2], align)) <= short
+            return fits_short_buffer(idx, dims, align)
 
     def backward(rows):
         def run(g, y, idx, weights, *w):
@@ -584,8 +601,8 @@ class ExpertLayer(_Layer):
     """Pre-norm expert layer of the chip's share: (T, hidden) -> the partial
     sum of the experts held here plus the shared expert (where the model has
     one), the counters
-    (choices on the experts held, the largest expert's load, float32) and the
-    (T, k) expert choices."""
+    (choices on the experts held, the largest expert's load, 1 where the batch
+    went through the short buffer; float32) and the (T, k) expert choices."""
 
     @nn.compact
     def __call__(self, x):
@@ -620,7 +637,8 @@ class ExpertLayer(_Layer):
         loads = expert_loads(idx, d)[2].astype(jnp.float32)
         # (summed behind the loads: where the accepted configurations' step has it)
         out = out if shared is None else out + shared
-        return out, jnp.stack([jnp.sum(loads), jnp.max(loads)]), idx
+        short = fits_short_buffer(idx, d, align).astype(jnp.float32)
+        return out, jnp.stack([jnp.sum(loads), jnp.max(loads), short]), idx
 
 
 # kind -> (the mixer, its name in the parameter tree, its scope, the counter of its
@@ -742,10 +760,10 @@ class MlaMoeDecoderPolicy(nn.Module):
 
     @property
     def COUNTERS(self):
-        """Counters the loss carries out: the expert layers' two and, of a
+        """Counters the loss carries out: the expert layers' three and, of a
         trunk with linear-attention or convolution layers, those layers' own."""
         kinds = {run[4] for run in self._runs()}
-        return ("moe_held_share", "moe_load_max_over_mean") + tuple(
+        return ("moe_held_share", "moe_load_max_over_mean", "moe_short_buffer_share") + tuple(
             counts for kind, (*_, counts) in MIXERS.items() if counts and kind in kinds)
 
     def _runs(self):
@@ -764,7 +782,7 @@ class MlaMoeDecoderPolicy(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, counters: bool = False, routing: bool = False):
-        """(logits, value); with ``counters`` also the expert layers' two
+        """(logits, value); with ``counters`` also the expert layers' three
         counters, with ``routing`` also their (layers, tokens, k) choices."""
         dims = self.dims()
         batch = tokens.shape[:-2]
@@ -787,7 +805,7 @@ class MlaMoeDecoderPolicy(nn.Module):
             if counter is not None:
                 own.setdefault(MIXERS[kind][3], []).append(counter)
         if not stats:
-            stats, chosen = jnp.zeros((1, 2), jnp.float32), None
+            stats, chosen = jnp.zeros((1, 3), jnp.float32), None
         elif len(stats) == 1:       # one run: its own arrays, no copy
             stats, chosen = stats[0], chosen[0]
         else:
@@ -806,6 +824,7 @@ class MlaMoeDecoderPolicy(nn.Module):
         counted = {
             "moe_held_share": held / choices,
             "moe_load_max_over_mean": largest * dims.experts_held / jnp.maximum(held, 1.0),
+            "moe_short_buffer_share": jnp.mean(stats[:, 2]),
         }
         for name, read in own.items():
             counted[name] = jnp.mean(jnp.concatenate(read))

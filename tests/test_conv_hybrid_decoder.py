@@ -212,7 +212,8 @@ def test_the_parameter_tree_has_the_mixers_by_kind_and_no_shared_expert():
 
 def test_the_counters_hold_the_convolutions_gate_rms():
     policy, params, tokens = policy_and_params()
-    assert policy.COUNTERS == ("moe_held_share", "moe_load_max_over_mean", "short_conv_gate_rms")
+    assert policy.COUNTERS == ("moe_held_share", "moe_load_max_over_mean", "moe_short_buffer_share",
+                               "short_conv_gate_rms")
     _, _, counted = policy.apply(params, tokens, counters=True)
     assert set(counted) == set(policy.COUNTERS)
     # by hand over the four convolution layers of the reference's own forward

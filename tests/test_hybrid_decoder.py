@@ -77,12 +77,13 @@ def test_logits_values_and_choices_are_the_references(over):
 
 def test_the_counters_hold_the_linear_layers_mean_log_decay():
     policy, params, tokens = policy_and_params()
-    assert policy.COUNTERS == ("moe_held_share", "moe_load_max_over_mean", "kda_log_decay_mean")
+    assert policy.COUNTERS == ("moe_held_share", "moe_load_max_over_mean", "moe_short_buffer_share",
+                               "kda_log_decay_mean")
     _, _, counted = policy.apply(params, tokens, counters=True)
     assert set(counted) == set(policy.COUNTERS)
     assert -5.0 < float(counted["kda_log_decay_mean"]) < 0.0
     glm = make_policy("mla_moe_decoder", **{**TINY, "layer_group_size": 0, "q_lora_rank": 24})
-    assert glm.COUNTERS == ("moe_held_share", "moe_load_max_over_mean")
+    assert glm.COUNTERS == ("moe_held_share", "moe_load_max_over_mean", "moe_short_buffer_share")
 
 
 def tiny_trainer(policy_dtype="float32", **policy_over):

@@ -335,6 +335,101 @@ def test_the_short_buffer_and_the_worst_case_buffer_give_the_same():
             np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
+def choices_for(routing, tokens, k, held, n, seed=0):
+    """(T, k) expert choices, the experts held the first ``held`` of ``n``:
+    ``even`` draws k distinct experts a token, token 0's then set ALL on experts
+    held (as many as are held) and token 1's none; ``one_expert`` puts one
+    choice of every token on expert 1 and the others on experts not held;
+    ``none_held`` every choice on experts not held."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), tokens)
+    if routing == "even":
+        idx = jax.vmap(lambda key: jax.random.permutation(key, n)[:k])(keys)
+        mostly_held = jnp.where(jnp.arange(k) < held, jnp.arange(k), held + jnp.arange(k))
+        return idx.at[0].set(mostly_held % n).at[1].set((held + jnp.arange(k)) % n)
+    away = jax.vmap(lambda key: held + jax.random.permutation(key, n - held)[:k])(keys)
+    return away if routing == "none_held" else away.at[:, k // 2].set(1)
+
+
+@pytest.mark.parametrize("tokens, k, held, n, hidden, buffer, routing", [
+    (64, 4, 4, 32, 8, "short", "even"), (64, 4, 4, 32, 8, "worst", "even"),
+    (64, 4, 4, 32, 8, "short", "one_expert"), (64, 4, 4, 32, 8, "worst", "one_expert"),
+    (64, 4, 4, 32, 8, "short", "none_held"), (64, 4, 4, 32, 8, "worst", "none_held"),
+    (128, 8, 8, 512, 16, "short", "even"), (128, 8, 8, 512, 16, "worst", "even"),
+    (128, 8, 8, 512, 16, "short", "one_expert"), (128, 8, 8, 512, 16, "short", "none_held"),
+    (96, 2, 2, 16, 8, "short", "even"), (32, 4, 16, 16, 8, "worst", "even"),
+])
+def test_rows_there_and_back_are_the_plain_takes_and_their_gradients(
+        tokens, k, held, n, hidden, buffer, routing):
+    """The way back (``_sum_of_choices``, ``combine_rows``) is the k-gather sum
+    restated here, element for element; the gradients of rows there and back
+    are autodiff's of plain takes: a weight's gradient as its row's own float32
+    dot placed by one scatter, a padding row neither zeroed on the way there nor
+    given a gradient on the way back."""
+    dims = mod.Dims(n_routed_experts=n, num_experts_per_tok=k, experts_held=held)
+    align = 8
+    rows = mod.buffer_rows(tokens, dims, align, worst=buffer == "worst")
+    idx = choices_for(routing, tokens, k, held, n)
+    plan = mod.routing_plan(idx, dims, align, rows)
+    held_here = np.asarray(idx).T < held
+    np.testing.assert_array_equal(plan.held, held_here)
+    assert int(plan.valid.sum()) == held_here.sum() <= rows      # the batch fits the buffer
+    if routing == "even":                                       # all k held, and none
+        assert held_here[:, 0].sum() == min(k, held)
+        assert held_here[:, 1].sum() == (0 if held < n else k)      # the uncut layer holds all
+    # the rows in use are the choices held, each once, sorted by expert
+    at = np.asarray(plan.valid)
+    np.testing.assert_array_equal(np.sort(np.asarray(plan.choice)[at]),
+                                  np.flatnonzero(held_here.reshape(-1)))
+    np.testing.assert_array_equal(np.asarray(plan.dest)[held_here],
+                                  np.argsort(np.where(at, plan.choice, k * tokens))[:at.sum()])
+
+    def plain(buffer_rows, weights=None):
+        out = 0.0
+        for j in range(k):
+            part = jnp.where(plan.held[j][:, None],
+                             buffer_rows[jnp.minimum(plan.dest[j], rows - 1)], 0)
+            out = out + (part if weights is None else part * weights[j][:, None])
+        return out
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        ys = jax.random.normal(jax.random.PRNGKey(3), (rows, hidden)).astype(dtype)
+        ys = ys.at[~at].set(jnp.nan)            # what nothing may read: padding rows
+        weights = jax.random.uniform(jax.random.PRNGKey(2), (k, tokens), jnp.float32, 0.1, 2.0)
+        for w in (None, weights.astype(dtype)):
+            got, want = mod._sum_of_choices(ys, plan, w), plain(ys, w)
+            assert jnp.asarray(got).dtype == jnp.asarray(want).dtype
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(mod.combine_rows(ys, weights, plan), np.float32),
+            np.asarray(plain(ys, weights.astype(dtype)), np.float32))
+
+    # the gradients of rows there and back: autodiff of plain takes
+    y = jax.random.normal(jax.random.PRNGKey(1), (tokens, hidden), jnp.float32)
+    probe = jax.random.normal(jax.random.PRNGKey(4), (tokens, hidden), jnp.float32)
+    there = mod.dispatch_rows(y, plan)
+    np.testing.assert_array_equal(there[at], y[plan.token[at]])
+
+    def plain_loss(y, weights):
+        there = jnp.where(plan.valid[:, None], y[plan.token], 0)
+        return jnp.sum(plain(there * there, weights) * probe)
+
+    def fused_loss(y, weights):
+        there = mod.dispatch_rows(y, plan)
+        return jnp.sum(mod.combine_rows(there * there, weights, plan) * probe)
+
+    for got, want in zip(jax.grad(fused_loss, (0, 1))(y, weights),
+                         jax.grad(plain_loss, (0, 1))(y, weights)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(                 # a token none of whose choices is held
+        jax.grad(fused_loss)(y, weights)[~held_here.any(axis=0)], 0)
+    # a padding row gets no gradient on the way back, whatever the expert wrote there
+    _, pull = jax.vjp(lambda ys, w: mod.combine_rows(ys, w, plan), ys.astype(jnp.float32), weights)
+    g_ys, g_w = pull(probe)
+    np.testing.assert_array_equal(g_ys[~at], 0)
+    assert np.isfinite(np.asarray(g_w)).all() and (np.asarray(g_w)[~held_here] == 0).all()
+
+
 def test_the_shares_add_up_to_the_uncut_layer():
     """Four shares of four experts each: their partial sums, the shared
     expert counted once, are the uncut reference's layer."""
@@ -359,19 +454,25 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 @pytest.mark.parametrize("routing", ["fits_the_short_buffer", "overflows_it"])
 def test_the_layer_picks_its_buffer_by_the_batch_and_loses_no_token(routing):
-    """128 tokens: the short buffer (twice the expected rows) is shorter than
-    the worst case's, so the layer chooses under ``lax.cond``, forward and
-    backward; either way output and gradients are the reference's."""
+    """The short buffer (twice the expected rows) is shorter than the worst
+    case's, so the layer chooses under ``lax.cond``, forward and backward;
+    either way output and gradients are the reference's.  128 tokens fit it;
+    384 all of whose choices fall on the four experts held do not (three tiles
+    of 128 an expert against 768 + 512 rows; at 128 tokens they would: an
+    expert's 128 choices are one tile, which every expert has anyway), which
+    the counter ``moe_short_buffer_share`` says."""
     dims = dims_of()
-    assert mod.buffer_rows(128, dims, 128, worst=False) < mod.buffer_rows(128, dims, 128)
+    tokens = 384 if routing == "overflows_it" else 128
+    assert mod.buffer_rows(tokens, dims, 128, worst=False) < mod.buffer_rows(tokens, dims, 128)
     layer = mod.ExpertLayer(dims, jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(5), (128, dims.hidden_size), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(5), (tokens, dims.hidden_size), jnp.float32)
     params = layer.init(jax.random.PRNGKey(4), x)["params"]
     if routing == "overflows_it":
         params = {**params, "e_score_correction_bias":
                   jnp.zeros(16, jnp.float32).at[4:8].set(10.0)}
     out, counters, _idx = layer.apply({"params": params}, x)
-    assert (float(counters[0]) == 4 * 128) == (routing == "overflows_it")
+    assert (float(counters[0]) == 4 * tokens) == (routing == "overflows_it")
+    assert float(counters[2]) == (0.0 if routing == "overflows_it" else 1.0)   # short buffer
     cfg = cfg_of()
 
     def want(p, x):
@@ -438,6 +539,7 @@ def test_two_train_steps_are_finite_with_the_expert_layers_scopes_and_counters()
     assert all(np.isfinite(float(v)) for v in metrics.values())
     assert 0.0 < float(metrics["moe_held_share"]) < 1.0
     assert float(metrics["moe_load_max_over_mean"]) >= 1.0
+    assert 0.0 <= float(metrics["moe_short_buffer_share"]) <= 1.0
     paths = {scope.path for scope in scopes.last_step_scope_map().values()}
     for part in (scopes.ATTENTION, scopes.FFN) + scopes.MOE_SCOPES:
         assert scopes.join(scopes.ROLLOUT, scopes.POLICY_ACT, part) in paths
