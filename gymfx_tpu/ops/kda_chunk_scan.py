@@ -79,6 +79,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from gymfx_tpu.telemetry import scopes
+
 CHUNK = 64
 SUB = 16
 # the largest |g| a step may have: a block's columns grow by exp((SUB - 1) |g|)
@@ -286,8 +288,9 @@ def _scan_bwd(chunk, kept, dout):
             *one[:3], jax.lax.dynamic_index_in_dim(g, i, keepdims=False), *one[3:], chunk)
         return jax.lax.dynamic_update_index_in_dim(g, dg, i, 0), (dq, dk, dv, dbeta)
 
-    dg, (dq, dk, dv, dbeta) = jax.lax.scan(
-        window, g, (jnp.arange(g.shape[0]), q, k, v, beta, dout))
+    with jax.named_scope(scopes.KDA_SCAN):
+        dg, (dq, dk, dv, dbeta) = jax.lax.scan(
+            window, g, (jnp.arange(g.shape[0]), q, k, v, beta, dout))
     return dq, dk, dv, dg, dbeta
 
 
@@ -298,15 +301,20 @@ def kda_chunk_scan(q, k, v, g, beta, *, chunk: int = CHUNK):
     """``o`` (B, W, H, V) of the recurrence above for q, k, g (B, W, H, K),
     v (B, W, H, V), beta (B, W, H); every window from a zero state.  A window
     that is no multiple of ``chunk`` is padded behind its last position (a
-    position there changes no output before it).  Out in ``q.dtype``."""
-    return _scan(q, k, v, g, beta, chunk)
+    position there changes no output before it).  Out in ``q.dtype``.  The
+    whole call, both directions, is the ``kda_scan`` part of its layer
+    (telemetry/scopes.py)."""
+    with jax.named_scope(scopes.KDA_SCAN):
+        return _scan(q, k, v, g, beta, chunk)
 
 
 def causal_conv(x, taps):
     """Depthwise causal convolution over positions: x (..., W, channels), taps
     (taps, channels); ``y_t = sum_j taps[j] x_{t - (taps - 1) + j}``, zeros
-    before the window's first position."""
+    before the window's first position: the ``causal_conv`` part of its layer
+    (telemetry/scopes.py)."""
     n = taps.shape[0]
-    padded = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(n - 1, 0), (0, 0)])
-    window = x.shape[-2]
-    return sum(padded[..., j:j + window, :] * taps[j] for j in range(n))
+    with jax.named_scope(scopes.CAUSAL_CONV):
+        padded = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(n - 1, 0), (0, 0)])
+        window = x.shape[-2]
+        return sum(padded[..., j:j + window, :] * taps[j] for j in range(n))

@@ -1,9 +1,8 @@
 """Managed jax.profiler trace capture: superstep-windowed, manifested,
 never-raises.
 
-Raw ``jax.profiler.trace`` dumps (the old ``bench.py --trace`` /
-``tools/profile_rollout.py`` path) leave an anonymous directory nobody
-can attribute later.  :class:`ProfilerSession` owns the capture
+Raw ``jax.profiler.trace`` dumps (the old ``bench.py --trace`` path)
+leave an anonymous directory nobody can attribute later.  :class:`ProfilerSession` owns the capture
 instead: it starts/stops the trace around a superstep dispatch window
 on a configured cadence and writes a **capture bundle** —
 

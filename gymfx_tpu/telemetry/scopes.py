@@ -51,10 +51,36 @@ The scope paths, under ``jit(...)``:
                                 the weighted sum
   .../moe_experts               grouped products over the experts held
   .../moe_shared                the shared expert (none where a model has none)
+
+The PARTS of a block (PR 37), names the layer vocabulary leaves out
+(``PART_NAMES``), so that planting them moves no layer path and no metric
+that reads one; only the part map (``part_map_from_hlo``,
+``last_step_part_map``) reads them, as an extension of the op's layer path:
+
+  .../linear_attention/kda_scan the whole gated-delta-rule scan, both
+                                directions (ops/kda_chunk_scan.py: the loop
+                                over windows, the chunks' parts, the inverse,
+                                the walk, the scan's own backward pass); not
+                                the projections, taps, norms, output product
+  .../linear_attention/causal_conv, .../short_conv/causal_conv
+                                the pad and the shifted multiply-adds of a
+                                short causal convolution; not the gates, SiLU
+                                or the projections
+  .../attention/attention_core  between the q / k / v products' outputs and
+                                the output product's input: head norms, RoPE,
+                                the k | v split, the layout (value pad, the
+                                repeat to the query heads, lane packing), the
+                                attention call; not the pre-norm, the
+                                products, the output gate
+
+The part map also names what XLA adds without metadata, by one rule: an
+async ``*-done`` takes the scope its users share, its ``*-start`` the
+done's, a ``copy`` its user's; a done with no named user stays unnamed.
 """
 from __future__ import annotations
 
 import re
+import time
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 ROLLOUT = "rollout"
@@ -81,6 +107,13 @@ MOE_EXPERTS = "moe_experts"
 MOE_SHARED = "moe_shared"
 # the parts of an expert layer (train/mla_moe_decoder.py)
 MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED)
+
+# the parts of a block (PR 37): sub-layer scopes that the LAYER vocabulary
+# leaves out, so that no layer path changes; only the part map reads them
+KDA_SCAN = "kda_scan"
+CAUSAL_CONV = "causal_conv"
+ATTENTION_CORE = "attention_core"
+PART_NAMES = (KDA_SCAN, CAUSAL_CONV, ATTENTION_CORE)
 
 # the two phases every trainer's fused step plants (PR 6)
 PHASE_SCOPES = (ROLLOUT, UPDATE)
@@ -160,6 +193,10 @@ _RUNS_RE = re.compile(
 )
 # one path component: `transpose(jvp(policy_forward))` -> wrappers, name
 _COMPONENT_RE = re.compile(r"((?:\w+\()*)([^()]*)\)*$")
+# the opcode after the shape, `= (f32[8]{0:T(256)}, u32[]) copy-start(%fusion.3)`,
+# and the instructions its operand list names
+_OPCODE_RE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
 
 
 def _op_scope(op_name: str, scopes: Optional[Sequence[str]]) -> OpScope:
@@ -170,24 +207,34 @@ def _op_scope(op_name: str, scopes: Optional[Sequence[str]]) -> OpScope:
     .../fused_attention_bwd``, and a block rematerialised in the backward
     pass carries its whole stack again, ``loss/transpose(jvp(policy_forward))/
     .../loss/jvp(policy_forward)/.../rematted_computation/attention``: a
-    name already on the path takes the path back to where it stood."""
+    name already on the path takes the path back to where it stood.
+
+    A PART name in ``scopes`` (``PART_NAMES``) is no layer: the last one
+    after the path's last layer name is put behind the rooted layer path,
+    so a part extends an op's layer path and never moves it."""
     direction = BWD if "transpose(" in op_name else (
         FWD if "jvp(" in op_name else None)
     if scopes is None:
         return OpScope(op_name, direction)
     found: List[str] = []
-    for part in op_name.split("/"):
-        match = _COMPONENT_RE.match(part)
+    inside = ""
+    for component in op_name.split("/"):
+        match = _COMPONENT_RE.match(component)
         if match is None:
             # an op XLA merged carries every source's path, `a/b/add;jit(f)/a/c/add`:
             # the seam is no component, and the later path takes the earlier one back
             continue
         wrappers, name = match.groups()
         if name in scopes and "jit(" not in wrappers:
+            if name in PART_NAMES:
+                inside = name
+                continue
             if name in found:
                 del found[found.index(name):]
             found.append(name)
-    return OpScope(_rooted(join(*found)), direction)
+            inside = ""
+    path = _rooted(join(*found))
+    return OpScope(join(path, inside) if path and inside else path, direction)
 
 
 def _rooted(path: str) -> str:
@@ -239,9 +286,32 @@ def scope_map_from_hlo(
         return {}
 
 
-def _parse(hlo_text: str, scopes) -> Dict[str, OpScope]:
+def part_map_from_hlo(hlo_text: str) -> Tuple[Dict[str, OpScope], Dict[str, str]]:
+    """The PART map: ``scope_map_from_hlo`` with the vocabulary
+    ``SCOPE_NAMES + PART_NAMES`` (a part extends its op's layer path,
+    ``_op_scope``), and one rule more for the instructions XLA adds without
+    metadata: an async ``*-done`` (``copy-done``, ``slice-done``) takes the
+    scope its users share, as an unscoped ``while`` takes its body's
+    (``_shared_scope``), its ``*-start`` the done's, and a ``copy`` its
+    user's.  A done with no named user stays unnamed.  Also
+    ``{instruction: opcode}`` of the top-level instructions the map leaves
+    unnamed.  Never raises: text that is no HLO gives ``({}, {})``."""
+    unnamed: Dict[str, str] = {}
+    try:
+        return _parse(hlo_text or "", SCOPE_NAMES + PART_NAMES, unnamed), unnamed
+    except Exception:
+        return {}, {}
+
+
+def _parse(hlo_text: str, scopes, unnamed: Optional[Dict[str, str]] = None,
+           ) -> Dict[str, OpScope]:
+    """``unnamed`` given: the part map's rule for async pairs and copies
+    applies, and the top-level instructions left unnamed go into it with
+    their opcode."""
     by_computation: Dict[str, Dict[str, OpScope]] = {}
     callers: List[Tuple[str, str, str]] = []  # (name, computation, callee)
+    # computation -> [(name, opcode, operands, has op_name)], in text order
+    listed: Dict[str, List[Tuple[str, str, List[str], bool]]] = {}
     runs = set()
     computation = "?"
     for line in hlo_text.splitlines():
@@ -265,6 +335,11 @@ def _parse(hlo_text: str, scopes) -> Dict[str, OpScope]:
             callee = _CALLEE_RE.search(line)
             if callee:
                 callers.append((name, computation, callee.group(1)))
+        if unnamed is not None:
+            opcode = _OPCODE_RE.search(line, match.end())
+            if opcode:
+                listed.setdefault(computation, []).append(
+                    (name, opcode.group(1), _operands(line, opcode.end()), bool(op_name)))
         for targets in _RUNS_RE.findall(line):
             for target in ",".join(targets).split(","):
                 if target.strip():
@@ -277,8 +352,38 @@ def _parse(hlo_text: str, scopes) -> Dict[str, OpScope]:
             by_computation.setdefault(computation, {})[name] = scope
     out: Dict[str, OpScope] = {}
     for computation in runs:
-        out.update(by_computation.get(computation, {}))
+        named = by_computation.get(computation, {})
+        if unnamed is not None:
+            _name_async_and_copies(listed.get(computation, []), named)
+            unnamed.update((name, opcode) for name, opcode, *_ in listed.get(computation, [])
+                           if name not in named)
+        out.update(named)
     return out
+
+
+def _operands(line: str, at: int) -> List[str]:
+    """The instruction names in the operand list that opens at ``at``: the
+    compiled text prints an operand as its name alone, so the list ends at
+    the first ``)`` (a constant's literal, megabytes on one line, is read
+    at the speed of ``str.find``)."""
+    return _OPERAND_RE.findall(line, at, line.find(")", at))
+
+
+def _name_async_and_copies(listed, named: Dict[str, OpScope]) -> None:
+    """The part map's rule, in one computation: walked from its last
+    instruction to its first, so that a done's users (a copy among them)
+    and a start's done are named before it is."""
+    users: Dict[str, List[str]] = {}
+    for name, _opcode, operands, _has_op_name in listed:
+        for operand in operands:
+            users.setdefault(operand, []).append(name)
+    for name, opcode, _operands, has_op_name in reversed(listed):
+        if has_op_name or name in named or not (
+                opcode == "copy" or opcode.endswith(("-start", "-done"))):
+            continue
+        scope = _shared_scope(named[user] for user in users.get(name, ()) if user in named)
+        if scope.path:
+            named[name] = scope
 
 
 def _shared_scope(called) -> OpScope:
@@ -302,22 +407,51 @@ def _shared_scope(called) -> OpScope:
 # ---------------------------------------------------------------------------
 _executable: Any = None
 _scope_map: Optional[Dict[str, OpScope]] = None
+_part_map: Optional[Dict[str, OpScope]] = None
+_unnamed: Optional[Dict[str, str]] = None
+# what building the newest step's maps cost the host (a traced run's cost)
+build_cost: Dict[str, float] = {}
 
 
 def register_step(executable: Any) -> None:
     """Remember the newest step executable, and only it: registering does
     no work (the text of the flagship step is megabytes), and an older
     program is let go the moment a newer one is handed out."""
-    global _executable, _scope_map
-    _executable, _scope_map = executable, None
+    global _executable, _scope_map, _part_map, _unnamed
+    _executable, _scope_map, _part_map, _unnamed = executable, None, None, None
+
+
+def _build_maps() -> None:
+    """Both maps of the newest step from ONE text, when either is first
+    asked for; the executable is let go then and the maps kept."""
+    global _executable, _scope_map, _part_map, _unnamed
+    if _executable is None:
+        return
+    text, _executable = _executable.as_text(), None
+    started = time.perf_counter()
+    _scope_map = scope_map_from_hlo(text)
+    built = time.perf_counter()
+    _part_map, _unnamed = part_map_from_hlo(text)
+    build_cost.update(scope_map_s=built - started,
+                      part_map_s=time.perf_counter() - built, text_bytes=len(text))
 
 
 def last_step_scope_map() -> Optional[Dict[str, OpScope]]:
     """The scope map of the newest step program handed out, or ``None``
-    when there was none.  Computed when first asked for; the executable
-    is let go then and the map kept."""
-    global _executable, _scope_map
-    if _executable is not None:
-        _scope_map = scope_map_from_hlo(_executable.as_text())
-        _executable = None
+    when there was none."""
+    _build_maps()
     return _scope_map
+
+
+def last_step_part_map() -> Optional[Dict[str, OpScope]]:
+    """The part map (``part_map_from_hlo``) of the newest step program
+    handed out, or ``None`` when there was none."""
+    _build_maps()
+    return _part_map
+
+
+def last_step_unnamed() -> Optional[Dict[str, str]]:
+    """``{instruction: opcode}`` of the newest step's top-level instructions
+    that its part map leaves unnamed, or ``None`` when there was no step."""
+    _build_maps()
+    return _unnamed

@@ -454,19 +454,23 @@ class LatentAttention(_Layer):
         q = q.reshape(*q.shape[:-1], heads, nope + rope)
         kv_a = y @ self.weight("kv_a", (d.hidden_size, d.kv_lora_rank + rope))
         c_kv = self.norm("kv_a_norm", kv_a[..., :d.kv_lora_rank])
-        k_rope = rope_interleaved(kv_a[..., None, d.kv_lora_rank:], d.rope_theta)
+        # the attention core (telemetry/scopes.py): from the products' outputs
+        # to the output product's input, in two pieces so that no op moves
+        with jax.named_scope(scopes.ATTENTION_CORE):
+            k_rope = rope_interleaved(kv_a[..., None, d.kv_lora_rank:], d.rope_theta)
         kv = c_kv @ self.weight("kv_b", (d.kv_lora_rank, heads * (nope + vdim)))
         kv = kv.reshape(*kv.shape[:-1], heads, nope + vdim)
-        q = jnp.concatenate(
-            [q[..., :nope], rope_interleaved(q[..., nope:], d.rope_theta)], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_rope, (*kv.shape[:-1], rope))], axis=-1)
-        v = kv[..., nope:]
-        if vdim < nope + rope:
-            # the attention kernel takes ONE head width: values narrower than the
-            # keys go in padded with zero columns, which come out as zeros
-            v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, nope + rope - vdim)])
-        a = dense_window_attention(q, k, v, causal=True)[..., :vdim]
+        with jax.named_scope(scopes.ATTENTION_CORE):
+            q = jnp.concatenate(
+                [q[..., :nope], rope_interleaved(q[..., nope:], d.rope_theta)], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (*kv.shape[:-1], rope))], axis=-1)
+            v = kv[..., nope:]
+            if vdim < nope + rope:
+                # the attention kernel takes ONE head width: values narrower than the
+                # keys go in padded with zero columns, which come out as zeros
+                v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, nope + rope - vdim)])
+            a = dense_window_attention(q, k, v, causal=True)[..., :vdim]
         if d.attn_output_gate:
             gate = jax.nn.sigmoid(y @ self.weight("head_gate", (d.hidden_size, heads)))
             a = a * gate[..., None]
@@ -576,11 +580,19 @@ class GroupedQueryAttention(_Layer):
             out = y @ self.weight(name, (d.hidden_size, n * width))
             return out.reshape(*out.shape[:-1], n, width)
 
-        q = rope_interleaved(self.norm("q_norm", parted("q", heads)), d.rope_theta)
-        k = rope_interleaved(self.norm("k_norm", parted("k", kv_heads)), d.rope_theta)
-        # the attention kernel takes ONE head count: k and v repeated to the query heads
-        k, v = (jnp.repeat(t, heads // kv_heads, axis=-2) for t in (k, parted("v", kv_heads)))
-        a = dense_window_attention(q, k, v, causal=True)
+        # the attention core (telemetry/scopes.py): head norms, rotation, the
+        # repeat and the call, between the products
+        q = parted("q", heads)
+        with jax.named_scope(scopes.ATTENTION_CORE):
+            q = rope_interleaved(self.norm("q_norm", q), d.rope_theta)
+        k = parted("k", kv_heads)
+        with jax.named_scope(scopes.ATTENTION_CORE):
+            k = rope_interleaved(self.norm("k_norm", k), d.rope_theta)
+        v = parted("v", kv_heads)
+        with jax.named_scope(scopes.ATTENTION_CORE):
+            # the attention kernel takes ONE head count: k and v repeated to the query heads
+            k, v = (jnp.repeat(t, heads // kv_heads, axis=-2) for t in (k, v))
+            a = dense_window_attention(q, k, v, causal=True)
         return a.reshape(*a.shape[:-2], heads * width) @ self.weight(
             "o", (heads * width, d.hidden_size))
 

@@ -322,18 +322,19 @@ class RingTransformerEncoder(nn.Module):
                         name=f"DenseGeneral_{4 * layer + i}")(y)
                     for i in range(3)
                 )
-                if self.seq_axis is not None:
-                    sp_attention = (
-                        ulysses_attention_inner
-                        if self.sp_backend == "ulysses"
-                        else ring_attention_inner
-                    )
-                    a = sp_attention(
-                        *(split_heads(t, self.n_heads) for t in (q, k, v)),
-                        axis=self.seq_axis, n_shards=self.seq_shards,
-                    ).reshape(q.shape)
-                else:
-                    a = packed_window_attention(q, k, v, self.n_heads)
+                with jax.named_scope(scopes.ATTENTION_CORE):
+                    if self.seq_axis is not None:
+                        sp_attention = (
+                            ulysses_attention_inner
+                            if self.sp_backend == "ulysses"
+                            else ring_attention_inner
+                        )
+                        a = sp_attention(
+                            *(split_heads(t, self.n_heads) for t in (q, k, v)),
+                            axis=self.seq_axis, n_shards=self.seq_shards,
+                        ).reshape(q.shape)
+                    else:
+                        a = packed_window_attention(q, k, v, self.n_heads)
                 y = PackedHeadsDense(
                     into_heads[1:] + into_heads[:1], contract=2,
                     dtype=self.dtype, name=f"DenseGeneral_{4 * layer + 3}")(a)

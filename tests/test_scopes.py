@@ -10,7 +10,9 @@
     no-op gives;
   * the registry of the newest step program does no work until it is asked,
     and keeps no executable it no longer needs;
-  * the Pallas kernels carry their names into the lowered text.
+  * the Pallas kernels carry their names into the lowered text;
+  * the parts of a block (PR 37) lie under their layers in every pass in the
+    PART map, and the layer map is the map of the step without them.
 """
 import contextlib
 import gc
@@ -413,3 +415,180 @@ def test_an_op_merged_from_several_sources_takes_the_last_ones_path():
             f'  ROOT %b = f32[4]{{0}} add(%p, %p), metadata={{op_name="{merged}"}}\n'
             '}\n')
     assert scopes.scope_map_from_hlo(text) == {"b": scopes.OpScope("rollout/policy_act", None)}
+
+
+# ---------------------------------------------------------------------------
+# (e) the parts of a block and the part map (PR 37)
+# ---------------------------------------------------------------------------
+# the trunk with every kind of mixer in ONE step: a dense linear-attention
+# layer, then a run each of convolution, grouped-query and latent attention
+ALL_KINDS = dict(
+    policy="mla_moe_decoder",
+    policy_kwargs=dict(
+        hidden_size=32, q_lora_rank=None, kv_lora_rank=8, num_attention_heads=2,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, intermediate_size=64,
+        moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
+        n_group=2, topk_group=1, n_layers=4, experts_held=4, num_key_value_heads=1,
+        attn_output_gate=True, kda_head_dim=16, kda_chunk=16, conv_L_cache=3,
+        layer_types=["linear_attention", "conv", "full_attention", "latent_attention"]))
+
+
+def parts_patched_out(monkeypatch):
+    real = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext()
+                        if name in scopes.PART_NAMES else real(name))
+
+
+@pytest.fixture(scope="module")
+def parted():
+    """The all-kinds step through the program's hand-out, its two maps and its
+    op names, and the layer map of the same step traced with the parts'
+    scopes patched out (what the layer map was before the parts)."""
+    from gymfx_tpu.bench_util import compile_train_step
+
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setitem(POLICIES, "all_kinds", ALL_KINDS)
+    try:
+        trainer = make_trainer("all_kinds")
+        step, _flops = compile_train_step(trainer, trainer.init_state(0))
+        layer_map, part_map = scopes.last_step_scope_map(), scopes.last_step_part_map()
+        op_names = scopes.scope_map_from_hlo(step.as_text(), None)
+        parts_patched_out(monkeypatch)
+        plain = make_trainer("all_kinds")
+        plain_map = scopes.scope_map_from_hlo(
+            plain._train_step.lower(plain.init_state(0)).compile().as_text())
+    finally:
+        monkeypatch.undo()
+    return layer_map, part_map, op_names, plain_map
+
+
+FORWARD_PATH = scopes.join(scopes.UPDATE, scopes.LOSS, scopes.POLICY_FORWARD)
+PART_LAYERS = [(scopes.KDA_SCAN, scopes.LINEAR_ATTENTION),
+               (scopes.CAUSAL_CONV, scopes.LINEAR_ATTENTION),
+               (scopes.CAUSAL_CONV, scopes.SHORT_CONV),
+               (scopes.ATTENTION_CORE, scopes.ATTENTION)]
+# where a part's ops lie: the rollout, the update's two directions, the
+# forward recomputed in the backward pass under nn.remat, and the backward
+# pass of a custom VJP (its own scope behind the call site's)
+WHERE = {
+    "rollout": lambda op_name, scope: scope.direction is None,
+    "update_fwd": lambda op_name, scope: scope.direction == scopes.FWD,
+    "update_bwd": lambda op_name, scope: scope.direction == scopes.BWD,
+    "remat": lambda op_name, scope: (scope.direction == scopes.BWD
+                                     and "rematted_computation" in op_name),
+}
+
+
+@pytest.mark.parametrize("part, layer, where", [
+    (part, layer, where) for part, layer in PART_LAYERS for where in WHERE]
+    + [(scopes.KDA_SCAN, scopes.LINEAR_ATTENTION, "custom_vjp_bwd")])
+def test_each_part_lies_under_its_layer_in_every_pass(parted, part, layer, where):
+    _layer_map, part_map, op_names, _plain = parted
+    phase = (scopes.join(scopes.ROLLOUT, scopes.POLICY_ACT) if where == "rollout"
+             else FORWARD_PATH)
+    path = scopes.join(phase, layer, part)
+    if where == "custom_vjp_bwd":
+        def wanted(op_name, scope):
+            return scope.direction == scopes.BWD and f"/{part}/{part}/" in op_name
+    else:
+        wanted = WHERE[where]
+    assert [name for name, scope in part_map.items()
+            if scope.path == path and name in op_names
+            and wanted(op_names[name].path, scope)]
+
+
+def test_the_layer_map_holds_no_part_and_is_the_map_without_the_parts(parted):
+    layer_map, _part_map, _op_names, plain_map = parted
+    assert not {name for scope in layer_map.values() for name in scope.path.split("/")
+                } & set(scopes.PART_NAMES)
+    assert layer_map == plain_map
+
+
+def test_every_part_path_extends_its_layer_path(parted):
+    layer_map, part_map, _op_names, _plain = parted
+    for name, scope in layer_map.items():
+        got = part_map[name]
+        assert got.direction == scope.direction, name
+        assert got.path == scope.path or (
+            got.path.startswith(scope.path + "/")
+            and got.path.split("/")[-1] in scopes.PART_NAMES), (name, got, scope)
+    # what the part map names beyond the layer map, the rule named
+    assert all(name.split(".")[0] in ("copy", "copy-start", "copy-done")
+               for name in set(part_map) - set(layer_map))
+
+
+ASYNC_HLO = """\
+HloModule jit_step
+
+ENTRY %main.1 (a: f32[4]) -> f32[4] {
+  %p.1 = f32[4]{0} parameter(0)
+  %copy-start.1 = (f32[4]{0:T(256)S(1)}, f32[4]{0}, u32[]{:S(2)}) copy-start(%p.1)
+  %copy-done.1 = f32[4]{0:T(256)S(1)} copy-done(%copy-start.1)
+  %scan.1 = f32[4]{0} dot(%copy-done.1, %p.1), metadata={op_name="jit(step)/update/loss/jvp(policy_forward)/M/linear_attention/kda/kda_scan/dot_general"}
+  %proj.1 = f32[4]{0} dot(%p.1, %copy-done.1), metadata={op_name="jit(step)/update/loss/jvp(policy_forward)/M/linear_attention/kda/dot_general"}
+  %slice-start.1 = ((f32[8]{0}), f32[4]{0}, s32[]) slice-start(%p.1)
+  %slice-done.1 = f32[4]{0} slice-done(%slice-start.1)
+  %copy.1 = f32[4]{0} copy(%slice-done.1)
+  %core.1 = f32[4]{0} add(%copy.1, %scan.1), metadata={op_name="jit(step)/rollout/policy_act/M/attention/attention_core/add"}
+  %copy-start.2 = (f32[4]{0}, f32[4]{0}, u32[]) copy-start(%proj.1)
+  %copy-done.2 = f32[4]{0} copy-done(%copy-start.2)
+  %moved.1 = f32[4]{0} copy(%core.1), metadata={op_name="jit(step)/jit(helper)/copy"}
+  ROOT %tuple.1 = (f32[4]{0}, f32[4]{0}, f32[4]{0}) tuple(%copy-done.2, %core.1, %moved.1)
+}
+"""
+LINEAR = "update/loss/policy_forward/linear_attention"
+ASYNC_PARTS = {
+    "scan.1": (LINEAR + "/kda_scan", "fwd"),
+    "proj.1": (LINEAR, "fwd"),
+    "core.1": ("rollout/policy_act/attention/attention_core", None),
+    # a done takes what its users share, its start the done's
+    "copy-done.1": (LINEAR, "fwd"),
+    "copy-start.1": (LINEAR, "fwd"),
+    # a copy takes its user's, the done behind it the copy's, and so on back
+    "copy.1": ("rollout/policy_act/attention/attention_core", None),
+    "slice-done.1": ("rollout/policy_act/attention/attention_core", None),
+    "slice-start.1": ("rollout/policy_act/attention/attention_core", None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ASYNC_PARTS))
+def test_the_part_map_names_async_pairs_and_copies_after_their_users(name):
+    part_map, _unnamed = scopes.part_map_from_hlo(ASYNC_HLO)
+    assert part_map[name] == ASYNC_PARTS[name]
+
+
+@pytest.mark.parametrize("name, opcode", [
+    # a done whose one user is unnamed stays unnamed, and its start with it;
+    # a copy WITH metadata is the program's own, not one XLA added
+    ("copy-done.2", "copy-done"), ("copy-start.2", "copy-start"), ("moved.1", "copy"),
+    ("tuple.1", "tuple"), ("p.1", "parameter")])
+def test_what_the_part_map_cannot_name_is_handed_out_with_its_opcode(name, opcode):
+    part_map, unnamed = scopes.part_map_from_hlo(ASYNC_HLO)
+    assert name not in part_map and unnamed[name] == opcode
+    assert set(part_map) | set(unnamed) == set(ASYNC_PARTS) | {
+        "copy-done.2", "copy-start.2", "moved.1", "tuple.1", "p.1"}
+
+
+def test_the_layer_map_takes_no_part_and_names_no_copy():
+    assert scopes.scope_map_from_hlo(ASYNC_HLO) == {
+        "scan.1": (LINEAR, "fwd"), "proj.1": (LINEAR, "fwd"),
+        "core.1": ("rollout/policy_act/attention", None)}
+    assert scopes.part_map_from_hlo("no HLO (") == ({}, {})
+
+
+def test_both_maps_come_from_one_text_and_the_executable_is_let_go():
+    exe = FakeExecutable(ASYNC_HLO)
+    ref = weakref.ref(exe)
+    scopes.register_step(exe)
+    assert exe.asked == 0
+    part_map = scopes.last_step_part_map()
+    assert part_map["copy-done.1"].path == LINEAR
+    assert scopes.last_step_scope_map()["core.1"].path == "rollout/policy_act/attention"
+    assert scopes.last_step_unnamed()["tuple.1"] == "tuple"
+    assert exe.asked == 1 and scopes.last_step_part_map() is part_map
+    assert set(scopes.build_cost) == {"scope_map_s", "part_map_s", "text_bytes"}
+    del exe
+    gc.collect()
+    assert ref() is None
+    scopes.register_step(FakeExecutable(HLO))       # a newer step: new maps
+    assert "copy-done.1" not in scopes.last_step_part_map()
