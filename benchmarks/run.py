@@ -19,7 +19,9 @@ files' ``rehearse`` overrides (tiny sizes, Pallas interpreted) and
 
 The LAST line of stdout is the contract's object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
-``breakdown``); everything else goes on earlier lines.
+``breakdown``; last, ``compared`` where the runner hands on the numbers its
+check compared with their limits, which also end stderr); everything else goes
+on earlier lines.
 """
 from __future__ import annotations
 
@@ -118,6 +120,10 @@ def main(argv=None) -> int:
                     "value": run["end_to_end"][entry["name"]], "unit": entry["unit"]}
 
     device = harness.device_report(devices, cell["chips"])
+    if run.get("memory_peak_bytes"):
+        # read by the runner before its check: a reference that runs after the
+        # window may not set the program's peak
+        device["memory_peak_bytes"] = run["memory_peak_bytes"]
     line = {"correct": bool(run["correct"]), "attempted": int(run["attempted"]),
             "failed": int(run["failed"]), "metrics": metrics, "device": device}
     if args.trace and run.get("trace"):
@@ -125,6 +131,13 @@ def main(argv=None) -> int:
         device["window_s"] = run["trace"]["window_s"]
         line["breakdown"] = {"device_ops": run["trace"]["device_ops"][:10],
                              "idle_gaps": run["trace"]["idle_gaps"][:10]}
+    if run.get("compared"):
+        # what the check compared, each number beside its limit: the last lines
+        # on stderr and the last key of the line
+        for name, read in run["compared"].items():
+            print(f"compared {name} {read['value']} limit {read['limit']}",
+                  file=sys.stderr, flush=True)
+        line["compared"] = run["compared"]
     print(json.dumps(line), flush=True)
     return 0
 

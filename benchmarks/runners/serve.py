@@ -7,20 +7,22 @@ unbatched ``policy.apply_seq``, each session threading its own carry, a
 copy of ``chip_smoke.phase_serve``'s reference); every session of the mix
 seeded into its slot; a lead-in of the same traffic that is not judged.
 Window: ``loadgen.send`` offers the schedule open loop from THIS thread
-(the batcher's threads are the program's); latencies run from due times.
+(the batcher's threads are the program's); latencies run from due times.  The
+open loop, its summary and the terms of ``correct`` all serving cells share
+are ``serving.py``'s.
 
 Traced run: the same open loop for the traffic file's ``trace_seconds``
 under the profiler, and the batcher's own request records of that window.
 """
 from __future__ import annotations
 
-import contextlib
 import time
 
 import numpy as np
 
 import harness
 import loadgen
+import serving
 from tape import ensure_tape
 
 
@@ -134,8 +136,6 @@ class Stack:
 
 
 def run(ctx: harness.Context) -> dict:
-    import jax
-
     conf, traffic = ctx.cell["config"], ctx.cell["traffic"]
     compiles = harness.CompileCounter()
     stack = Stack(ctx)
@@ -153,38 +153,18 @@ def run(ctx: harness.Context) -> dict:
                             "cache_misses": compiles.misses},
                      check=check_detail, buckets=list(engine.buckets),
                      rate=traffic["rate"])
-        compiled_before = compiles.count
-        trace, end_to_end = {}, {}
-        window = (harness.traced_window(ctx.cell["name"], trace) if ctx.trace
-                  else contextlib.nullcontext())
-        with window:
-            end_to_end["setup_s"] = time.perf_counter() + lead_in - ctx.t0
-            sent, t0, t1, t_end = stack.offer(
-                harness.seed31(ctx.seed, 4), float(traffic["rate"]), lead_in, span_s,
-                float(traffic.get("answer_s", 10.0)),
-                span=jax.profiler.TraceAnnotation if ctx.trace else None)
-        records = [r for r in batcher.records if t0 <= r.t_enqueue < t1]
+        win = serving.open_loop(ctx, compiles, lead_in, lambda span: stack.offer(
+            harness.seed31(ctx.seed, 4), float(traffic["rate"]), lead_in, span_s,
+            float(traffic.get("answer_s", 10.0)), span=span))
+        records = [r for r in batcher.records if win.t0 <= r.t_enqueue < win.t1]
     finally:
         batcher.close(timeout=30)
 
-    summary = loadgen.summarise(sent, t0, t1, t_end)
-    end_to_end["decision_p95_ms"] = summary["latency_ms"][95]
-    end_to_end["decisions_per_s"] = summary["decisions_per_s"]
     stats = engine.slot_stats()
-    counters = {
-        "late_compiles": int(engine.late_compiles), "batch_mode": engine.batch_mode,
-        "donate": bool(engine.donate), "dispatches": int(batcher.dispatches),
-        "compiled_inside_window": compiles.count - compiled_before,
-        "shed": int(batcher.shed_count), "refused": sent.refused,
-        "errors": len(sent.error), "slots": stats,
-    }
-    correct = (check_ok and counters["late_compiles"] == 0
-               and counters["batch_mode"] == conf["expect"]["batch_mode"]
+    counters = {**serving.counters(engine, batcher, win), "donate": bool(engine.donate),
+                "slots": stats}
+    correct = (check_ok and serving.served_soundly(counters, conf, win)
                and counters["donate"] == conf["expect"]["donate"]
-               and counters["compiled_inside_window"] == 0
-               and stats["evictions"] == 0 and summary["attempted"] > 0)
-    harness.note(window=summary, counters=counters, check_ok=check_ok)
-    return {"correct": correct, "attempted": summary["attempted"],
-            "failed": summary["failed"], "end_to_end": end_to_end, "trace": trace,
-            "spans": {"generator_late_ms": summary["generator_late_ms"]},
-            "counters": counters, "records": records, "cell": ctx.cell}
+               and stats["evictions"] == 0)
+    harness.note(window=win.summary, counters=counters, check_ok=check_ok)
+    return serving.result(correct, win, counters, records, ctx.cell)

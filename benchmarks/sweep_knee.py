@@ -5,13 +5,26 @@
 
 One process, one booted stack.  Steps of ``step_s`` seconds of the cell's
 open loop at a fixed rate: the rate doubles until a step is not sustained,
-then the gap is bisected twice.  A step is SUSTAINED when nothing was shed
-or failed, the generator kept to its schedule (95 % of requests sent within
-10 ms of their due time) and the backlog at its end is no larger than at its
-middle — where "no larger" allows for noise: at most a quarter more, plus
-5 ms of arrivals (a backlog that grows steadily from nothing doubles).  The knee is the highest sustained rate; the
-cell's traffic file then carries four fifths of it.  Prints one JSON line a
-step and the table's summary last.
+then the gap is bisected ``bisections`` times.  Each step draws its own
+arrival times from its own seed (``Stack.offer``), also where the cell's runs
+replay one set of arrivals: a knee read on one pattern would read that
+pattern's last burst.  A step is SUSTAINED when
+  - nothing was shed or failed;
+  - the generator kept to its schedule (95 % of requests sent within 10 ms of
+    their due time);
+  - the backlog did not grow: its mean over ``INSTANTS`` instants of the
+    step's second half is at most a quarter over its mean in the first half,
+    plus one request (one in service at any load is no growth; a backlog that
+    grows steadily from nothing doubles);
+  - where the traffic file states ``knee_p95_limit_ms``, the step's p95
+    latency is within it: a server that batches keeps any backlog bounded by
+    filling its largest bucket from a standing queue, so without a latency
+    limit its "knee" is the rate at which the tail is seconds long.
+The knee is the highest sustained rate; the cell's traffic file then carries a
+fixed share of it (its ``what`` says which; ``four_fifths`` and ``half`` are
+printed).  The stack is the one the cell's runner boots
+(``runners/<runner>.py``'s ``Stack``).  Prints one JSON line a step and the
+table's summary last.
 """
 from __future__ import annotations
 
@@ -22,6 +35,26 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
 sys.path[:0] = [str(BENCH), str(BENCH.parent)]
+
+INSTANTS = 50
+
+
+def mean_backlog(sent, start: float, end: float) -> float:
+    """The backlog's mean over ``INSTANTS`` evenly spaced instants of ``[start, end)``."""
+    import numpy as np
+
+    import loadgen
+
+    return float(np.mean([loadgen.backlog(sent, t)
+                          for t in np.linspace(start, end, INSTANTS, endpoint=False)]))
+
+
+def sustained(row: dict, limit_ms) -> bool:
+    """The step's verdict (the module's docstring)."""
+    return bool(row["failed"] == 0 and row["shed"] == 0
+                and row["generator_late_ms"][95] <= 10.0
+                and row["backlog_late"] <= 1.25 * row["backlog_early"] + 1.0
+                and (limit_ms is None or row["latency_ms"][95] <= limit_ms))
 
 
 def main(argv=None) -> int:
@@ -35,6 +68,7 @@ def main(argv=None) -> int:
     ap.add_argument("--benchmark", default="BENCHMARK.json")
     args = ap.parse_args(argv)
 
+    import importlib
     import time
 
     import run as entry
@@ -42,7 +76,9 @@ def main(argv=None) -> int:
     cell = entry.load_cell(args.workload, args.rehearse, args.benchmark)
     import harness
     import loadgen
-    from runners.serve import Stack
+
+    # the serving stack of the cell's own runner (``runners/<runner>.py``)
+    Stack = importlib.import_module(f"runners.{cell['config']['runner']}").Stack
 
     devices, _cache_dir = harness.open_devices(1, args.rehearse)
     if devices is None:
@@ -53,13 +89,17 @@ def main(argv=None) -> int:
     stack.seed_slots()
     table = []
 
+    limit_ms = cell["traffic"].get("knee_p95_limit_ms")
+
     def step(rate: float) -> bool:
         sent, t0, t1, t_end = stack.offer(
             harness.seed31(args.seed, len(table)), rate, 0.5, args.step_s, 30.0)
         row = loadgen.summarise(sent, t0, t1, t_end)
-        row.update(rate=rate, shed=int(stack.batcher.shed_count), sustained=bool(
-            row["failed"] == 0 and row["generator_late_ms"][95] <= 10.0
-            and row["backlog_end"] <= 1.25 * row["backlog_mid"] + 0.005 * rate))
+        half = (t0 + t1) / 2
+        row.update(rate=rate, shed=int(stack.batcher.shed_count),
+                   backlog_early=mean_backlog(sent, t0, half),
+                   backlog_late=mean_backlog(sent, half, t1), p95_limit_ms=limit_ms)
+        row["sustained"] = sustained(row, limit_ms)
         table.append(row)
         print(json.dumps(row), flush=True)
         return row["sustained"]
@@ -75,7 +115,7 @@ def main(argv=None) -> int:
     finally:
         stack.batcher.close(timeout=30)
     print(json.dumps({"knee": low, "first_not_sustained": high,
-                      "four_fifths": 0.8 * low,
+                      "four_fifths": 0.8 * low, "half": 0.5 * low,
                       "device": harness.device_report(devices, 1)}))
     return 0
 

@@ -46,7 +46,11 @@ def test_rehearsal_prints_the_contract_line(cell, trace):
     assert line["correct"] is True and line["attempted"] > 0 and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"      # never a device number
     kind = "per_layer" if trace else "end_to_end"
-    assert set(line["metrics"]) == names(kind, cell)
+    wanted = names(kind, cell)
+    if trace:
+        # a CPU has no peak in peaks.json: the shares of one are left out
+        wanted = {n for n in wanted if "mfu" not in n and not n.endswith("_roofline")}
+    assert set(line["metrics"]) == wanted
     for value in line["metrics"].values():
         assert isinstance(value["value"], float) and value["unit"]
     if trace:
